@@ -32,30 +32,45 @@ Config via env so one manifest scales from the CPU e2e test to a TPU slice:
 
 import os
 import sys
+import time
+
+_T_START = time.perf_counter()  # before the jax import: set-up time counts it
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from mpi_operator_tpu.runtime import bootstrap
+import functools
+import json
 
 import jax
-
-if bootstrap.context_from_env().accelerator in ("", "cpu"):
-    jax.config.update("jax_platforms", "cpu")
-
-import json
-import time
 
 from mpi_operator_tpu.models import llama
 from mpi_operator_tpu.ops import Trainer, TrainerConfig
 from mpi_operator_tpu.ops.data import make_global_batch, synthetic_tokens
 from mpi_operator_tpu.ops.elastic import ElasticConfig, run_elastic
-from mpi_operator_tpu.runtime import MeshPlan, mesh_from_context
+from mpi_operator_tpu.runtime import (
+    MeshPlan,
+    bootstrap,
+    compile_cache,
+    mesh_from_context,
+    stepstats,
+)
 
 CONFIGS = {
     "tiny": llama.tiny,
     "bench": llama.bench_single_chip,
     "8b": llama.llama3_8b,
 }
+
+
+def _memory():
+    """Per local device, the allocator's bytes in use and their peak (None
+    where the backend reports nothing, as the CPU does)."""
+    out = []
+    for d in jax.local_devices():
+        ms = d.memory_stats()
+        out.append(ms and {"in_use": ms["bytes_in_use"],
+                           "peak": ms["peak_bytes_in_use"]})
+    return out
 
 
 def main():
@@ -92,10 +107,20 @@ def main():
     # before they inject the fault.
     progress_every = int(os.environ.get("LLAMA_PROGRESS_EVERY", "0") or 0)
 
+    # set-up facts for the report: the first batch is drawn once the state
+    # is built or restored, the second once step 1 has been dispatched
+    setup = {}
+
     def batches_iter():
         for i, b in enumerate(synthetic_tokens(
             global_batch=global_batch, seq_len=seq_len, vocab=cfg.vocab
         )):
+            if i == 0:
+                setup["first_dispatch_s"] = round(
+                    time.perf_counter() - _T_START, 2)
+                setup["memory_after_init"] = _memory()
+            elif i == 1:
+                setup["memory_after_step1"] = _memory()
             if pace:
                 time.sleep(pace)
             if progress_every and i and i % progress_every == 0 \
@@ -106,7 +131,14 @@ def main():
     batches = batches_iter()
 
     def init_state():
-        return trainer.init_state(llama.init(cfg, jax.random.PRNGKey(0)))
+        # drawn under jit with the mesh layout as out_shardings, so each
+        # chip generates only its own shard: an unsharded draw puts the
+        # whole model on the first chip before init_state spreads it
+        draw = jax.jit(
+            functools.partial(llama.init, cfg),
+            out_shardings=trainer.params_sharding(),
+        )
+        return trainer.init_state(draw(jax.random.PRNGKey(0)))
 
     t0 = time.perf_counter()
     if ckpt_dir:
@@ -135,6 +167,8 @@ def main():
         start_step = 0
 
     dt = time.perf_counter() - t0
+    # run_elastic's recorder flushed its last blob here on close
+    stats = stepstats.read_stats(os.environ.get(stepstats.ENV_STATS_FILE, ""))
     if ctx.is_coordinator:
         print(
             json.dumps(
@@ -150,9 +184,19 @@ def main():
                     "tokens_per_sec": round(global_batch * steps_run * seq_len / dt, 1),
                     "hosts": ctx.num_hosts,
                     "backend": jax.default_backend(),
+                    "device_kind": jax.devices()[0].device_kind,
+                    "devices": jax.device_count(),
                     "mesh": ",".join(
                         f"{a}={s}" for a, s in mesh.shape.items() if s > 1
                     ),
+                    "params": llama.param_count(cfg),
+                    "vocab": cfg.vocab,
+                    "global_batch": global_batch,
+                    "seq_len": seq_len,
+                    "compile_cache": compile_cache.cache_stats(),
+                    "buckets": (stats or {}).get("buckets"),
+                    **setup,
+                    "memory_at_exit": _memory(),
                 }
             ),
             flush=True,

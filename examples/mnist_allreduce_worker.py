@@ -23,22 +23,16 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from mpi_operator_tpu.runtime import bootstrap
-
-import jax
-
-if bootstrap.context_from_env().accelerator in ("", "cpu"):
-    jax.config.update("jax_platforms", "cpu")
-
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from mpi_operator_tpu.ops.data import make_global_batch
 from mpi_operator_tpu.parallel import collectives
-from mpi_operator_tpu.runtime import mesh_from_context
+from mpi_operator_tpu.runtime import bootstrap, mesh_from_context
 from mpi_operator_tpu.runtime.topology import AXIS_DATA
 
 

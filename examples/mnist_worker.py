@@ -8,21 +8,13 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from mpi_operator_tpu.runtime import bootstrap
-
-# Platform from the controller's declared accelerator BEFORE any XLA-backend-
-# initializing call (jax.distributed must run first on multi-host).
 import jax
-
-if bootstrap.context_from_env().accelerator in ("", "cpu"):
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 from mpi_operator_tpu.models import mnist
 from mpi_operator_tpu.ops import Trainer, TrainerConfig
 from mpi_operator_tpu.ops.data import make_global_batch
-from mpi_operator_tpu.runtime import mesh_from_context
+from mpi_operator_tpu.runtime import bootstrap, mesh_from_context
 
 
 def main():

@@ -9,16 +9,10 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from mpi_operator_tpu.runtime import bootstrap, mesh_from_context
-
-# Pick the platform from the controller's declared accelerator BEFORE any
-# call that would initialize the XLA backend (jax.distributed must go first).
 import jax
-
-if bootstrap.context_from_env().accelerator in ("", "cpu"):
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
+
+from mpi_operator_tpu.runtime import bootstrap, mesh_from_context
 
 
 def main():

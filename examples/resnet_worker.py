@@ -16,20 +16,15 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from mpi_operator_tpu.runtime import bootstrap
-
-import jax
-
-if bootstrap.context_from_env().accelerator in ("", "cpu"):
-    jax.config.update("jax_platforms", "cpu")
-
 import json
 import time
+
+import jax
 
 from mpi_operator_tpu.models import resnet
 from mpi_operator_tpu.ops import Trainer, TrainerConfig
 from mpi_operator_tpu.ops.data import make_global_batch, synthetic_imagenet
-from mpi_operator_tpu.runtime import mesh_from_context
+from mpi_operator_tpu.runtime import bootstrap, mesh_from_context
 
 
 def main():

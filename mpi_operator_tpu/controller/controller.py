@@ -111,11 +111,9 @@ ENV_HOST_MESH = "TPUJOB_HOST_MESH"
 ENV_HOST_COORD = "TPUJOB_HOST_COORD"
 ENV_SLICE_ID = "TPUJOB_SLICE_ID"
 ENV_NUM_SLICES = "TPUJOB_NUM_SLICES"
-# spec.compile_cache projection ("1"/"0", ISSUE 16): the EXECUTOR reads
-# this gate and, when on, injects its node-local persistent-cache dir as
-# $TPUJOB_COMPILE_CACHE_DIR (runtime/compile_cache.py owns that name —
-# same split as the stepstats file: controller knows policy, executor
-# knows node paths)
+# spec.compile_cache projection ("1"/"0", ISSUE 16): the WORKER reads this
+# gate at bootstrap (runtime/compile_cache.configure_from_env) and turns
+# jax's persistent compilation cache off for the job when it is "0"
 ENV_COMPILE_CACHE = "TPUJOB_COMPILE_CACHE"
 
 DEFAULT_COORDINATOR_PORT = 8476

@@ -226,7 +226,7 @@ class TrainLoadModel:
     ISSUE 15).
 
     Each registered worker pod advances a seeded synthetic step clock on
-    every stats tick: wall time splits into the TRAIN_BUCKETS taxonomy by
+    every stats tick: wall time splits into the TRAIN_BUCKETS scheme by
     a steady-state profile (mostly ``compute``), the first tick charges a
     one-shot ``compile`` phase, and two seeded fault knobs exist so the
     goodput aggregator has something real to attribute:

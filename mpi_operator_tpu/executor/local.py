@@ -46,10 +46,6 @@ from mpi_operator_tpu.machinery.store import (
     ObjectStore,
 )
 from mpi_operator_tpu.runtime.emulation import pin_host_device_count
-from mpi_operator_tpu.runtime.compile_cache import (
-    ENV_CACHE_DIR,
-    ENV_CACHE_ENABLED,
-)
 from mpi_operator_tpu.runtime.stepstats import ENV_STATS_FILE, read_stats
 
 log = logging.getLogger("tpujob.executor")
@@ -118,7 +114,6 @@ class LocalExecutor:
         status_sink=None,
         eviction_grace: float = 5.0,
         stepstats_poll: float = 1.0,
-        compile_cache_dir: Optional[str] = None,
     ):
         self.store = store
         self.loopback_rewrite = loopback_rewrite
@@ -178,16 +173,10 @@ class LocalExecutor:
         # `ctl logs` (any process on this node) can read it
         self.logs_dir = logs_dir or tempfile.mkdtemp(prefix="tpujob-logs-")
         self._config_root = tempfile.mkdtemp(prefix="tpujob-config-")
-        # the persistent-compile-cache root (ISSUE 16): NODE-LOCAL and
-        # STABLE across pod incarnations — unlike the per-incarnation
-        # stepstats/log paths, reuse across restarts is the whole point.
-        # Injected as $TPUJOB_COMPILE_CACHE_DIR unless the controller's
-        # $TPUJOB_COMPILE_CACHE projection opted the job out; workers
-        # namespace their entries by jax version + backend under it
-        # (runtime/compile_cache.py), so one dir serves every job safely.
-        self.compile_cache_dir = compile_cache_dir or os.path.join(
-            self.logs_dir, "compile-cache"
-        )
+        # the persistent compile cache must never hang off these temporary
+        # directories (a cache that moves never hits): the worker places it
+        # (runtime/compile_cache.py), from the $JAX_COMPILATION_CACHE_DIR
+        # that _launch passes through with the rest of the environment
         self._lock = threading.Lock()
         self._stop = threading.Event()
         self._threads: list = []
@@ -539,13 +528,6 @@ class LocalExecutor:
             # log files, so a restarted pod never inherits stale stats
             stats_path = base + ".stats.json"
             env[ENV_STATS_FILE] = stats_path
-            # the compile-cache contract (ISSUE 16): a STABLE node-local
-            # dir (vs the per-incarnation paths above — restarts reusing
-            # it is the feature), gated on the controller's projection of
-            # spec.compile_cache; the worker's bootstrap points jax at a
-            # version/backend-namespaced subdir
-            if env.get(ENV_CACHE_ENABLED, "1") != "0":
-                env[ENV_CACHE_DIR] = self.compile_cache_dir
             handles = []
             try:
                 f_out = open(log_path, "w")

@@ -33,8 +33,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from mpi_operator_tpu.jaxcompat import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -589,7 +587,7 @@ def flash_attention(
     # check_vma=False: pallas_call's out_shape carries no varying-mesh-axes
     # annotation, so shard_map's vma checker rejects it; the specs above are
     # the full partitioning contract anyway.
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

@@ -407,7 +407,7 @@ ANNOTATION_PROFILE_REQUEST = "tpujob.dev/profile-request"
 # bounded status-stats blobs (the workload telemetry plane, ISSUE 15)
 # ---------------------------------------------------------------------------
 
-# the stall-attribution bucket taxonomy — every wall-second of a training
+# the stall-attribution bucket scheme — every wall-second of a training
 # step classifies into exactly one of these (worker-side) or "restart"
 # (controller-side downtime, charged from conditions by the goodput
 # aggregator). Shared by the real step loop (runtime/stepstats.py), the
@@ -453,7 +453,7 @@ def bounded_train_stats(step=0, steps=0, step_p50_ms=0.0, buckets=None,
                         **_ignored) -> Dict[str, object]:
     """THE constructor for a pod's ``status.train_stats`` blob (oplint
     OBS004). Fixed key set, rounded floats, bucket keys clamped to the
-    :data:`TRAIN_BUCKETS` taxonomy, profile ack clamped to short strings
+    :data:`TRAIN_BUCKETS` scheme, profile ack clamped to short strings
     — an unbounded dict here would bloat every watch event carrying the
     pod (the same reason serve_stats is three floats).
 

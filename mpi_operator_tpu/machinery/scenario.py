@@ -299,7 +299,7 @@ class Scenario:
         if doc.get("chaos"):
             # the embedded fault timeline reuses the ChaosScript grammar
             # VERBATIM (knob whitelists included): one validator, one
-            # error taxonomy, and the new `reclaim` verb comes for free
+            # set of error messages, and the new `reclaim` verb comes for free
             try:
                 chaos = ChaosScript.parse(
                     {"seed": seed, "actions": doc["chaos"]}

@@ -13,9 +13,27 @@ exactly the elastic-resume path the controller's scale-up/down drives."""
 from __future__ import annotations
 
 import os
+import resource
 from typing import Any, Optional
 
 import jax
+
+# Saves are cut into bounded OCDBT data files: orbax's default lets one
+# file grow to 2 GiB, and a multi-hundred-MB single file is a poor unit on
+# any shared volume — and impossible to write at all on a machine with a
+# smaller RLIMIT_FSIZE (EFBIG killed the first full-width save on one).
+# orbax chunks every array down to the target and OCDBT closes a data file
+# once it passes it, so a file stays under twice this.
+DATA_FILE_TARGET_BYTES = 16 << 20
+
+
+def _data_file_target() -> int:
+    """DATA_FILE_TARGET_BYTES, lowered to a quarter of a finite
+    RLIMIT_FSIZE so even the 2x overshoot stays well inside the limit."""
+    soft, _hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+    if soft == resource.RLIM_INFINITY:
+        return DATA_FILE_TARGET_BYTES
+    return max(1, min(DATA_FILE_TARGET_BYTES, soft // 4))
 
 
 class CheckpointManager:
@@ -59,7 +77,11 @@ class CheckpointManager:
         device→host snapshot; the disk commit overlaps later steps and is
         fenced by :meth:`wait`."""
         saved = self.manager.save(
-            step, args=self._ocp.args.StandardSave(state), force=force
+            step,
+            args=self._ocp.args.PyTreeSave(
+                state, ocdbt_target_data_file_size=_data_file_target()
+            ),
+            force=force,
         )
         return bool(saved)
 
@@ -67,9 +89,12 @@ class CheckpointManager:
         return self.manager.latest_step()
 
     def restore(self, state_template: Any, *, step: Optional[int] = None) -> Any:
-        """Restore into the layout of ``state_template`` (an abstract or
-        concrete TrainState whose shardings describe the *current* mesh —
-        resharding across gang sizes happens here)."""
+        """Restore into the layout of ``state_template``: a TrainState of
+        ``jax.ShapeDtypeStruct`` leaves (or concrete arrays, of which only
+        shape, dtype and sharding are read) whose shardings describe the
+        *current* mesh — resharding across gang sizes happens here. Pass
+        the abstract form at scale: a materialized template costs a second
+        copy of the state in device memory beside the restored one."""
         # pre-restore fence (a sanctioned wait seam, oplint CKP001): an
         # in-flight async commit of the step being restored must finish
         # before its files are read back
@@ -85,7 +110,13 @@ class CheckpointManager:
             state_template,
         )
         return self.manager.restore(
-            step, args=self._ocp.args.StandardRestore(abstract)
+            step,
+            args=self._ocp.args.PyTreeRestore(
+                abstract,
+                restore_args=self._ocp.checkpoint_utils.construct_restore_args(
+                    abstract
+                ),
+            ),
         )
 
     def wait(self) -> None:

@@ -134,9 +134,10 @@ def run_elastic(
 ) -> ElasticResult:
     """Train to total_steps or until membership changes.
 
-    ``init_state`` builds a fresh TrainState (used only when no checkpoint
-    exists); otherwise the latest checkpoint is restored INTO the current
-    mesh layout. Returns "restart" (caller exits EXIT_RESTART) or "done".
+    ``init_state`` builds a fresh TrainState with ``trainer`` (run only
+    when no checkpoint exists; otherwise it is merely traced for its
+    shapes, and the latest checkpoint is restored INTO the current mesh
+    layout). Returns "restart" (caller exits EXIT_RESTART) or "done".
     """
     import jax
 
@@ -177,11 +178,14 @@ def run_elastic(
         config.checkpoint_dir,
         save_interval_steps=config.save_interval_steps,
     )
-    template = init_state()
     if mgr.latest_step() is not None:
-        state = mgr.restore(template)
+        # restore INTO an abstract template (shapes, dtypes, this mesh's
+        # shardings): a materialized one would hold a second full state in
+        # device memory beside the restored one — two ~9.5 GB states do
+        # not fit a 16 GB chip
+        state = mgr.restore(trainer.abstract_state(init_state))
     else:
-        state = template
+        state = init_state()
 
     # Track the step host-side: int(state.step) forces a device sync on a
     # jit output, which would serialize dispatch of step N+1 behind compute

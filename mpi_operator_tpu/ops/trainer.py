@@ -127,7 +127,6 @@ class Trainer:
         self._step_fn = None
         self._multi_fns = None  # n → compiled n-step scan (multi_step)
         self._donate = donate
-        self._opt_state_sharding_template = None  # set by init_state
 
     # -- shardings ---------------------------------------------------------
 
@@ -151,30 +150,45 @@ class Trainer:
         spec = mesh_filtered_spec(logical_spec(["batch"], self.rules), self.mesh)
         return jax.tree.map(lambda _: NamedSharding(self.mesh, spec), batch)
 
-    def state_sharding(self) -> "TrainState":
-        """Sharding pytree for TrainState (valid after init_state)."""
+    def state_sharding(self, state: "TrainState") -> "TrainState":
+        """Sharding pytree for a TrainState, derived from the shapes of
+        ``state.params`` alone — so it can be named for a state that does
+        not exist yet (``state`` may hold ``jax.ShapeDtypeStruct`` leaves)."""
+        p_sh = self.params_sharding()
         return TrainState(
             step=NamedSharding(self.mesh, PartitionSpec()),
-            params=self.params_sharding(),
-            opt_state=self._opt_state_sharding_template,
+            params=p_sh,
+            opt_state=self._opt_sharding_for(state.params, p_sh),
             model_state=self.model_state_sharding()
             if self.has_model_state
             else {},
         )
 
+    def abstract_state(self, init_state: Callable[[], "TrainState"]) -> "TrainState":
+        """The TrainState ``init_state()`` would build, as shapes, dtypes
+        and this mesh's shardings — traced, so no device memory is touched.
+        What a checkpoint restores into (ops/elastic.py)."""
+        shapes = jax.eval_shape(init_state)
+        return jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            shapes,
+            self.state_sharding(shapes),
+        )
+
     # -- lifecycle ---------------------------------------------------------
 
     def init_state(self, params, model_state: Any = None) -> TrainState:
-        """Build TrainState with every array placed per the mesh layout."""
+        """Build TrainState with every array placed per the mesh layout.
+        At scale, draw ``params`` under ``jit`` with
+        ``out_shardings=params_sharding()`` so each device generates only
+        its own shard; an unsharded draw lands whole on the first device
+        before the placement here spreads it."""
         p_sh = self.params_sharding()
         params = jax.tree.map(jax.device_put, params, p_sh)
         opt_state = jax.jit(
             self.tx.init,
             out_shardings=self._opt_sharding_for(params, p_sh),
         )(params)
-        self._opt_state_sharding_template = jax.tree.map(
-            lambda x: x.sharding, opt_state
-        )
         if self.has_model_state:
             model_state = jax.tree.map(
                 jax.device_put, model_state, self.model_state_sharding()
@@ -249,11 +263,11 @@ class Trainer:
             metrics,
         )
 
-    def _jit_wrap(self, fn, batch_example):
+    def _jit_wrap(self, fn, state, batch_example):
         """jit a (state, batch) -> (state, metrics) function with the
         trainer's shardings + donation (shared by train_step/multi_step so
         the two paths can never drift)."""
-        state_sh = self.state_sharding()
+        state_sh = self.state_sharding(state)
         metrics_sh = {"loss": NamedSharding(self.mesh, PartitionSpec())}
         if self.config.grad_clip_norm > 0:
             metrics_sh["grad_norm"] = NamedSharding(self.mesh, PartitionSpec())
@@ -264,12 +278,9 @@ class Trainer:
             donate_argnums=(0,) if self._donate else (),
         )
 
-    def _build_step(self, batch_example):
-        return self._jit_wrap(self._bare_step, batch_example)
-
     def train_step(self, state: TrainState, batch):
         if self._step_fn is None:
-            self._step_fn = self._build_step(batch)
+            self._step_fn = self._jit_wrap(self._bare_step, state, batch)
         return self._step_fn(state, batch)
 
     def multi_step(self, state: TrainState, batch, n: int):
@@ -294,7 +305,7 @@ class Trainer:
                 state, ms = jax.lax.scan(body, state, None, length=n)
                 return state, jax.tree.map(lambda x: x[-1], ms)
 
-            fn = self._jit_wrap(run, batch)
+            fn = self._jit_wrap(run, state, batch)
             self._multi_fns[n] = fn
         return fn(state, batch)
 
@@ -302,5 +313,5 @@ class Trainer:
         """AOT-compile the step (returns the lowered+compiled executable;
         also caches it as the active step fn)."""
         if self._step_fn is None:
-            self._step_fn = self._build_step(batch)
+            self._step_fn = self._jit_wrap(self._bare_step, state, batch)
         return self._step_fn.lower(state, batch).compile()

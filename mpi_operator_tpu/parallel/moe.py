@@ -25,7 +25,6 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from mpi_operator_tpu.jaxcompat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mpi_operator_tpu.runtime.topology import AXIS_EXPERT
@@ -126,7 +125,7 @@ def apply(config: MoEConfig, params: Params, x, *, mesh: Mesh = None):
 
             return jax.vmap(one)(buf_local, w_in_local, w_out_local)
 
-        out_buf = shard_map(
+        out_buf = jax.shard_map(
             sharded,
             mesh=mesh,
             in_specs=(P(AXIS_EXPERT), P(AXIS_EXPERT), P(AXIS_EXPERT)),

@@ -20,7 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from mpi_operator_tpu.jaxcompat import shard_map
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -143,7 +142,7 @@ def run_pipeline(
     # axes so a data×pipe mesh does DP beside PP instead of replicating
     b_part = tuple(a for a in batch_axes if a in mesh.axis_names) or None
     micro_spec = P(None, b_part, *(None,) * (micro.ndim - 2))
-    out = shard_map(
+    out = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(param_spec, micro_spec),

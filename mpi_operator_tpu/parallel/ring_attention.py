@@ -23,7 +23,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from mpi_operator_tpu.jaxcompat import shard_map
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -170,7 +169,7 @@ def ring_attention(
 
             return chunked_reference(q, k, v, causal=causal, scale=scale)
         return dense_attention(q, k, v, causal=causal, scale=scale)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )(q, k, v)
 

@@ -61,7 +61,7 @@ class RuntimeContext:
     num_hosts: int = 1
     host_id: int = 0
     chips_per_host: int = 0  # 0 = undeclared; local_chips() discovers
-    accelerator: str = "cpu"
+    accelerator: str = ""  # "" = undeclared: jax picks the platform
     topology: Tuple[int, ...] = ()
     host_mesh: Tuple[int, ...] = ()
     host_coord: Tuple[int, ...] = ()
@@ -103,7 +103,7 @@ def context_from_env(environ: Optional[Mapping[str, str]] = None) -> RuntimeCont
         num_hosts=int(env.get(ENV_NUM_HOSTS, "1")),
         host_id=int(env.get(ENV_HOST_ID, "0")),
         chips_per_host=int(env.get(ENV_CHIPS_PER_HOST, "0") or 0),
-        accelerator=env.get(ENV_ACCELERATOR, "cpu"),
+        accelerator=env.get(ENV_ACCELERATOR, ""),
         topology=_parse_shape(env.get(ENV_TOPOLOGY, "")),
         host_mesh=_parse_shape(env.get(ENV_HOST_MESH, "")),
         host_coord=_parse_shape(env.get(ENV_HOST_COORD, "")),
@@ -147,22 +147,31 @@ def initialize(
     the controller advertised via the headless service DNS name; every other
     host dials it. This is the TPU-native replacement for the v2 SSH wireup
     (SURVEY.md §3.3) and the v1 kubectl-exec path (§3.4).
+
+    The platform is chosen here and nowhere else, from the accelerator the
+    manifest declared: ``cpu`` pins jax to the CPU; a TPU family raises
+    unless jax came up on a TPU; undeclared (a script run outside the
+    operator) pins and checks nothing.
     """
     global _initialized_ctx
     if _initialized_ctx is not None:
         return _initialized_ctx
     if ctx is None:
         ctx = context_from_env(environ)
-    # the persistent-compile-cache contract (ISSUE 16): when the executor
-    # injected a node-local cache dir, point jax at it BEFORE anything
-    # compiles — a relaunched gang then reads its executables off disk
-    # instead of repaying the 75–98 s warmup
+    import jax
+
     from mpi_operator_tpu.runtime import compile_cache
 
+    # Everything before the rendezvous must leave jax's backends
+    # uninitialized: jax.distributed.initialize refuses to run once one
+    # exists.
+    if ctx.accelerator == "cpu":
+        jax.config.update("jax_platforms", "cpu")
+    # point jax at the persistent compile cache BEFORE anything compiles —
+    # a relaunched gang then reads its executables off disk instead of
+    # repaying the warmup
     compile_cache.configure_from_env(environ)
     if ctx.is_distributed:
-        import jax
-
         if not ctx.coordinator_address:
             raise RuntimeError(
                 f"{ENV_NUM_HOSTS}={ctx.num_hosts} but {ENV_COORDINATOR} is "
@@ -175,26 +184,22 @@ def initialize(
             ctx.num_hosts,
             ctx.coordinator_address,
         )
-        if ctx.accelerator in ("", "cpu"):
-            # cross-process collectives on the CPU backend need the gloo
-            # implementation selected BEFORE the distributed handshake —
-            # without it every multi-process jit (and orbax's process-sync
-            # barrier, so any multi-host checkpoint/restore) dies with
-            # "Multiprocess computations aren't implemented on the CPU
-            # backend". Newer jax makes gloo the default; the guard keeps
-            # this a no-op there.
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo"
-                )
-            # oplint: disable=EXC001 — newer jax removed the knob because
-            # gloo IS the default there; the no-op is the desired outcome
-            except Exception:
-                pass
         jax.distributed.initialize(
             coordinator_address=ctx.coordinator_address,
             num_processes=ctx.num_hosts,
             process_id=ctx.host_id,
+        )
+    if ctx.accelerator not in ("", "cpu") and jax.default_backend() != "tpu":
+        # every accelerator but the "cpu" test family names TPU hardware
+        # (api/types.py HOST_BLOCK). No fallback: a job declared for a chip
+        # must not train on whatever platform $JAX_PLATFORMS happened to
+        # allow. After the rendezvous on purpose: default_backend()
+        # initializes the backend.
+        raise RuntimeError(
+            f"{ENV_ACCELERATOR}={ctx.accelerator} but jax's backend is "
+            f"{jax.default_backend()!r} (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS', '')!r}) — refusing to run a "
+            "TPU job off the chip"
         )
     _initialized_ctx = ctx
     return ctx
@@ -206,4 +211,7 @@ def active_context() -> Optional[RuntimeContext]:
 
 def _reset_for_tests() -> None:
     global _initialized_ctx
+    from mpi_operator_tpu.runtime import compile_cache
+
     _initialized_ctx = None
+    compile_cache._reset_for_tests()

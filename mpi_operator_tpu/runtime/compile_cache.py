@@ -4,35 +4,40 @@
 Every recovery path the operator optimizes — gang restart, elastic
 rescale, checkpoint-then-migrate, autoscaler cold start — relaunches the
 worker process, and the relaunched process repays the full trace+compile
-warmup (75–98 s on the real llama/resnet gangs) before its first step.
-The program being compiled is byte-identical across incarnations: same
-model, same mesh, same jax. jax's persistent compilation cache turns
-that repayment into a disk read, IF something owns a cache directory
-that survives the pod.
+warmup before its first step. The program being compiled is
+byte-identical across incarnations: same model, same mesh, same jax.
+jax's persistent compilation cache turns that repayment into a disk
+read, IF the cache directory is the same one every time — the directory
+is part of jax's cache key, so a directory that moves never hits.
 
-Ownership shape mirrors ``$TPUJOB_STEPSTATS_FILE`` (the telemetry
-plane's executor→worker contract): the EXECUTOR owns a node-local cache
-dir (stable across incarnations — the whole point) and injects it as
-``$TPUJOB_COMPILE_CACHE_DIR`` at launch, gated on the job's
-``spec.compile_cache`` knob the controller projects as
-``$TPUJOB_COMPILE_CACHE``. The worker side calls
-:func:`configure_from_env` at bootstrap (runtime/bootstrap.initialize),
-which points jax at a *namespaced* subdir and installs a hit/miss
-listener so the telemetry plane can tell a warm restart from a cold one:
-:func:`cache_stats` rides the ``compile_cache`` field of the bounded
-train_stats blob (machinery/objects.py) into ``pod.status.train_stats``.
+Where the cache lives is decided in ONE place, :func:`configure_from_env`,
+called by the worker bootstrap (runtime/bootstrap.initialize), bench.py
+and anything else that compiles:
+
+- ``$JAX_COMPILATION_CACHE_DIR`` set — jax read it at import; this module
+  sets no other directory and appends nothing to it. That is how a
+  deployment (or a benchmark driver) places the cache from outside: the
+  executor passes its own environment through to every worker.
+- unset — :data:`DEFAULT_CACHE_DIR`, one fixed git-ignored path inside
+  the checkout. Never a temporary directory, a pid or a time.
+- the job's ``spec.compile_cache: false`` (projected by the controller as
+  ``$TPUJOB_COMPILE_CACHE=0``) turns caching off for that job.
+
+Nothing here may initialize a jax backend: multi-host workers configure
+the cache BEFORE ``jax.distributed.initialize``, which refuses to run
+once a backend exists. jax keys every entry by its version, backend and
+compile options already, so one directory serves every job safely.
+
+The hit/miss listener lets the telemetry plane tell a warm restart from
+a cold one: :func:`cache_stats` rides the ``compile_cache`` field of the
+bounded train_stats blob (machinery/objects.py) into
+``pod.status.train_stats``.
 
 Failure modes, by design of jax's cache (verified in
 tests/test_compile_cache.py):
 
 - a corrupted/truncated entry is a WARNING + cache miss + fresh compile,
   never a crashed step loop (jax re-writes the entry);
-- entries are keyed by a hash covering the jax/jaxlib version, backend
-  and compile options, so an upgraded worker can never reuse a stale
-  executable — and :func:`cache_namespace` additionally puts each
-  (jax version, backend) in its OWN subdir, so mixed-version nodes
-  during a rolling upgrade don't even share a directory, and an operator
-  can reclaim dead-version caches by deleting the dead subdir;
 - an unwritable dir degrades to no caching (jax warns), same contract as
   a full disk on the stepstats flush.
 
@@ -50,15 +55,17 @@ import os
 import threading
 from typing import Dict, Mapping, Optional
 
-log = logging.getLogger("tpujob.compilecache")
-
-# the executor→worker contract: the node-local persistent cache root the
-# executor owns (stable across pod incarnations, unlike the per-
-# incarnation stepstats path — reuse across restarts IS the feature)
-ENV_CACHE_DIR = "TPUJOB_COMPILE_CACHE_DIR"
-# the controller→executor projection of spec.compile_cache ("1"/"0");
-# the executor only injects ENV_CACHE_DIR when this is not "0"
+# jax's own variable: read by jax at import, passed through by the executor
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+# the controller's projection of spec.compile_cache ("1"/"0")
 ENV_CACHE_ENABLED = "TPUJOB_COMPILE_CACHE"
+# where the cache lives when nobody placed it from outside (.gitignore
+# lists it)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 # jax's cache-event names (jax._src.monitoring); stable since 0.4.x
 _EVENT_HIT = "/jax/compilation_cache/cache_hits"
@@ -70,29 +77,6 @@ _listener_installed = False
 _counts = {"hits": 0, "misses": 0}
 
 
-def cache_namespace(jax_version: Optional[str] = None,
-                    backend: Optional[str] = None) -> str:
-    """The version/backend-scoped subdir name entries live under.
-
-    jax already folds its version + compile options into every cache
-    key, so cross-version reuse is impossible at the key level; the
-    subdir makes the isolation *inspectable* (an operator can see and
-    delete `jax-0.4.36-*` after an upgrade) and keeps a rolling-upgrade
-    fleet from churning one directory's eviction LRU from two versions
-    at once. Args are injectable for tests; the defaults describe this
-    process."""
-    if jax_version is None or backend is None:
-        import jax
-
-        jax_version = jax_version or jax.__version__
-        # default_backend() initializes the platform, which is fine at
-        # bootstrap time (the very next thing the worker does is compile)
-        backend = backend or jax.default_backend()
-    safe = "".join(c if c.isalnum() or c in "._-" else "_"
-                   for c in f"{jax_version}-{backend}")
-    return f"jax-{safe}"
-
-
 def _on_event(event: str, **_kw) -> None:
     if event == _EVENT_HIT:
         _counts["hits"] += 1
@@ -100,28 +84,30 @@ def _on_event(event: str, **_kw) -> None:
         _counts["misses"] += 1
 
 
-def configure(root: str) -> str:
-    """Point jax's persistent compilation cache at
-    ``root/<cache_namespace()>`` and start counting hits/misses.
-    Idempotent per process (a second call with a different root wins,
-    matching jax.config semantics). Returns the namespaced dir."""
+def configure_from_env(env: Optional[Mapping[str, str]] = None
+                       ) -> Optional[str]:
+    """Turn the persistent compilation cache on for this process and
+    start counting hits/misses. Returns the cache directory, or None when
+    the job opted out (``$TPUJOB_COMPILE_CACHE=0``). Idempotent, and
+    initializes no backend (see the module docstring)."""
     global _configured_dir, _listener_installed
     import jax
 
-    cache_dir = os.path.join(os.path.abspath(root), cache_namespace())
+    env = os.environ if env is None else env
     with _lock:
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-        except OSError:
-            # an unwritable root degrades to no caching (jax will warn on
-            # its first write attempt); a worker must never die over it
-            log.warning("compile cache dir %s not creatable", cache_dir,
-                        exc_info=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        if env.get(ENV_CACHE_ENABLED, "1") == "0":
+            jax.config.update("jax_compilation_cache_dir", None)
+            _configured_dir = None
+            return None
+        # what jax read from $JAX_COMPILATION_CACHE_DIR at import
+        cache_dir = jax.config.jax_compilation_cache_dir
+        if not cache_dir:
+            cache_dir = DEFAULT_CACHE_DIR
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         # cache EVERYTHING: the default thresholds skip small/fast
         # compiles, but the restart warmup this exists to kill is the sum
-        # of many entries — and the bench's tiny CPU twin would never
-        # cross the default 1s floor at all
+        # of many entries — and a tiny CPU twin would never cross the
+        # default 1s floor at all
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         if not _listener_installed:
@@ -131,18 +117,6 @@ def configure(root: str) -> str:
             _listener_installed = True
         _configured_dir = cache_dir
     return cache_dir
-
-
-def configure_from_env(env: Optional[Mapping[str, str]] = None
-                       ) -> Optional[str]:
-    """Bootstrap-time entry point: configure from ``$TPUJOB_COMPILE_
-    CACHE_DIR`` when the executor injected one; a no-op (returns None)
-    otherwise, so processes outside the operator keep jax's defaults."""
-    env = os.environ if env is None else env
-    root = env.get(ENV_CACHE_DIR, "")
-    if not root:
-        return None
-    return configure(root)
 
 
 def is_configured() -> bool:
@@ -163,7 +137,10 @@ def cache_stats() -> Dict[str, int]:
 
 def _reset_for_tests() -> None:
     global _configured_dir
+    import jax
+
     with _lock:
+        jax.config.update("jax_compilation_cache_dir", None)
         _configured_dir = None
         _counts["hits"] = 0
         _counts["misses"] = 0
@@ -225,7 +202,7 @@ def smoke() -> int:
     out: Dict[str, object] = {"metric": "compile_cache_smoke", "ok": False}
     with tempfile.TemporaryDirectory(prefix="tpujob-cc-smoke-") as root:
         env = dict(os.environ)
-        env[ENV_CACHE_DIR] = root
+        env[ENV_JAX_CACHE_DIR] = root
         env.setdefault("JAX_PLATFORMS", "cpu")
         runs = []
         for i in range(2):

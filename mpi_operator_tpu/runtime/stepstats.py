@@ -114,8 +114,8 @@ class StepStatsRecorder:
         """Attribute the enclosed wall time to ``bucket``. The FIRST
         ``compute`` phase lands in ``compile`` instead: the first step's
         wall time is trace+compile+run, and charging it to compute would
-        poison every small-N step average (the 75-98s restart warmup
-        ROADMAP item 5 is chasing must be visible as ITS OWN bucket)."""
+        poison every small-N step average (the restart warmup's compile
+        share must be visible as ITS OWN bucket)."""
         t0 = self._clock()
         try:
             yield

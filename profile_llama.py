@@ -20,9 +20,11 @@ import json
 import jax
 
 from bench import llama_per_chip_batch, llama_setup
+from mpi_operator_tpu.runtime import compile_cache
 
 
 def main():
+    compile_cache.configure_from_env()
     per_chip_batch = llama_per_chip_batch()
     seq_len = int(os.environ.get("BENCH_SEQ", "2048"))
     _, trainer, state, batch, _ = llama_setup(per_chip_batch, seq_len)

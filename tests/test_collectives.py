@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from mpi_operator_tpu.jaxcompat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mpi_operator_tpu.parallel import collectives as c
@@ -25,7 +24,7 @@ def mesh():
 
 
 def smap(fn, mesh, in_specs=P(AXIS), out_specs=P(AXIS)):
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
 
 
 def test_psum_matches_allreduce(mesh):

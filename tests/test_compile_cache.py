@@ -1,43 +1,124 @@
 """Persistent compile cache plumbing (runtime/compile_cache.py, ISSUE 16).
 
-Fast tier: the pure plumbing — namespace derivation, env gating, the
-train_stats blob field. Slow tier: real child processes compiling against
-a shared cache dir — the warm-restart win, corruption robustness, and
-version isolation on disk."""
+Fast tier: where the cache lives — placed from outside by
+``$JAX_COMPILATION_CACHE_DIR`` (passed through the executor untouched) or at
+the one fixed in-checkout path — the job-level off switch, and the
+train_stats blob field. Slow tier: real child processes compiling against a
+shared cache dir — the warm-restart win and corruption robustness."""
 
 import json
 import os
 import subprocess
 import sys
+import time
 
 import pytest
 
-from mpi_operator_tpu.machinery.objects import bounded_train_stats
+from mpi_operator_tpu.api.types import Container, ObjectMeta
+from mpi_operator_tpu.executor.local import LocalExecutor
+from mpi_operator_tpu.machinery.objects import (
+    Pod,
+    PodPhase,
+    PodSpec,
+    bounded_train_stats,
+)
+from mpi_operator_tpu.machinery.store import ObjectStore
 from mpi_operator_tpu.runtime import compile_cache
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
-# fast: namespace + env plumbing
+# fast: where the cache lives
 # ---------------------------------------------------------------------------
 
+# what a worker does at bootstrap, reporting what it ended up with
+_WORKER_SRC = """
+import json, jax
+from jax._src import xla_bridge
+from mpi_operator_tpu.runtime import compile_cache
+print(json.dumps({
+    "returned": compile_cache.configure_from_env(),
+    "config": jax.config.jax_compilation_cache_dir,
+    "backend_initialized": xla_bridge.backends_are_initialized(),
+}))
+"""
 
-def test_namespace_isolates_versions_and_backends():
-    a = compile_cache.cache_namespace("0.4.37", "tpu")
-    assert a == "jax-0.4.37-tpu"
-    assert compile_cache.cache_namespace("0.4.36", "tpu") != a
-    assert compile_cache.cache_namespace("0.4.37", "cpu") != a
+
+def _configure_in_pods(executors):
+    """Run _WORKER_SRC as one pod on each LocalExecutor (concurrently);
+    returns what each printed."""
+    for ex in executors:
+        ex.start()
+    try:
+        for ex in executors:
+            ex.store.create(Pod(
+                metadata=ObjectMeta(name="w-0", namespace="default"),
+                spec=PodSpec(container=Container(
+                    command=[sys.executable, "-c", _WORKER_SRC],
+                )),
+            ))
+        deadline = time.time() + 60
+        for ex in executors:
+            while time.time() < deadline:
+                pod = ex.store.get("Pod", "default", "w-0")
+                if pod.status.phase in (PodPhase.SUCCEEDED, PodPhase.FAILED):
+                    break
+                time.sleep(0.05)
+            assert pod.status.phase == PodPhase.SUCCEEDED, ex.logs
+        return [json.loads(ex.logs["default/w-0"][0]) for ex in executors]
+    finally:
+        for ex in executors:
+            ex.stop()
 
 
-def test_namespace_sanitizes_weird_version_strings():
-    ns = compile_cache.cache_namespace("0.5.0.dev+g1234/zz", "cpu")
-    assert "/" not in ns and os.sep not in ns
-    assert ns.startswith("jax-")
+def test_cache_dir_placed_from_outside_is_left_alone(tmp_path, monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR reaches the worker through the executor
+    and wins: no other directory set in code, no subdirectory appended —
+    and configuring initializes no backend (the multi-host rendezvous
+    comes after it and refuses to run once one exists)."""
+    outside = str(tmp_path / "placed")
+    monkeypatch.setenv(compile_cache.ENV_JAX_CACHE_DIR, outside)
+    ex = LocalExecutor(ObjectStore(), workdir=REPO,
+                       logs_dir=str(tmp_path / "logs"))
+    (got,) = _configure_in_pods([ex])
+    assert got == {"returned": outside, "config": outside,
+                   "backend_initialized": False}
 
 
-def test_configure_from_env_is_noop_without_the_contract_var():
-    assert compile_cache.configure_from_env(env={}) is None
+def test_default_cache_dir_is_fixed_and_the_same_for_every_executor(
+        tmp_path, monkeypatch):
+    """Unset, the cache lives at ONE fixed path inside the checkout: the
+    directory is part of jax's cache key, so a root hung off an executor's
+    temporary logs directory would move on every start and never hit."""
+    monkeypatch.delenv(compile_cache.ENV_JAX_CACHE_DIR, raising=False)
+    executors = [
+        LocalExecutor(ObjectStore(), workdir=REPO,
+                      logs_dir=str(tmp_path / f"logs{i}"))
+        for i in range(2)
+    ]
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.DEFAULT_CACHE_DIR == fixed
+    for got in _configure_in_pods(executors):
+        assert got == {"returned": fixed, "config": fixed,
+                       "backend_initialized": False}
+
+
+def test_job_opt_out_turns_caching_off():
+    """spec.compile_cache: false → $TPUJOB_COMPILE_CACHE=0 → no cache for
+    that job, whatever the environment placed."""
+    import jax
+
+    try:
+        assert compile_cache.configure_from_env(env={}) is not None
+        assert compile_cache.is_configured()
+        got = compile_cache.configure_from_env(
+            env={compile_cache.ENV_CACHE_ENABLED: "0"})
+        assert got is None
+        assert jax.config.jax_compilation_cache_dir is None
+        assert not compile_cache.is_configured()
+    finally:
+        compile_cache._reset_for_tests()
 
 
 def test_blob_field_absent_when_unconfigured():
@@ -58,23 +139,6 @@ def test_blob_field_bounded_when_present():
     assert blob["compile_cache"] == {"hits": 7, "misses": 2}
 
 
-def test_versions_get_disjoint_dirs_on_disk(tmp_path):
-    """Two incarnations claiming different jax versions must not share a
-    cache namespace directory (rolling-upgrade isolation)."""
-    import jax
-
-    configured = compile_cache.configure(str(tmp_path))
-    try:
-        assert configured.startswith(str(tmp_path))
-        assert os.path.isdir(configured)
-        ns_now = os.path.basename(configured)
-        other = compile_cache.cache_namespace("9.9.9", "cpu")
-        assert other != ns_now
-    finally:
-        jax.config.update("jax_compilation_cache_dir", None)
-        compile_cache._reset_for_tests()
-
-
 # ---------------------------------------------------------------------------
 # slow: real child processes against one cache dir
 # ---------------------------------------------------------------------------
@@ -83,7 +147,7 @@ def test_versions_get_disjoint_dirs_on_disk(tmp_path):
 def _run_child(cache_root, extra_env=None):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env[compile_cache.ENV_CACHE_DIR] = str(cache_root)
+    env[compile_cache.ENV_JAX_CACHE_DIR] = str(cache_root)
     env.update(extra_env or {})
     src = compile_cache._CHILD_SRC.format(repo=REPO)
     proc = subprocess.run(

@@ -55,7 +55,7 @@ def test_context_from_empty_env_is_local():
     assert ctx.num_hosts == 1
     assert not ctx.is_distributed
     assert ctx.is_coordinator
-    assert ctx.accelerator == "cpu"
+    assert ctx.accelerator == ""  # undeclared — NOT "cpu": absence pins nothing
 
 
 def test_context_parses_controller_env():
@@ -87,6 +87,60 @@ def test_initialize_single_host_skips_handshake():
     # idempotent
     assert bootstrap.initialize() is ctx
     bootstrap._reset_for_tests()
+
+
+def _platform_pins(monkeypatch, environ):
+    """The jax_platforms values bootstrap.initialize(environ) sets."""
+    pins = []
+    real = jax.config.update
+
+    def spy(name, value):
+        if name == "jax_platforms":
+            pins.append(value)
+        return real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    bootstrap._reset_for_tests()
+    try:
+        bootstrap.initialize(environ=environ)
+    finally:
+        bootstrap._reset_for_tests()
+    return pins
+
+
+def test_platform_choice_cpu_pins_and_absence_pins_nothing(monkeypatch):
+    """The platform is chosen in bootstrap.initialize, from the accelerator
+    the manifest declared: "cpu" pins the CPU; an ABSENT variable pins
+    nothing (it used to pin the CPU, so a chip job whose env got lost
+    trained on the host)."""
+    assert _platform_pins(monkeypatch, {bootstrap.ENV_ACCELERATOR: "cpu"}) == ["cpu"]
+    assert _platform_pins(monkeypatch, {}) == []
+
+
+def test_tpu_family_on_a_cpu_backend_raises(monkeypatch):
+    """accelerator: v5e means the chip or an error — never whatever
+    platform $JAX_PLATFORMS allowed (this process's backend is the CPU)."""
+    with pytest.raises(RuntimeError, match="refusing to run a TPU job off"):
+        _platform_pins(monkeypatch, {bootstrap.ENV_ACCELERATOR: "v5e"})
+
+
+def test_worker_declared_for_tpu_exits_nonzero_on_cpu():
+    """The same check end to end: a worker given TPUJOB_ACCELERATOR=v5e
+    under JAX_PLATFORMS=cpu exits non-zero instead of computing."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(repo, "examples", "pi_worker.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             bootstrap.ENV_ACCELERATOR: "v5e"},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert "refusing to run a TPU job off the chip" in proc.stderr
+    assert "pi is approximately" not in proc.stdout
 
 
 def test_initialize_distributed_requires_coordinator():

@@ -9,13 +9,8 @@ same math (jax.disable_jit — an independent lowering of every op)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from mpi_operator_tpu.parallel import moe
-
-pytestmark = pytest.mark.skipif(
-    jax.default_backend() != "tpu", reason="needs a real TPU chip"
-)
 
 
 def _setup(key, b=4, t=256, d=128, d_ff=512, e=8):

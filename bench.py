@@ -253,14 +253,12 @@ def llama_per_chip_batch() -> int:
     """BENCH_BATCH with its coupled default: batch 10 only fits the 16 GiB
     chip because bf16 moments free ~1.6 GB — an f32-moment run
     (BENCH_MU_BF16=0) drops back to the batch-8 baseline unless BENCH_BATCH
-    overrides. One definition, shared with profile_llama.py so the profile
-    measures exactly the step the benchmark times."""
+    overrides."""
     return int(os.environ.get("BENCH_BATCH", "10" if _mu_bf16() else "8"))
 
 
 def llama_setup(per_chip_batch: int, seq_len: int):
-    """Build the llama bench workload (shared with profile_llama.py so the
-    profile measures exactly the step the benchmark times). Returns
+    """Build the llama bench workload. Returns
     (cfg, trainer, state, batch, global_batch)."""
     import jax
 

@@ -34,8 +34,6 @@ import os
 import sys
 import time
 
-_T_START = time.perf_counter()  # before the jax import: set-up time counts it
-
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import functools
@@ -108,7 +106,9 @@ def main():
     progress_every = int(os.environ.get("LLAMA_PROGRESS_EVERY", "0") or 0)
 
     # set-up facts for the report: the first batch is drawn once the state
-    # is built or restored, the second once step 1 has been dispatched
+    # is built or restored (set-up's spans end there: their sum is the
+    # seconds from the process's start), the second once step 1 has been
+    # dispatched
     setup = {}
 
     def batches_iter():
@@ -117,7 +117,7 @@ def main():
         )):
             if i == 0:
                 setup["first_dispatch_s"] = round(
-                    time.perf_counter() - _T_START, 2)
+                    sum(stepstats.setup_seconds().values()), 2)
                 setup["memory_after_init"] = _memory()
             elif i == 1:
                 setup["memory_after_step1"] = _memory()
@@ -140,7 +140,6 @@ def main():
         )
         return trainer.init_state(draw(jax.random.PRNGKey(0)))
 
-    t0 = time.perf_counter()
     if ckpt_dir:
         result = run_elastic(
             trainer,
@@ -154,21 +153,26 @@ def main():
             init_state=init_state,
         )
         outcome, last_step = result.outcome, result.last_step
-        steps_run = result.steps_run  # exclude checkpoint-restored progress
         start_step = result.start_step
         loss = (result.metrics or {}).get("loss")
     else:
-        state = init_state()
+        with stepstats.setup_span("init_state"):
+            state = init_state()
         for _ in range(steps):
             state, metrics = trainer.train_step(state, next(batches))
         jax.block_until_ready(metrics["loss"])
         outcome, last_step, loss = "done", steps, float(metrics["loss"])
-        steps_run = steps
         start_step = 0
 
-    dt = time.perf_counter() - t0
     # run_elastic's recorder flushed its last blob here on close
-    stats = stepstats.read_stats(os.environ.get(stepstats.ENV_STATS_FILE, ""))
+    stats = stepstats.read_stats(
+        os.environ.get(stepstats.ENV_STATS_FILE, "")) or {}
+    # tokens a step over the recorder's median step: under async dispatch
+    # the host's step-to-step time is the device's once the queue is full.
+    # Absent without a recorder (no checkpoint dir, or no stats file)
+    step_ms = stats.get("step_p50_ms") or 0.0
+    rate = ({"tokens_per_sec": round(
+        global_batch * seq_len / (step_ms / 1e3), 1)} if step_ms else {})
     if ctx.is_coordinator:
         print(
             json.dumps(
@@ -181,7 +185,7 @@ def main():
                     # second incarnation — checkpoint recovery actually ran
                     "start_step": start_step,
                     "loss": loss,
-                    "tokens_per_sec": round(global_batch * steps_run * seq_len / dt, 1),
+                    **rate,
                     "hosts": ctx.num_hosts,
                     "backend": jax.default_backend(),
                     "device_kind": jax.devices()[0].device_kind,
@@ -194,7 +198,9 @@ def main():
                     "global_batch": global_batch,
                     "seq_len": seq_len,
                     "compile_cache": compile_cache.cache_stats(),
-                    "buckets": (stats or {}).get("buckets"),
+                    "buckets": stats.get("buckets"),
+                    # this incarnation's set-up seconds by span
+                    "setup": stats.get("setup"),
                     **setup,
                     "memory_at_exit": _memory(),
                 }

@@ -189,6 +189,7 @@ def _flash_fwd(
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o[:, :, :t], lse
 
@@ -362,6 +363,7 @@ def _flash_bwd(
         out_shape=jax.ShapeDtypeStruct((b, h, n_qb * bq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q_p, k_p, v_p, do_p, lse, delta)[:, :, :t]
 
     # dk/dv per q-head (grid over k tiles, q innermost); kv grads group-sum
@@ -395,6 +397,7 @@ def _flash_bwd(
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q_p, k_p, v_p, do_p, lse, delta)
 
     dk = (

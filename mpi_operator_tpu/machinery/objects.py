@@ -416,6 +416,12 @@ TRAIN_BUCKETS = ("compile", "input", "compute", "sync", "ckpt")
 # the controller-side bucket: wall time a job spent torn down between
 # generations (evict → relaunch), which no worker process can observe
 BUCKET_RESTART = "restart"
+# the worker's set-up, before its first step: host seconds from the
+# process's start (as the OS records it) to the first batch, by what the
+# program was doing (runtime/stepstats.setup_span). Once per incarnation,
+# so every restart, rescale and rescheduler move shows what it paid.
+SETUP_SPANS = ("pre_bootstrap", "cache_config", "rendezvous", "attach",
+               "mesh", "ckpt_open", "init_state", "restore")
 
 _PROFILE_KEYS = ("id", "state", "dir")
 
@@ -449,7 +455,7 @@ def bounded_serve_stats(qps=0.0, queue_depth=0.0, p99_ms=0.0,
 
 
 def bounded_train_stats(step=0, steps=0, step_p50_ms=0.0, buckets=None,
-                        profile=None, compile_cache=None,
+                        profile=None, compile_cache=None, setup=None,
                         **_ignored) -> Dict[str, object]:
     """THE constructor for a pod's ``status.train_stats`` blob (oplint
     OBS004). Fixed key set, rounded floats, bucket keys clamped to the
@@ -461,7 +467,9 @@ def bounded_train_stats(step=0, steps=0, step_p50_ms=0.0, buckets=None,
     resume); ``steps`` counts steps run by THIS incarnation and
     ``buckets`` are THIS incarnation's cumulative attributed seconds —
     both reset on relaunch, which the aggregator's reset-aware deltas
-    expect (like a Prometheus counter across a process restart)."""
+    expect (like a Prometheus counter across a process restart).
+    ``setup`` is this incarnation's set-up seconds by span, clamped to
+    :data:`SETUP_SPANS`."""
     # the source may be a file written by an UNTRUSTED workload process
     # (the executor mirrors whatever the worker flushed): wrong-typed
     # fields degrade to zeros/absence, never an exception out of the
@@ -489,6 +497,12 @@ def bounded_train_stats(step=0, steps=0, step_p50_ms=0.0, buckets=None,
             "hits": _i(compile_cache.get("hits")),
             "misses": _i(compile_cache.get("misses")),
         }
+    if isinstance(setup, dict):
+        # this incarnation's set-up seconds by span: only the spans that
+        # ran (a fresh start has no `restore`), only the fixed key set
+        kept = {k: _r3(setup[k]) for k in SETUP_SPANS if k in setup}
+        if kept:
+            out["setup"] = kept
     return out
 
 

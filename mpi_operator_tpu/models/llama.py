@@ -245,12 +245,14 @@ def apply(
     # (XLA's TP-embedding gather + psum), the embed dim is gathered over
     # fsdp VOLUNTARILY here — otherwise the partitioner reshards the gather
     # output [.,.,fsdp] → [batch-sharded] by full rematerialization
-    emb = constrain(params["embed"]["w"].astype(dt), ["vocab", None])
-    x = emb[tokens]
-    x = constrain_fwd(x, ["batch", "seq", "embed"])
+    # the named scopes (embed, attention, mlp, head_loss) are metadata in
+    # each operation's `op_name`: a device trace reads time by scope
+    with jax.named_scope("embed"):
+        emb = constrain(params["embed"]["w"].astype(dt), ["vocab", None])
+        x = emb[tokens]
+        x = constrain_fwd(x, ["batch", "seq", "embed"])
 
-    def layer(carry, lp):
-        h = carry
+    def attention(h, lp):
         y = _rmsnorm(h, lp["attn_norm"]["scale"], c.norm_eps)
         b, t, _ = y.shape
         # K/V stay at n_kv_heads: every attention path is GQA-aware, so the
@@ -297,7 +299,9 @@ def apply(
                 )
             attn = attn.reshape(b, t, c.q_dim)
             h = h + attn @ lp["wo"]["w"].astype(dt)
-        h = constrain_fwd(h, ["batch", "seq", "embed"])
+        return constrain_fwd(h, ["batch", "seq", "embed"])
+
+    def mlp(h, lp):
         y = _rmsnorm(h, lp["mlp_norm"]["scale"], c.norm_eps)
         if c.matmul_precision == "bf16":
             gate = jax.nn.silu(y @ lp["w_gate"]["w"].astype(dt))
@@ -317,7 +321,13 @@ def apply(
             h = h + quant_matmul(
                 gate * up, lp["w_down"]["w"].astype(dt), precision=mp
             )
-        h = constrain_fwd(h, ["batch", "seq", "embed"])
+        return constrain_fwd(h, ["batch", "seq", "embed"])
+
+    def layer(carry, lp):
+        with jax.named_scope("attention"):
+            h = attention(carry, lp)
+        with jax.named_scope("mlp"):
+            h = mlp(h, lp)
         return h, None
 
     if c.remat_layers:
@@ -331,11 +341,12 @@ def apply(
             ),
         )
     x, _ = lax.scan(layer, x, params["layers"])
-    x = _rmsnorm(x, params["final_norm"]["scale"], c.norm_eps)
-    if return_features:
-        return x
-    logits = x @ params["lm_head"]["w"].astype(dt)
-    return logits.astype(jnp.float32)
+    with jax.named_scope("head_loss"):
+        x = _rmsnorm(x, params["final_norm"]["scale"], c.norm_eps)
+        if return_features:
+            return x
+        logits = x @ params["lm_head"]["w"].astype(dt)
+        return logits.astype(jnp.float32)
 
 
 def loss_fn(
@@ -359,15 +370,24 @@ def loss_fn(
     t = tokens.shape[1]
     if t - 1 <= ce_chunk:
         logits = apply(config, params, tokens, mesh=mesh, rules=rules)
-        targets = tokens[:, 1:]
-        lp = jax.nn.log_softmax(logits[:, :-1])
-        ll = jnp.take_along_axis(lp, targets[..., None], axis=-1)[..., 0]
-        return -jnp.mean(ll)
+        with jax.named_scope("head_loss"):
+            targets = tokens[:, 1:]
+            lp = jax.nn.log_softmax(logits[:, :-1])
+            ll = jnp.take_along_axis(lp, targets[..., None], axis=-1)[..., 0]
+            return -jnp.mean(ll)
 
     feats = apply(
         config, params, tokens, mesh=mesh, rules=rules, return_features=True
     )  # [B, T, D] compute dtype
-    head = params["lm_head"]["w"]
+    with jax.named_scope("head_loss"):
+        return _chunked_nll(
+            feats, params["lm_head"]["w"], tokens, ce_chunk
+        )
+
+
+def _chunked_nll(feats, head, tokens, ce_chunk):
+    """Mean next-token NLL from final-norm features [B,T,D], the lm_head
+    applied blockwise over the sequence (see :func:`loss_fn`)."""
     # shift targets by roll instead of slicing feats[:-1]/tokens[1:]:
     # keeping T intact aligns chunk boundaries with the (typically
     # power-of-two) sequence length so no repad is needed; the final

@@ -32,7 +32,7 @@ from typing import Any, Callable, Dict, Iterator, Optional
 from mpi_operator_tpu.ops.checkpoint import CheckpointManager
 from mpi_operator_tpu.ops.profiling import ProfileRequestWatcher, StepProfiler
 from mpi_operator_tpu.ops.trainer import Trainer, TrainState
-from mpi_operator_tpu.runtime.stepstats import StepStatsRecorder
+from mpi_operator_tpu.runtime.stepstats import StepStatsRecorder, setup_span
 
 # EX_TEMPFAIL: the "re-run me" exit code workers use on membership change.
 # Job specs pair it with restart_policy: ExitCode (the controller treats the
@@ -174,32 +174,39 @@ def run_elastic(
     # disposition), so nothing meaningful can race the clear.
     _PREEMPTED.clear()
     install_preemption_handler()
-    mgr = CheckpointManager(
-        config.checkpoint_dir,
-        save_interval_steps=config.save_interval_steps,
-    )
-    if mgr.latest_step() is not None:
+    # set-up spans (runtime/stepstats.py): host seconds of each part of
+    # what a start or a restart pays before its first step
+    with setup_span("ckpt_open"):
+        mgr = CheckpointManager(
+            config.checkpoint_dir,
+            save_interval_steps=config.save_interval_steps,
+        )
+        resume = mgr.latest_step() is not None
+    if resume:
         # restore INTO an abstract template (shapes, dtypes, this mesh's
         # shardings): a materialized one would hold a second full state in
         # device memory beside the restored one — two ~9.5 GB states do
         # not fit a 16 GB chip
-        state = mgr.restore(trainer.abstract_state(init_state))
+        with setup_span("restore"):
+            state = mgr.restore(trainer.abstract_state(init_state))
     else:
-        state = init_state()
+        with setup_span("init_state"):
+            state = init_state()
 
     # Track the step host-side: int(state.step) forces a device sync on a
     # jit output, which would serialize dispatch of step N+1 behind compute
     # of step N every iteration. One sync at restore, then a local counter.
     step = start_step = int(state.step)
     metrics = None
-    profiler = StepProfiler()  # no-op unless TPUJOB_PROFILE_DIR is set
     # the workload telemetry plane (ISSUE 15): every wall-second of every
     # step classifies into an attributed bucket — input wait, compute (the
     # first one lands in `compile`), membership sync, checkpoint save —
     # flushed to $TPUJOB_STEPSTATS_FILE for the executor to mirror into
-    # pod.status.train_stats. Two perf_counter calls per phase: the
-    # goodput bench pins the per-step cost at <=2% of step p50.
+    # pod.status.train_stats, with this process's set-up spans. Each phase
+    # is also a `tpujob.<bucket>` annotation in a profiler trace.
     stats = StepStatsRecorder.from_env()
+    # no-op unless TPUJOB_PROFILE_DIR is set; acks through the recorder
+    profiler = StepProfiler(stats=stats)
     # operator-triggered profiling: `ctl profile` stamps the annotation,
     # the controller projects it into the same config dir the membership
     # check polls; captures land under the job's artifact dir
@@ -247,8 +254,8 @@ def run_elastic(
         _final_checkpoint(mgr, stats, step, state)
     finally:
         prof_watch.close()
-        stats.close()
         profiler.close()
+        stats.close()
         mgr.close()
     return ElasticResult(
         "done",

@@ -44,12 +44,22 @@ ENV_STEPS = "TPUJOB_PROFILE_STEPS"
 PROFILE_REQUEST_FILE = "profile"
 
 
+# the id under which the env-driven capture acks in the train_stats
+# ``profile`` entry (an operator's request carries its own id)
+ENV_PROFILE_ID = "env"
+
+
 class StepProfiler:
     """Drive from a training loop: call observe(step) once per step; the
     trace starts/stops itself around the configured window. No-op (and
-    import-free) when TPUJOB_PROFILE_DIR is unset."""
+    import-free) when TPUJOB_PROFILE_DIR is unset. With a recorder
+    (``stats``), the capture acks through its ``profile`` entry like an
+    operator's request does — id ``env``, ``capturing`` then ``done``,
+    ``dir`` this host's trace directory — so whoever reads the blob learns
+    where the trace is."""
 
-    def __init__(self, directory: Optional[str] = None):
+    def __init__(self, directory: Optional[str] = None, *, stats=None):
+        self.stats = stats  # StepStatsRecorder, optional
         self.directory = directory if directory is not None else os.environ.get(ENV_DIR, "")
         self.start_step = int(os.environ.get(ENV_START, "10") or "10")
         self.num_steps = max(1, int(os.environ.get(ENV_STEPS, "5") or "5"))
@@ -73,18 +83,25 @@ class StepProfiler:
         if not self._active and self.start_step <= step < self.start_step + self.num_steps:
             jax.profiler.start_trace(self._trace_dir())
             self._active = True
+            self._ack("capturing")
         elif self._active and step >= self.start_step + self.num_steps:
-            jax.profiler.stop_trace()
-            self._active = False
-            self._done = True
+            self._stop()
+
+    def _ack(self, state: str) -> None:
+        if self.stats is not None:
+            self.stats.set_profile(ENV_PROFILE_ID, state, self._trace_dir())
+
+    def _stop(self) -> None:
+        import jax
+
+        jax.profiler.stop_trace()
+        self._active = False
+        self._done = True
+        self._ack("done")
 
     def close(self) -> None:
         if self._active:
-            import jax
-
-            jax.profiler.stop_trace()
-            self._active = False
-            self._done = True
+            self._stop()
 
 
 class ProfileRequestWatcher:

@@ -234,25 +234,30 @@ class Trainer:
 
     def _bare_step(self, state: TrainState, batch):
         """The un-jitted step body (shared by train_step and multi_step)."""
-        if self.has_model_state:
-            (loss, new_ms), grads = jax.value_and_grad(
-                self._loss_fn, has_aux=True
-            )(state.params, state.model_state, batch)
-        else:
-            loss, grads = jax.value_and_grad(self._loss_fn)(
-                state.params, batch
-            )
-            new_ms = state.model_state
-        updates, new_opt = self.tx.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
+        # the two scopes put the step's phases into every operation's
+        # `op_name` (metadata only): a device trace then splits forward,
+        # backward, layer replay and optimizer (PERF.md §3)
+        with jax.named_scope("model"):
+            if self.has_model_state:
+                (loss, new_ms), grads = jax.value_and_grad(
+                    self._loss_fn, has_aux=True
+                )(state.params, state.model_state, batch)
+            else:
+                loss, grads = jax.value_and_grad(self._loss_fn)(
+                    state.params, batch
+                )
+                new_ms = state.model_state
         metrics = {"loss": loss}
-        if self.config.grad_clip_norm > 0:
-            # free when clipping: XLA CSEs this with the clip's norm.
-            # When not clipping it would be an extra full pass over the
-            # gradients, so the metric is only emitted alongside a clip.
-            metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = self.tx.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
+            if self.config.grad_clip_norm > 0:
+                # free when clipping: XLA CSEs this with the clip's norm.
+                # When not clipping it would be an extra full pass over the
+                # gradients, so the metric is only emitted alongside a clip.
+                metrics["grad_norm"] = optax.global_norm(grads)
         return (
             TrainState(
                 step=state.step + 1,

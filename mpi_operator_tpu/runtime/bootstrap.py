@@ -156,11 +156,14 @@ def initialize(
     global _initialized_ctx
     if _initialized_ctx is not None:
         return _initialized_ctx
+    from mpi_operator_tpu.runtime import compile_cache, stepstats
+
+    # set-up's first span closes here: process start, `import jax`, the
+    # program's imports (runtime/stepstats.py)
+    stepstats.mark_pre_bootstrap()
     if ctx is None:
         ctx = context_from_env(environ)
     import jax
-
-    from mpi_operator_tpu.runtime import compile_cache
 
     # Everything before the rendezvous must leave jax's backends
     # uninitialized: jax.distributed.initialize refuses to run once one
@@ -170,7 +173,8 @@ def initialize(
     # point jax at the persistent compile cache BEFORE anything compiles —
     # a relaunched gang then reads its executables off disk instead of
     # repaying the warmup
-    compile_cache.configure_from_env(environ)
+    with stepstats.setup_span("cache_config"):
+        compile_cache.configure_from_env(environ)
     if ctx.is_distributed:
         if not ctx.coordinator_address:
             raise RuntimeError(
@@ -184,23 +188,27 @@ def initialize(
             ctx.num_hosts,
             ctx.coordinator_address,
         )
-        jax.distributed.initialize(
-            coordinator_address=ctx.coordinator_address,
-            num_processes=ctx.num_hosts,
-            process_id=ctx.host_id,
-        )
-    if ctx.accelerator not in ("", "cpu") and jax.default_backend() != "tpu":
+        with stepstats.setup_span("rendezvous"):
+            jax.distributed.initialize(
+                coordinator_address=ctx.coordinator_address,
+                num_processes=ctx.num_hosts,
+                process_id=ctx.host_id,
+            )
+    if ctx.accelerator not in ("", "cpu"):
         # every accelerator but the "cpu" test family names TPU hardware
         # (api/types.py HOST_BLOCK). No fallback: a job declared for a chip
         # must not train on whatever platform $JAX_PLATFORMS happened to
         # allow. After the rendezvous on purpose: default_backend()
-        # initializes the backend.
-        raise RuntimeError(
-            f"{ENV_ACCELERATOR}={ctx.accelerator} but jax's backend is "
-            f"{jax.default_backend()!r} (JAX_PLATFORMS="
-            f"{os.environ.get('JAX_PLATFORMS', '')!r}) — refusing to run a "
-            "TPU job off the chip"
-        )
+        # initializes the backend, which is the TPU attach.
+        with stepstats.setup_span("attach"):
+            backend = jax.default_backend()
+        if backend != "tpu":
+            raise RuntimeError(
+                f"{ENV_ACCELERATOR}={ctx.accelerator} but jax's backend is "
+                f"{backend!r} (JAX_PLATFORMS="
+                f"{os.environ.get('JAX_PLATFORMS', '')!r}) — refusing to "
+                "run a TPU job off the chip"
+            )
     _initialized_ctx = ctx
     return ctx
 

@@ -8,12 +8,27 @@ it is slow. This module is the worker-side half of the eyes: a
 its phases so every wall-second of every step classifies into exactly one
 attributed bucket of :data:`~mpi_operator_tpu.machinery.objects.TRAIN_BUCKETS`:
 
-- ``compile``  — the first compute dispatch (trace + XLA compile + run);
+- ``compile``  — the first compute dispatch (trace + XLA compile or the
+  cache read, and the enqueue);
 - ``input``    — waiting on ``next(batches)`` (the input pipeline);
-- ``compute``  — the jitted step (dispatch + the implicit block on the
-  previous step's donated buffers: steady-state device time);
+- ``compute``  — the call of the jitted step: ENQUEUE time under JAX's
+  async dispatch, plus whatever the call waits for (the previous step's
+  donated buffers). A host-clock share, not a device time: the step's
+  device time comes from a profiler trace (PERF.md §3);
 - ``sync``     — the gang-uniform membership/preemption allgather;
 - ``ckpt``     — checkpoint saves (periodic and forced).
+
+Set-up, before the first step, is a second fixed key set
+(:data:`~mpi_operator_tpu.machinery.objects.SETUP_SPANS`): host seconds
+from the process's start to the first batch, recorded by the module-level
+:func:`setup_span` (the recorder does not exist yet when most of them
+run) and carried in the blob's ``setup`` field.
+
+Every phase and set-up span is also a ``jax.profiler.TraceAnnotation``
+(``tpujob.<name>``) where jax is loaded, so a trace taken by
+``StepProfiler`` or ``ctl profile`` shows what the step loop was doing on
+the device's own timeline. The executor and the controller import this
+module without jax and get no annotation.
 
 The recorder accumulates cumulative per-incarnation totals plus a rolling
 step-time window, and flushes a BOUNDED blob (``bounded_train_stats``,
@@ -26,10 +41,10 @@ reading cAdvisor. The controller-side goodput aggregator
 (controller/goodput.py) rolls the per-pod blobs up into per-job goodput,
 dominant-stall attribution and straggler detection.
 
-Overhead budget: two ``perf_counter`` calls per phase plus one dict add —
-single-digit microseconds per step against millisecond-scale steps; the
-goodput bench (BENCH_CP_MODES=goodput) pins the measured per-step cost at
-<=2% of the real llama step p50.
+Overhead: two ``perf_counter`` calls, one annotation (half a microsecond
+with the profiler off, on this sandbox's CPU) and one dict add per phase,
+three phases a step. What that costs a step on the chip is what the
+benchmark's cells measure with the profiler off and on (PERF.md §6).
 
 ``python -m mpi_operator_tpu.runtime.stepstats --smoke`` is the <30s
 verify-gate check: one hollow gang with a seeded input-stall timeline
@@ -44,10 +59,12 @@ import contextlib
 import json
 import logging
 import os
+import sys
 import time
 from typing import Any, Dict, Optional
 
 from mpi_operator_tpu.machinery.objects import (
+    SETUP_SPANS,
     TRAIN_BUCKETS,
     bounded_train_stats,
 )
@@ -60,6 +77,64 @@ log = logging.getLogger("tpujob.stepstats")
 ENV_STATS_FILE = "TPUJOB_STEPSTATS_FILE"
 ENV_STATS_INTERVAL = "TPUJOB_STEPSTATS_INTERVAL"
 DEFAULT_FLUSH_INTERVAL = 1.0
+
+ANNOTATION_PREFIX = "tpujob."
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation("tpujob.<name>")`` where jax is
+    already loaded, else nothing: this module never imports jax itself
+    (the executor and the controller import it and have none)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+
+
+# this process's set-up seconds by span. Module-level because set-up runs
+# before any recorder exists (bootstrap, the mesh) and is per process by
+# nature: one incarnation, one set-up.
+_setup: Dict[str, float] = {}
+
+
+@contextlib.contextmanager
+def setup_span(name: str):
+    """Attribute the enclosed host seconds to set-up span ``name`` (one of
+    SETUP_SPANS) and show it in a running profiler trace. Host clock only:
+    work the span merely enqueues on the device is not waited for."""
+    if name not in SETUP_SPANS:
+        raise ValueError(f"unknown set-up span {name!r} (one of {SETUP_SPANS})")
+    t0 = time.perf_counter()
+    try:
+        with _annotation(name):
+            yield
+    finally:
+        _setup[name] = _setup.get(name, 0.0) + time.perf_counter() - t0
+
+
+def mark_pre_bootstrap() -> None:
+    """Record ``pre_bootstrap``: from the process's start as the OS has it
+    (``/proc/self/stat``, in clock ticks since boot) to now — the
+    interpreter, ``import jax`` and the program's own imports, which no
+    clock inside the program can see. Left out where /proc is not there."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            # the command may hold spaces: fields are counted after its ")"
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime", encoding="ascii") as f:
+            uptime = float(f.read().split()[0])
+        age = uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return
+    _setup["pre_bootstrap"] = max(0.0, age)
+
+
+def setup_seconds() -> Dict[str, float]:
+    return dict(_setup)
+
+
+def _reset_for_tests() -> None:
+    _setup.clear()
 
 
 class StepStatsRecorder:
@@ -116,14 +191,15 @@ class StepStatsRecorder:
         wall time is trace+compile+run, and charging it to compute would
         poison every small-N step average (the restart warmup's compile
         share must be visible as ITS OWN bucket)."""
+        if bucket == "compute" and not self._compiled:
+            self._compiled = True
+            bucket = "compile"
         t0 = self._clock()
         try:
-            yield
+            with _annotation(bucket):
+                yield
         finally:
             dt = self._clock() - t0
-            if bucket == "compute" and not self._compiled:
-                self._compiled = True
-                bucket = "compile"
             self._buckets[bucket] = self._buckets.get(bucket, 0.0) + dt
 
     def step_done(self, step: Optional[int] = None) -> None:
@@ -166,6 +242,7 @@ class StepStatsRecorder:
             # `compile` bucket as warm-vs-cold instead of just big-vs-small
             compile_cache=(compile_cache.cache_stats()
                            if compile_cache.is_configured() else None),
+            setup=_setup,
         )
 
     def flush(self, force: bool = False, now: Optional[float] = None) -> None:

@@ -210,28 +210,36 @@ def mesh_from_context(
     """
     import jax
 
-    if ctx is not None and ctx.chips_per_host:
-        expected = ctx.num_hosts * ctx.chips_per_host
-        if expected != jax.device_count():
-            raise RuntimeError(
-                f"gang declares {ctx.num_hosts} hosts × {ctx.chips_per_host} "
-                f"chips = {expected} devices but XLA sees "
-                f"{jax.device_count()} — rendezvous and placement disagree"
+    # not at module level: `python -m ...runtime.stepstats` imports this
+    # package first, and would then run a second copy of that module
+    from mpi_operator_tpu.runtime import stepstats
+
+    with stepstats.setup_span("mesh"):
+        if ctx is not None and ctx.chips_per_host:
+            expected = ctx.num_hosts * ctx.chips_per_host
+            if expected != jax.device_count():
+                raise RuntimeError(
+                    f"gang declares {ctx.num_hosts} hosts × "
+                    f"{ctx.chips_per_host} chips = {expected} devices but "
+                    f"XLA sees {jax.device_count()} — rendezvous and "
+                    "placement disagree"
+                )
+        ns = getattr(ctx, "num_slices", 1) if ctx is not None else 1
+        if plan is None:
+            n = jax.device_count()
+            if ns > 1 and n % ns == 0:
+                plan = MeshPlan(
+                    axes={AXIS_DATA: n // ns}, dcn={AXIS_DATA: ns})
+            else:
+                plan = MeshPlan.data_parallel(n)
+        elif ns > 1 and plan.dcn_size != ns:
+            # an explicit plan on a multi-slice gang MUST name the DCN
+            # factor: silently flattening the slices would let inner mesh
+            # axes span the slice boundary and put per-layer collectives on
+            # DCN instead of ICI — the invariant this module exists to uphold
+            raise ValueError(
+                f"gang spans {ns} slices but the mesh plan's DCN factor is "
+                f"{plan.dcn_size}; declare it "
+                f"(e.g. LLAMA_MESH_DCN='data={ns}')"
             )
-    ns = getattr(ctx, "num_slices", 1) if ctx is not None else 1
-    if plan is None:
-        n = jax.device_count()
-        if ns > 1 and n % ns == 0:
-            plan = MeshPlan(axes={AXIS_DATA: n // ns}, dcn={AXIS_DATA: ns})
-        else:
-            plan = MeshPlan.data_parallel(n)
-    elif ns > 1 and plan.dcn_size != ns:
-        # an explicit plan on a multi-slice gang MUST name the DCN factor:
-        # silently flattening the slices would let inner mesh axes span the
-        # slice boundary and put per-layer collectives on DCN instead of
-        # ICI — the invariant this module exists to uphold
-        raise ValueError(
-            f"gang spans {ns} slices but the mesh plan's DCN factor is "
-            f"{plan.dcn_size}; declare it (e.g. LLAMA_MESH_DCN='data={ns}')"
-        )
-    return build_mesh(plan)
+        return build_mesh(plan)

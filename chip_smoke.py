@@ -173,8 +173,9 @@ def show(name: str, report: dict, ckpt_dir: str, tiny: bool) -> None:
         "global_batch", "seq_len")}
     if not tiny:  # the loss, seconds and bytes: facts of a chip run only
         shown.update({k: report.get(k) for k in (
-            "loss", "first_dispatch_s", "buckets", "memory_after_init",
-            "memory_after_step1", "memory_at_exit")})
+            "loss", "first_dispatch_s", "setup", "setup_overlapped",
+            "buckets", "memory_after_init", "memory_after_step1",
+            "memory_at_exit")})
     n_files, largest, saved = checkpoint_files(ckpt_dir)
     print(f"chip_smoke: {name}: {json.dumps(shown)}")
     print(f"chip_smoke: {name}: checkpoints {saved}, {n_files} files, "

@@ -201,6 +201,9 @@ def main():
                     "buckets": stats.get("buckets"),
                     # this incarnation's set-up seconds by span
                     "setup": stats.get("setup"),
+                    # and what ran beside them on another thread (orbax's
+                    # background import)
+                    "setup_overlapped": stats.get("setup_overlapped"),
                     **setup,
                     "memory_at_exit": _memory(),
                 }

@@ -422,6 +422,12 @@ BUCKET_RESTART = "restart"
 # so every restart, rescale and rescheduler move shows what it paid.
 SETUP_SPANS = ("pre_bootstrap", "cache_config", "rendezvous", "attach",
                "mesh", "ckpt_open", "init_state", "restore")
+# what set-up did on another thread WHILE those spans ran: its own wall
+# seconds, beside `setup` and never inside it (the spans stay additive).
+# `ckpt_import` is the background `import orbax.checkpoint`
+# (runtime/bootstrap.py); against `ckpt_open` it says how much of the
+# import a start hid.
+SETUP_OVERLAPPED = ("ckpt_import",)
 
 _PROFILE_KEYS = ("id", "state", "dir")
 
@@ -456,6 +462,7 @@ def bounded_serve_stats(qps=0.0, queue_depth=0.0, p99_ms=0.0,
 
 def bounded_train_stats(step=0, steps=0, step_p50_ms=0.0, buckets=None,
                         profile=None, compile_cache=None, setup=None,
+                        setup_overlapped=None,
                         **_ignored) -> Dict[str, object]:
     """THE constructor for a pod's ``status.train_stats`` blob (oplint
     OBS004). Fixed key set, rounded floats, bucket keys clamped to the
@@ -469,7 +476,8 @@ def bounded_train_stats(step=0, steps=0, step_p50_ms=0.0, buckets=None,
     both reset on relaunch, which the aggregator's reset-aware deltas
     expect (like a Prometheus counter across a process restart).
     ``setup`` is this incarnation's set-up seconds by span, clamped to
-    :data:`SETUP_SPANS`."""
+    :data:`SETUP_SPANS`; ``setup_overlapped`` the seconds of what ran on
+    another thread meanwhile, clamped to :data:`SETUP_OVERLAPPED`."""
     # the source may be a file written by an UNTRUSTED workload process
     # (the executor mirrors whatever the worker flushed): wrong-typed
     # fields degrade to zeros/absence, never an exception out of the
@@ -497,12 +505,17 @@ def bounded_train_stats(step=0, steps=0, step_p50_ms=0.0, buckets=None,
             "hits": _i(compile_cache.get("hits")),
             "misses": _i(compile_cache.get("misses")),
         }
-    if isinstance(setup, dict):
-        # this incarnation's set-up seconds by span: only the spans that
-        # ran (a fresh start has no `restore`), only the fixed key set
-        kept = {k: _r3(setup[k]) for k in SETUP_SPANS if k in setup}
-        if kept:
-            out["setup"] = kept
+    # this incarnation's set-up seconds by span, and beside them what ran
+    # on another thread meanwhile: only what ran (a fresh start has no
+    # `restore`; an import still running has its seconds so far), only
+    # the fixed key sets
+    for field, given, keys in (("setup", setup, SETUP_SPANS),
+                               ("setup_overlapped", setup_overlapped,
+                                SETUP_OVERLAPPED)):
+        if isinstance(given, dict):
+            kept = {k: _r3(given[k]) for k in keys if k in given}
+            if kept:
+                out[field] = kept
     return out
 
 

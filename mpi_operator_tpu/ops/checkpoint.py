@@ -8,7 +8,15 @@ primitive (SURVEY.md §7 phase 7): scale events save → re-mesh → restore.
 
 Restore is *reshard-on-load*: the target shardings come from the new mesh,
 so a checkpoint written on 16 hosts restores cleanly onto 8 or 32 — this is
-exactly the elastic-resume path the controller's scale-up/down drives."""
+exactly the elastic-resume path the controller's scale-up/down drives.
+
+Orbax is off the start's critical path. Its import takes seconds and
+needs neither the chip nor the state: ``bootstrap.initialize`` starts it
+on a background thread before the TPU attaches, and this module gets it
+through ``bootstrap.orbax_checkpoint()``, which joins that thread (or
+imports then and there where no ``initialize`` ran). The join is made at
+the first use of orbax and no earlier: on a resume that is the start's
+``latest_step()``, on a fresh directory the first save."""
 
 from __future__ import annotations
 
@@ -17,6 +25,8 @@ import resource
 from typing import Any, Optional
 
 import jax
+
+from mpi_operator_tpu.runtime.bootstrap import orbax_checkpoint
 
 # Saves are cut into bounded OCDBT data files: orbax's default lets one
 # file grow to 2 GiB, and a multi-hundred-MB single file is a poor unit on
@@ -38,7 +48,15 @@ def _data_file_target() -> int:
 
 class CheckpointManager:
     """Thin wrapper over orbax's CheckpointManager pinned to this
-    framework's TrainState layout and elastic-resume semantics."""
+    framework's TrainState layout and elastic-resume semantics.
+
+    Orbax's manager is built at the first call that needs it
+    (``latest_step()`` on a directory that holds anything, ``save``,
+    ``restore``, ``wait``), not here: the constructor only makes the
+    directory, so that one that cannot be written fails the start and not
+    the first save. On several hosts orbax's manager makes a barrier when
+    it is built; every host sees the same shared directory, so all build
+    it at the same call."""
 
     def __init__(
         self,
@@ -48,27 +66,34 @@ class CheckpointManager:
         save_interval_steps: int = 1000,
         async_save: bool = True,
     ):
-        import orbax.checkpoint as ocp
-
-        self._ocp = ocp
         self.directory = os.path.abspath(directory)
-        self.manager = ocp.CheckpointManager(
-            self.directory,
-            options=ocp.CheckpointManagerOptions(
-                max_to_keep=max_to_keep,
-                save_interval_steps=save_interval_steps,
-                create=True,
-                # async commit (ISSUE 16): save() returns once the device
-                # arrays are snapshotted host-side; serialization to disk
-                # overlaps the NEXT steps on orbax's background thread.
-                # The step loop then charges only that blocking snapshot
-                # slice to its `ckpt` bucket — the commit costs goodput
-                # nothing. Durability is unchanged WHERE IT MATTERS: the
-                # sanctioned seams (SIGTERM force-checkpoint, terminal
-                # exit, pre-restore) call wait() to fence the commit.
-                enable_async_checkpointing=async_save,
-            ),
+        os.makedirs(self.directory, exist_ok=True)
+        self._options = dict(
+            max_to_keep=max_to_keep,
+            save_interval_steps=save_interval_steps,
+            # async commit (ISSUE 16): save() returns once the device
+            # arrays are snapshotted host-side; serialization to disk
+            # overlaps the NEXT steps on orbax's background thread.
+            # The step loop then charges only that blocking snapshot
+            # slice to its `ckpt` bucket — the commit costs goodput
+            # nothing. Durability is unchanged WHERE IT MATTERS: the
+            # sanctioned seams (SIGTERM force-checkpoint, terminal
+            # exit, pre-restore) call wait() to fence the commit.
+            enable_async_checkpointing=async_save,
         )
+        self._manager: Any = None
+
+    @property
+    def manager(self) -> Any:
+        """Orbax's manager, built on first use (joins orbax's import)."""
+        if self._manager is None:
+            ocp = orbax_checkpoint()
+            self._manager = ocp.CheckpointManager(
+                self.directory,
+                options=ocp.CheckpointManagerOptions(
+                    create=True, **self._options),
+            )
+        return self._manager
 
     def save(self, step: int, state: Any, *, force: bool = False) -> bool:
         """Save if the step hits the interval (or force). Multi-host safe:
@@ -76,9 +101,10 @@ class CheckpointManager:
         With ``async_save`` (the default) this returns after the blocking
         device→host snapshot; the disk commit overlaps later steps and is
         fenced by :meth:`wait`."""
+        ocp = orbax_checkpoint()
         saved = self.manager.save(
             step,
-            args=self._ocp.args.PyTreeSave(
+            args=ocp.args.PyTreeSave(
                 state, ocdbt_target_data_file_size=_data_file_target()
             ),
             force=force,
@@ -86,6 +112,14 @@ class CheckpointManager:
         return bool(saved)
 
     def latest_step(self) -> Optional[int]:
+        """The newest committed step, or None. A directory with no entry
+        at all holds no step, and says so without orbax: that is a fresh
+        start, which then needs orbax at its first save and no earlier.
+        Anything in it (a step, a leftover temporary step, a stray file)
+        is orbax's to judge: which entries count as a committed step is
+        its rule, and none of it is copied here."""
+        if self._manager is None and not os.listdir(self.directory):
+            return None
         return self.manager.latest_step()
 
     def restore(self, state_template: Any, *, step: Optional[int] = None) -> Any:
@@ -109,11 +143,12 @@ class CheckpointManager:
             else x,
             state_template,
         )
+        ocp = orbax_checkpoint()
         return self.manager.restore(
             step,
-            args=self._ocp.args.PyTreeRestore(
+            args=ocp.args.PyTreeRestore(
                 abstract,
-                restore_args=self._ocp.checkpoint_utils.construct_restore_args(
+                restore_args=ocp.checkpoint_utils.construct_restore_args(
                     abstract
                 ),
             ),
@@ -124,4 +159,5 @@ class CheckpointManager:
         self.manager.wait_until_finished()
 
     def close(self) -> None:
-        self.manager.close()
+        if self._manager is not None:
+            self._manager.close()

@@ -175,7 +175,11 @@ def run_elastic(
     _PREEMPTED.clear()
     install_preemption_handler()
     # set-up spans (runtime/stepstats.py): host seconds of each part of
-    # what a start or a restart pays before its first step
+    # what a start or a restart pays before its first step. `ckpt_open`
+    # on a fresh start is a makedirs and a listdir: an empty directory
+    # has no step, and orbax waits for the first save. On a resume it
+    # holds the wait for what is left of orbax's background import
+    # (bootstrap.initialize) and orbax's own look at the directory.
     with setup_span("ckpt_open"):
         mgr = CheckpointManager(
             config.checkpoint_dir,

@@ -17,9 +17,12 @@ framework's replacement for ``OMPI_MCA_orte_default_hostfile`` /
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import logging
 import os
-from typing import Mapping, Optional, Tuple
+import threading
+import time
+from typing import Any, Dict, Mapping, Optional, Tuple
 
 log = logging.getLogger(__name__)
 
@@ -131,7 +134,76 @@ def default_checkpoint_dir(
     return os.path.join(base, ctx.namespace, ctx.job_name)
 
 
+class _BackgroundImport:
+    """``import <name>`` on a daemon thread, joined at the first use.
+
+    Orbax's import takes seconds (12.6 s on the v5e's machine, PERF.md §5)
+    and needs neither the chip nor the state, so it runs beside the
+    rendezvous and the TPU attach instead of after them. The thread keeps
+    its own wall seconds and whatever the import raised.
+    """
+
+    def __init__(self, name: str):
+        self.name = name
+        self._module: Any = None
+        self._error: Optional[BaseException] = None
+        self._started = time.perf_counter()
+        self._ended: Optional[float] = None  # set when the import ends
+        self._thread = threading.Thread(
+            target=self._run, name=f"import-{name}", daemon=True)
+        self._thread.start()
+
+    @property
+    def seconds(self) -> float:
+        """Wall seconds the import has run: up to now while it runs, its
+        whole once it has ended. On the chip's machine it outlasts set-up
+        (26-29 s beside the attach and the first steps, PERF.md §5), and
+        a blob flushed meanwhile says how far it has come, like every
+        other count in it."""
+        ended = self._ended
+        return (time.perf_counter() if ended is None else ended) \
+            - self._started
+
+    def _run(self) -> None:
+        try:
+            self._module = importlib.import_module(self.name)
+        except BaseException as e:
+            # kept for the caller: module() raises it at the first use
+            self._error = e
+            log.warning("background import of %s failed; it is raised at "
+                        "the first use", self.name, exc_info=True)
+        finally:
+            self._ended = time.perf_counter()
+
+    def module(self) -> Any:
+        """The imported module, once the thread is done; what the import
+        raised is raised here, on the caller's thread, unchanged."""
+        self._thread.join()
+        if self._error is not None:
+            raise self._error
+        return self._module
+
+
 _initialized_ctx: Optional[RuntimeContext] = None
+_orbax_import: Optional[_BackgroundImport] = None
+
+
+def orbax_checkpoint() -> Any:
+    """The ``orbax.checkpoint`` module: joins the import that
+    :func:`initialize` started in the background; where none was started
+    (a library caller with no ``initialize`` before it) imports it here."""
+    if _orbax_import is None:
+        return importlib.import_module("orbax.checkpoint")
+    return _orbax_import.module()
+
+
+def setup_overlapped_seconds() -> Dict[str, float]:
+    """Wall seconds of what set-up ran on another thread, for the stats
+    blob's ``setup_overlapped`` (machinery/objects.SETUP_OVERLAPPED):
+    the background import's own, so far while it still runs."""
+    if _orbax_import is None:
+        return {}
+    return {"ckpt_import": _orbax_import.seconds}
 
 
 def initialize(
@@ -153,7 +225,7 @@ def initialize(
     unless jax came up on a TPU; undeclared (a script run outside the
     operator) pins and checks nothing.
     """
-    global _initialized_ctx
+    global _initialized_ctx, _orbax_import
     if _initialized_ctx is not None:
         return _initialized_ctx
     from mpi_operator_tpu.runtime import compile_cache, stepstats
@@ -167,7 +239,11 @@ def initialize(
 
     # Everything before the rendezvous must leave jax's backends
     # uninitialized: jax.distributed.initialize refuses to run once one
-    # exists.
+    # exists. That holds for the import started here too (a test pins
+    # it): it runs beside the rendezvous and the attach, and
+    # ops/checkpoint.py joins it at the first use of a checkpoint.
+    if _orbax_import is None:
+        _orbax_import = _BackgroundImport("orbax.checkpoint")
     if ctx.accelerator == "cpu":
         jax.config.update("jax_platforms", "cpu")
     # point jax at the persistent compile cache BEFORE anything compiles —
@@ -218,8 +294,9 @@ def active_context() -> Optional[RuntimeContext]:
 
 
 def _reset_for_tests() -> None:
-    global _initialized_ctx
+    global _initialized_ctx, _orbax_import
     from mpi_operator_tpu.runtime import compile_cache
 
     _initialized_ctx = None
+    _orbax_import = None
     compile_cache._reset_for_tests()

@@ -22,7 +22,12 @@ Set-up, before the first step, is a second fixed key set
 (:data:`~mpi_operator_tpu.machinery.objects.SETUP_SPANS`): host seconds
 from the process's start to the first batch, recorded by the module-level
 :func:`setup_span` (the recorder does not exist yet when most of them
-run) and carried in the blob's ``setup`` field.
+run) and carried in the blob's ``setup`` field. What set-up runs on
+another thread while those spans pass (the background ``import
+orbax.checkpoint``, runtime/bootstrap.py) keeps its own wall seconds,
+which the blob carries in ``setup_overlapped``
+(:data:`~mpi_operator_tpu.machinery.objects.SETUP_OVERLAPPED`): beside
+``setup``, never inside it, so the spans stay additive.
 
 Every phase and set-up span is also a ``jax.profiler.TraceAnnotation``
 (``tpujob.<name>``) where jax is loaded, so a trace taken by
@@ -231,7 +236,7 @@ class StepStatsRecorder:
 
     def snapshot(self) -> Dict[str, Any]:
         """The bounded blob (exactly what lands in status.train_stats)."""
-        from mpi_operator_tpu.runtime import compile_cache
+        from mpi_operator_tpu.runtime import bootstrap, compile_cache
 
         return bounded_train_stats(
             step=self._step, steps=self._steps,
@@ -243,6 +248,7 @@ class StepStatsRecorder:
             compile_cache=(compile_cache.cache_stats()
                            if compile_cache.is_configured() else None),
             setup=_setup,
+            setup_overlapped=bootstrap.setup_overlapped_seconds(),
         )
 
     def flush(self, force: bool = False, now: Optional[float] = None) -> None:

@@ -4,3 +4,4 @@ there (PERF.md §7)."""
 
 from benchmark.tests.test_benchmark import *  # noqa: F401,F403
 from benchmark.tests.test_named_trace import *  # noqa: F401,F403
+from benchmark.tests.test_ckpt_import_s import *  # noqa: F401,F403

@@ -9,6 +9,6 @@ interpret-mode path so the CPU test suite checks numerics.
 """
 
 from mpi_operator_tpu.kernels.flash_attention import flash_attention
-from mpi_operator_tpu.kernels.quant_matmul import quant_matmul
+from mpi_operator_tpu.kernels.quant_matmul import quant_matmul, quant_ragged_dot
 
-__all__ = ["flash_attention", "quant_matmul"]
+__all__ = ["flash_attention", "quant_matmul", "quant_ragged_dot"]

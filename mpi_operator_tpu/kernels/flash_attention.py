@@ -39,6 +39,12 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
+def _window_kw(window):
+    """The kernels' ``window`` keyword, only where there is one: without it
+    the partials, and so the Mosaic bodies, are what they were."""
+    return {} if window is None else {"window": int(window)}
+
+
 def _pad_t(x, t_pad: int):
     """Zero-pad dim 2 (sequence) of [B,H,T,D]-like arrays up to t_pad."""
     t = x.shape[2]
@@ -69,10 +75,62 @@ def _causal_first_q_tile(ki, bq: int, bk: int):
     return (ki * bk) // bq
 
 
+# A window adds the band's other side: query i sees key j iff
+# i - window < j <= i. A tile is then computed iff it is causally open AND
+# (ki + 1) * bk + window - 1 > qi * bq ("band open"): the tile's last key
+# lies inside the window of the tile's first query. The clamps below are
+# the same inequality solved for ki and for qi.
+
+
+def _band_open(qi, ki, bq: int, bk: int, window: int):
+    """True iff k tile ki reaches into the window of q tile qi."""
+    return (ki + 1) * bk + window - 1 > qi * bq
+
+
+def _band_first_k_tile(qi, bq: int, bk: int, window: int):
+    """Smallest ki with _band_open(qi, ki): the tile that holds the oldest
+    key the tile's first query sees."""
+    return jnp.maximum(qi * bq - window + 1, 0) // bk
+
+
+def _band_last_q_tile(ki, bq: int, bk: int, window: int):
+    """Largest qi with _band_open(qi, ki) (the caller clips it to the
+    grid): the tile of the last query that still sees the tile's last key."""
+    return ((ki + 1) * bk + window - 2) // bq
+
+
+def _tile_open(qi, ki, bq: int, bk: int, causal: bool, window):
+    """The kernels' compute-skip predicate; ``True`` (a Python bool) where
+    nothing is skipped."""
+    is_open = _causal_open(qi, ki, bq, bk) if causal else True
+    if window is not None:
+        is_open = jnp.logical_and(is_open, _band_open(qi, ki, bq, bk, window))
+    return is_open
+
+
+def _k_tile_clamp(qi, ki, bq: int, bk: int, causal: bool, window):
+    """ki clamped to the tiles q tile qi uses: a clamped index repeats on
+    skipped grid steps, so pallas elides their DMAs."""
+    if causal:
+        ki = jnp.minimum(ki, _causal_last_k_tile(qi, bq, bk))
+    if window is not None:
+        ki = jnp.maximum(ki, _band_first_k_tile(qi, bq, bk, window))
+    return ki
+
+
+def _q_tile_clamp(ki, qi, bq: int, bk: int, causal: bool, window):
+    """qi clamped to the tiles k tile ki uses (dk/dv streams q tiles)."""
+    if causal:
+        qi = jnp.maximum(qi, _causal_first_q_tile(ki, bq, bk))
+    if window is not None:
+        qi = jnp.minimum(qi, _band_last_q_tile(ki, bq, bk, window))
+    return qi
+
+
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     block_q: int, block_k: int, n_kb: int, causal: bool, scale: float,
-    t_real: int,
+    t_real: int, window=None,
 ):
     """One grid step folds one (q-tile, k-tile) pair. Grid (b, h, qi, ki),
     ki innermost: the f32 scratch (acc, m, l) carries the online softmax
@@ -88,8 +146,9 @@ def _fwd_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     # causal: tiles strictly above the diagonal contribute nothing; skip
-    # their compute (the matching index-map clamp elides their DMAs too)
-    diag_open = _causal_open(qi, ki, block_q, block_k) if causal else True
+    # their compute (the matching index-map clamp elides their DMAs too).
+    # A window skips the tiles wholly older than the band the same way.
+    diag_open = _tile_open(qi, ki, block_q, block_k, causal, window)
 
     @pl.when(diag_open)
     def _fold():
@@ -108,6 +167,8 @@ def _fwd_kernel(
                 jnp.int32, (block_q, block_k), 0
             )
             valid = jnp.logical_and(valid, q_idx >= k_idx)
+            if window is not None:  # the two edge tiles of the band
+                valid = jnp.logical_and(valid, q_idx - k_idx < window)
         s = jnp.where(valid, s, _NEG_INF)
         m_prev = m_ref[:, 0]
         l_prev = l_ref[:, 0]
@@ -132,7 +193,7 @@ def _fwd_kernel(
 
 def _flash_fwd(
     q, k, v, *, causal: bool, scale: float, block_q: int, block_k: int,
-    interpret: bool,
+    interpret: bool, window=None,
 ):
     """q [B,H,T,D], k/v [B,Hkv,T,D] → (o [B,H,T,D], lse [B,H,Tq_pad,1])."""
     b, h, t, d = q.shape
@@ -155,17 +216,16 @@ def _flash_fwd(
 
     kernel = functools.partial(
         _fwd_kernel, block_q=bq, block_k=bk, n_kb=n_kb, causal=causal,
-        scale=scale, t_real=t,
+        scale=scale, t_real=t, **_window_kw(window),
     )
 
     # causal: a k tile strictly above the diagonal is skipped by the kernel
     # (pl.when) — clamping its block index to the last USED tile makes the
     # index map repeat, so pallas elides the DMA too. ~2x less K/V traffic
-    # at long T (the causally-dead half of the rectangle grid).
+    # at long T (the causally-dead half of the rectangle grid). A window
+    # clamps from below too: only the band's tiles are ever fetched.
     def kv_index(bi, hi, qi, ki):
-        if causal:
-            ki = jnp.minimum(ki, _causal_last_k_tile(qi, bq, bk))
-        return (bi, hi // g, ki, 0)
+        return (bi, hi // g, _k_tile_clamp(qi, ki, bq, bk, causal, window), 0)
 
     o, lse = pl.pallas_call(
         kernel,
@@ -197,7 +257,7 @@ def _flash_fwd(
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref, *,
     block_q: int, block_k: int, n_kb: int, causal: bool, scale: float,
-    t_real: int,
+    t_real: int, window=None,
 ):
     """dq: grid (b, h, qi, ki) streams K/V tiles past each q tile,
     recomputing P on-chip from the saved LSE. Refs: q/do/dq [1,1,BQ,D],
@@ -209,7 +269,7 @@ def _bwd_dq_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    diag_open = _causal_open(qi, ki, block_q, block_k) if causal else True
+    diag_open = _tile_open(qi, ki, block_q, block_k, causal, window)
 
     @pl.when(diag_open)
     def _fold():
@@ -231,6 +291,8 @@ def _bwd_dq_kernel(
                 jnp.int32, (block_q, block_k), 0
             )
             valid = jnp.logical_and(valid, q_idx >= k_idx)
+            if window is not None:  # the two edge tiles of the band
+                valid = jnp.logical_and(valid, q_idx - k_idx < window)
         # p rows are already normalized: lse folds in the denominator
         p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)
         dp = jax.lax.dot_general(
@@ -250,7 +312,7 @@ def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc_ref, dv_acc_ref, *,
     block_q: int, block_k: int, n_qb: int, causal: bool, scale: float,
-    t_real: int,
+    t_real: int, window=None,
 ):
     """dk/dv: grid (b, h, ki, qi) streams Q/dO tiles past each k tile. GQA:
     outputs are per *q* head; the wrapper group-sums to kv heads. Refs:
@@ -263,7 +325,7 @@ def _bwd_dkv_kernel(
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    diag_open = _causal_open(qi, ki, block_q, block_k) if causal else True
+    diag_open = _tile_open(qi, ki, block_q, block_k, causal, window)
 
     @pl.when(diag_open)
     def _fold():
@@ -285,6 +347,8 @@ def _bwd_dkv_kernel(
         valid = jnp.logical_and(q_idx < t_real, k_idx < t_real)
         if causal:
             valid = jnp.logical_and(valid, q_idx >= k_idx)
+            if window is not None:  # the two edge tiles of the band
+                valid = jnp.logical_and(valid, q_idx - k_idx < window)
         p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)
         dv_acc_ref[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -306,7 +370,7 @@ def _bwd_dkv_kernel(
 
 def _flash_bwd(
     q, k, v, o, lse, do, *, causal: bool, scale: float, block_q: int,
-    block_k: int, interpret: bool,
+    block_k: int, interpret: bool, window=None,
 ):
     """Pallas flash backward. q/o/do [B,H,T,D], k/v [B,Hkv,T,D],
     lse [B,H,Tq_pad,1] → (dq, dk, dv) in input shapes/dtypes."""
@@ -334,19 +398,17 @@ def _flash_bwd(
     # causally-skipped tiles: clamp the index map so the DMA is elided too
     # (see the same trick in _flash_fwd)
     def kv_index(bi, hi, qi, ki):
-        if causal:
-            ki = jnp.minimum(ki, _causal_last_k_tile(qi, bq, bk))
-        return (bi, hi // g, ki, 0)
+        return (bi, hi // g, _k_tile_clamp(qi, ki, bq, bk, causal, window), 0)
 
     def q_index_dkv(bi, hi, ki, qi):
-        if causal:
-            qi = jnp.maximum(qi, _causal_first_q_tile(ki, bq, bk))
-        return (bi, hi, qi, 0)
+        qi = _q_tile_clamp(ki, qi, bq, bk, causal, window)
+        # the band's last q tile may lie past the grid at the sequence's end
+        return (bi, hi, jnp.minimum(qi, n_qb - 1) if window else qi, 0)
 
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, block_q=bq, block_k=bk, n_kb=n_kb,
-            causal=causal, scale=scale, t_real=t,
+            causal=causal, scale=scale, t_real=t, **_window_kw(window),
         ),
         grid=(b, h, n_qb, n_kb),
         in_specs=[
@@ -370,7 +432,7 @@ def _flash_bwd(
     dk_h, dv_h = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, block_q=bq, block_k=bk, n_qb=n_qb,
-            causal=causal, scale=scale, t_real=t,
+            causal=causal, scale=scale, t_real=t, **_window_kw(window),
         ),
         grid=(b, h, n_kb, n_qb),
         in_specs=[
@@ -417,9 +479,11 @@ def _flash_bwd(
     return dq, dk, dv
 
 
-def _block_reference(q_blk, k, v, q_offset, *, causal: bool, scale: float):
+def _block_reference(q_blk, k, v, q_offset, *, causal: bool, scale: float,
+                     window=None):
     """Attention for one q block against full K/V (heads-major, GQA-aware).
-    q_blk [B,H,BQ,D], k/v [B,Hkv,T,D], q_offset scalar start index."""
+    q_blk [B,H,BQ,D], k/v [B,Hkv,T,D], q_offset scalar start index; with
+    ``window`` the band is a mask on positions."""
     b, h, bq, d = q_blk.shape
     h_kv = k.shape[1]
     g = h // h_kv
@@ -429,14 +493,18 @@ def _block_reference(q_blk, k, v, q_offset, *, causal: bool, scale: float):
     if causal:
         q_idx = q_offset + jnp.arange(bq)[:, None]
         k_idx = jnp.arange(k.shape[2])[None, :]
-        s = jnp.where((q_idx >= k_idx)[None, None], s, _NEG_INF)
+        seen = q_idx >= k_idx
+        if window is not None:
+            seen = jnp.logical_and(seen, q_idx - k_idx < window)
+        s = jnp.where(seen[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     p5 = p.reshape(b, h_kv, g, bq, k.shape[2])
     o = jnp.einsum("bhgqk,bhkd->bhgqd", p5, v.astype(p.dtype))
     return o.reshape(b, h, bq, d).astype(q_blk.dtype)
 
 
-def _chunked_reference(q, k, v, *, causal: bool, scale: float, block_q: int):
+def _chunked_reference(q, k, v, *, causal: bool, scale: float, block_q: int,
+                       window=None):
     """Memory-bounded XLA attention: lax.map over checkpointed q blocks, so
     its vjp stores only block inputs and recomputes scores blockwise —
     backward memory stays O(BQ·T) instead of [T,T]. The non-TPU fallback
@@ -450,19 +518,22 @@ def _chunked_reference(q, k, v, *, causal: bool, scale: float, block_q: int):
     offsets = jnp.arange(n) * bq
 
     blk = jax.checkpoint(
-        lambda qb, off: _block_reference(qb, k, v, off, causal=causal, scale=scale)
+        lambda qb, off: _block_reference(
+            qb, k, v, off, causal=causal, scale=scale, window=window)
     )
     out = jax.lax.map(lambda args: blk(*args), (qr, offsets))  # [n,B,H,BQ,D]
     out = out.transpose(1, 2, 0, 3, 4).reshape(b, h, t_pad, d)
     return out[:, :, :t]
 
 
-def _dense_reference(q, k, v, *, causal: bool, scale: float):
+def _dense_reference(q, k, v, *, causal: bool, scale: float, window=None):
     """Unchunked XLA reference (numerics tests)."""
-    return _block_reference(q, k, v, 0, causal=causal, scale=scale)
+    return _block_reference(q, k, v, 0, causal=causal, scale=scale,
+                            window=window)
 
 
-def chunked_reference(q, k, v, *, causal: bool = True, scale=None, block_q: int = 256):
+def chunked_reference(q, k, v, *, causal: bool = True, scale=None,
+                      block_q: int = 256, window=None):
     """The chunked XLA reference in *model* layout (q [B,T,H,D]) — the
     independent lowering that on-hardware checks (bench.py's pre-timing
     gate, tests_tpu/) compare the compiled kernel against."""
@@ -475,24 +546,26 @@ def chunked_reference(q, k, v, *, causal: bool = True, scale=None, block_q: int 
         causal=causal,
         scale=scale,
         block_q=block_q,
+        window=window,
     ).transpose(0, 2, 1, 3)
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
 )
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, window):
     o, _ = _flash_fwd(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret,
+        block_k=block_k, interpret=interpret, window=window,
     )
     return o
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                   window):
     o, lse = _flash_fwd(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret,
+        block_k=block_k, interpret=interpret, window=window,
     )
     # named so a rematted caller can elect to SAVE these residuals (o is
     # cheap to keep, recomputing it costs a full kernel pass) — see
@@ -504,11 +577,13 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, do):
+def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window, res,
+                   do):
     q, k, v, o, lse = res
     return _flash_bwd(
         q, k, v, o, lse, do, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
+        window=window,
     )
 
 
@@ -529,6 +604,7 @@ def flash_attention(
     batch_axes=("data", "fsdp"),
     head_axis: str = "tensor",
     layout: str = "bthd",
+    window: Optional[int] = None,
 ):
     """Flash attention in model layout q [B,T,H,D], k/v [B,T,Hkv,D] — or,
     with ``layout="bhtd"``, directly in the kernel's heads-major layout
@@ -541,9 +617,16 @@ def flash_attention(
     runs the real kernel on TPU and the exact chunked XLA reference on any
     other backend — never the Pallas interpreter; pass ``interpret=True``
     explicitly to exercise the kernel body off-TPU (kernel tests do).
-    Differentiable (Pallas flash backward)."""
+    Differentiable (Pallas flash backward).
+
+    ``window`` (with ``causal``): query i sees key j iff i - window < j <= i,
+    ``window`` keys with its own. All three kernels skip the k tiles (dk/dv:
+    the q tiles) on both sides of the band and mask inside its edge tiles."""
     if layout not in ("bthd", "bhtd"):
         raise ValueError(f"layout={layout!r}; expected bthd|bhtd")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window!r} needs causal attention and at least one key")
     heads_major = layout == "bhtd"
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -563,10 +646,12 @@ def flash_attention(
             kt = k_.transpose(0, 2, 1, 3)
             vt = v_.transpose(0, 2, 1, 3)
         if use_kernel:
-            o = _flash(qt, kt, vt, causal, scale, block_q, block_k, interpret)
+            o = _flash(qt, kt, vt, causal, scale, block_q, block_k, interpret,
+                       window)
         else:
             o = _chunked_reference(
-                qt, kt, vt, causal=causal, scale=scale, block_q=block_q
+                qt, kt, vt, causal=causal, scale=scale, block_q=block_q,
+                window=window,
             )
         return o if heads_major else o.transpose(0, 2, 1, 3)
 

@@ -50,7 +50,16 @@ def _scale(a32: jnp.ndarray, axis: int, qmax: float) -> jnp.ndarray:
 def _quantize(a32, scale, precision):
     if precision == "int8":
         return jnp.clip(jnp.round(a32 / scale), -127.0, 127.0).astype(jnp.int8)
-    return (a32 / scale).astype(jnp.float8_e4m3fn)
+    # rounded to e4m3's three mantissa bits by arithmetic before the cast.
+    # A chip with no fp8 product (v5e) gets the operands widened again, and
+    # the compiler drops a narrowing convert that a widening one follows: the
+    # cast alone read as bf16 on every number there (PERF.md section 7, row
+    # 18f). A reduce_precision it keeps. Five exponent bits, so that nothing
+    # up to 448 overflows; the cast that follows is then exact but for
+    # e4m3's subnormals, under 2**-6 / 448 of the largest.
+    return lax.reduce_precision(
+        a32 / scale, exponent_bits=5, mantissa_bits=3
+    ).astype(jnp.float8_e4m3fn)
 
 
 def _forward_2d(x: jnp.ndarray, w: jnp.ndarray, precision: str) -> jnp.ndarray:
@@ -110,6 +119,65 @@ def quant_matmul(
     lead = x.shape[:-1]
     out = _quant_mm_2d(x.reshape(-1, x.shape[-1]), w, precision)
     return out.reshape(*lead, w.shape[-1])
+
+
+# -- the grouped product (parallel/moe.py's expert matrices) -----------------
+
+def _ragged_forward(x, w, group_sizes, precision):
+    """Rows of group g times ``w[g]``, quantized as :func:`_forward_2d`
+    does it: activations a row, weights a column of each expert; the
+    contraction in the narrow dtype, the scales in the epilogue."""
+    qmax = _QMAX[precision]
+    x32 = x.astype(jnp.float32)
+    w32 = w.astype(jnp.float32)
+    sx = _scale(x32, -1, qmax)  # [M, 1]
+    sw = _scale(w32, 1, qmax)   # [G, 1, N]
+    acc = lax.ragged_dot(
+        _quantize(x32, sx, precision), _quantize(w32, sw, precision),
+        group_sizes,
+        preferred_element_type=(
+            jnp.int32 if precision == "int8" else jnp.float32
+        ),
+    )
+    # each row's own expert's column scales; rows past the last group
+    # belong to no expert and are never read
+    group_of_row = jnp.repeat(
+        jnp.arange(w.shape[0]), group_sizes, total_repeat_length=x.shape[0])
+    return (acc.astype(jnp.float32) * sx * sw[group_of_row, 0]).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _quant_ragged(x, w, group_sizes, precision):
+    return _ragged_forward(x, w, group_sizes, precision)
+
+
+def _quant_ragged_fwd(x, w, group_sizes, precision):
+    return _ragged_forward(x, w, group_sizes, precision), (x, w, group_sizes)
+
+
+def _quant_ragged_bwd(precision, res, g):
+    # straight-through, as _quant_mm_bwd: the plain grouped product's own
+    # transposes
+    x, w, group_sizes = res
+    _, vjp = jax.vjp(lambda x, w: lax.ragged_dot(x, w, group_sizes), x, w)
+    return (*vjp(g.astype(x.dtype)), None)
+
+
+_quant_ragged.defvjp(_quant_ragged_fwd, _quant_ragged_bwd)
+
+
+def quant_ragged_dot(x, w, group_sizes, *, precision: str = "int8"):
+    """``lax.ragged_dot(x, w, group_sizes)`` with the contraction quantized
+    to ``precision``: ``x`` [M, K] with its rows sorted by group, ``w``
+    [G, K, N], ``group_sizes`` [G]. Forward in the narrow dtype, backward
+    full precision (straight-through), like :func:`quant_matmul`."""
+    if precision == "bf16":
+        return lax.ragged_dot(x, w, group_sizes)
+    if precision not in _QMAX:
+        raise ValueError(
+            f"precision={precision!r}; expected int8|fp8|bf16"
+        )
+    return _quant_ragged(x, w, group_sizes, precision)
 
 
 def quant_error(x, w, *, precision: str = "int8") -> float:

@@ -428,6 +428,13 @@ SETUP_SPANS = ("pre_bootstrap", "cache_config", "rendezvous", "attach",
 # (runtime/bootstrap.py); against `ckpt_open` it says how much of the
 # import a start hid.
 SETUP_OVERLAPPED = ("ckpt_import",)
+# named scalars of a training step that its loss returns beside its value
+# and the blob carries under `counters`: the routed feed-forward's
+# (parallel/moe.py) — how many of the step's assignments fell to experts
+# held here (mean a layer), the fullest held expert over the mean (worst
+# layer), assignments that found no row (0: the layer is dropless).
+TRAIN_COUNTERS = ("moe.assignments_held", "moe.load_max_over_mean",
+                  "moe.assignments_dropped")
 
 _PROFILE_KEYS = ("id", "state", "dir")
 
@@ -462,7 +469,7 @@ def bounded_serve_stats(qps=0.0, queue_depth=0.0, p99_ms=0.0,
 
 def bounded_train_stats(step=0, steps=0, step_p50_ms=0.0, buckets=None,
                         profile=None, compile_cache=None, setup=None,
-                        setup_overlapped=None,
+                        setup_overlapped=None, counters=None,
                         **_ignored) -> Dict[str, object]:
     """THE constructor for a pod's ``status.train_stats`` blob (oplint
     OBS004). Fixed key set, rounded floats, bucket keys clamped to the
@@ -477,7 +484,9 @@ def bounded_train_stats(step=0, steps=0, step_p50_ms=0.0, buckets=None,
     expect (like a Prometheus counter across a process restart).
     ``setup`` is this incarnation's set-up seconds by span, clamped to
     :data:`SETUP_SPANS`; ``setup_overlapped`` the seconds of what ran on
-    another thread meanwhile, clamped to :data:`SETUP_OVERLAPPED`."""
+    another thread meanwhile, clamped to :data:`SETUP_OVERLAPPED`;
+    ``counters`` the newest finished step's named scalars, clamped to
+    :data:`TRAIN_COUNTERS`."""
     # the source may be a file written by an UNTRUSTED workload process
     # (the executor mirrors whatever the worker flushed): wrong-typed
     # fields degrade to zeros/absence, never an exception out of the
@@ -508,10 +517,11 @@ def bounded_train_stats(step=0, steps=0, step_p50_ms=0.0, buckets=None,
     # this incarnation's set-up seconds by span, and beside them what ran
     # on another thread meanwhile: only what ran (a fresh start has no
     # `restore`; an import still running has its seconds so far), only
-    # the fixed key sets
+    # the fixed key sets; the step's counters ride the same way
     for field, given, keys in (("setup", setup, SETUP_SPANS),
                                ("setup_overlapped", setup_overlapped,
-                                SETUP_OVERLAPPED)):
+                                SETUP_OVERLAPPED),
+                               ("counters", counters, TRAIN_COUNTERS)):
         if isinstance(given, dict):
             kept = {k: _r3(given[k]) for k in keys if k in given}
             if kept:

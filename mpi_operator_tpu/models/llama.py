@@ -13,18 +13,27 @@ Llama-3-8B-class data-parallel + long-context workload. TPU-native design:
 - long context via parallel/ring_attention.py when the mesh has a
   ``sequence`` axis — RoPE and norms operate on global [B,T,D] arrays (XLA
   global-view), only the attention inner loop is manually ring-scheduled;
-- logical-axis pytree drives DP/FSDP/TP/SP resharding with zero model edits.
+- logical-axis pytree drives DP/FSDP/TP/SP resharding with zero model edits;
+- one decoder for every family it runs: the scan walks *periods* of layers,
+  and the kinds inside a period (``Config.layer_kinds``: full causal
+  attention, or a sliding window of keys, each with its own RoPE) are
+  unrolled in the scan's body, so a model of one kind compiles to the plain
+  scan over layers; the feed-forward is dense (SwiGLU) or routed
+  (``Config.n_experts`` > 0: parallel/moe.py's share of the experts). What
+  a model is follows from its configuration's shape; there is no switch.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict
+import math
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from mpi_operator_tpu.parallel import moe
 from mpi_operator_tpu.parallel.ring_attention import (
     dense_attention,
     ring_attention,
@@ -36,6 +45,29 @@ from mpi_operator_tpu.parallel.sharding import (
 from mpi_operator_tpu.runtime.topology import AXIS_SEQ
 
 Params = Dict[str, Any]
+
+
+LAYER_KINDS = ("full", "window")
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN scaling of a RoPE table (arXiv:2309.00071, as the public
+    ``rope_type: yarn`` configurations state it): frequencies whose
+    wavelength exceeds the original context are divided by ``factor``, those
+    well inside it are kept, with a linear ramp between the dimensions at
+    which ``beta_fast`` and ``beta_slow`` rotations fit the original
+    context; cos and sin are multiplied by ``attention_factor``. Here the
+    factor's square is folded into the scores' scale instead, in float32:
+    the same scores, and no rounding of the factor into a bf16 table (1.277
+    is no bf16 number: the table's cos and sin would come out 0.3 % low at
+    every position of the slow dimensions, all to one side)."""
+
+    factor: float
+    original_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +102,23 @@ class Config:
     # jax.checkpoint would not: its backward recomputation re-materializes
     # all layer intermediates at once)
     remat_layers: bool = False
+    # one period of the layer pattern: the attention of each of its layers,
+    # "full" (causal) or "window" (query i sees the ``window`` keys up to
+    # its own). The stack is n_layers / len(layer_kinds) periods.
+    layer_kinds: Tuple[str, ...] = ("full",)
+    window: Optional[int] = None
+    # RoPE by kind: window layers rotate at the plain ``rope_theta``, full
+    # layers too unless there is a Yarn for them
+    yarn_full: Optional[Yarn] = None
+    # routed feed-forward (n_experts > 0; ``d_ff`` is then unused): the
+    # router scores ``n_experts`` published experts and keeps the
+    # ``experts_per_token`` largest; this program holds ``n_experts_held``
+    # of them (0: all), ``first_expert`` on, each ``d_expert`` wide
+    n_experts: int = 0
+    n_experts_held: int = 0
+    first_expert: int = 0
+    experts_per_token: int = 0
+    d_expert: int = 0
 
     def __post_init__(self):
         if self.attention_impl not in ("auto", "dense", "flash"):
@@ -82,6 +131,34 @@ class Config:
                 f"matmul_precision={self.matmul_precision!r}; "
                 "expected bf16|int8|fp8"
             )
+        kinds = self.layer_kinds
+        if not kinds or any(k not in LAYER_KINDS for k in kinds):
+            raise ValueError(f"layer_kinds={kinds!r}; each of {LAYER_KINDS}")
+        if self.n_layers % len(kinds):
+            raise ValueError(
+                f"n_layers={self.n_layers} is no whole number of periods "
+                f"of {len(kinds)} layers")
+        if ("window" in kinds) != (self.window is not None):
+            raise ValueError(
+                "window layers need a window, and a window needs them: "
+                f"layer_kinds={kinds!r}, window={self.window!r}")
+        if self.n_experts:
+            held = self.n_experts_held or self.n_experts
+            if not (0 < self.experts_per_token <= self.n_experts
+                    and self.d_expert > 0 and self.first_expert >= 0
+                    and self.first_expert + held <= self.n_experts):
+                raise ValueError(
+                    f"routed feed-forward: {self.experts_per_token} of "
+                    f"{self.n_experts} experts a token, {held} held from "
+                    f"{self.first_expert} on, {self.d_expert} wide")
+
+    @property
+    def routed(self) -> bool:
+        return self.n_experts > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def q_dim(self) -> int:
@@ -123,6 +200,19 @@ def tiny(vocab: int = 256) -> Config:
     )
 
 
+def tiny_routed(vocab: int = 256) -> Config:
+    """Test-scale config of the routed, mixed-attention shape: a period of
+    three window layers and one full one (YaRN on the full one), 8 experts
+    of which 2 a token and the first 4 held, q_dim != d_model."""
+    return Config(
+        vocab=vocab, d_model=48, n_layers=4, n_heads=4, n_kv_heads=2,
+        head_dim=16, rope_theta=10_000.0, norm_eps=1e-6,
+        layer_kinds=("window", "window", "window", "full"), window=8,
+        yarn_full=Yarn(factor=4.0, original_len=16, attention_factor=1.1),
+        n_experts=8, n_experts_held=4, experts_per_token=2, d_expert=32,
+    )
+
+
 def _normal(key, shape, scale):
     return jax.random.normal(key, shape, jnp.float32) * scale
 
@@ -135,6 +225,17 @@ def init(config: Config, key) -> Params:
     s_d = d**-0.5
     s_ff = c.d_ff**-0.5
     s_q = c.q_dim**-0.5
+    if c.routed:
+        # every layer's router and held experts, stacked like the rest
+        feed_forward = jax.vmap(lambda k: moe.init(
+            k, d_model=d, d_expert=c.d_expert, n_experts=c.n_experts,
+            n_held=c.experts_held))(jax.random.split(lk[4], n))
+    else:
+        feed_forward = {
+            "w_gate": {"w": _normal(lk[4], (n, d, c.d_ff), s_d)},
+            "w_up": {"w": _normal(lk[5], (n, d, c.d_ff), s_d)},
+            "w_down": {"w": _normal(lk[6], (n, c.d_ff, d), s_ff)},
+        }
     return {
         "embed": {"w": _normal(ke, (c.vocab, d), 1.0)},
         # all layers stacked on axis 0 → lax.scan over the leading axis
@@ -145,9 +246,7 @@ def init(config: Config, key) -> Params:
             "wv": {"w": _normal(lk[2], (n, d, c.kv_dim), s_d)},
             "wo": {"w": _normal(lk[3], (n, c.q_dim, d), s_q)},
             "mlp_norm": {"scale": jnp.ones((n, d), jnp.float32)},
-            "w_gate": {"w": _normal(lk[4], (n, d, c.d_ff), s_d)},
-            "w_up": {"w": _normal(lk[5], (n, d, c.d_ff), s_d)},
-            "w_down": {"w": _normal(lk[6], (n, c.d_ff, d), s_ff)},
+            **feed_forward,
         },
         "final_norm": {"scale": jnp.ones((d,), jnp.float32)},
         "lm_head": {"w": _normal(kh, (d, c.vocab), s_d)},
@@ -156,6 +255,16 @@ def init(config: Config, key) -> Params:
 
 def logical_axes(config: Config) -> Params:
     # leading "layers" stack axis is always replicated (None)
+    if config.routed:
+        feed_forward = jax.tree.map(
+            lambda axes: (None, *axes), moe.logical_axes(),
+            is_leaf=lambda x: isinstance(x, tuple))
+    else:
+        feed_forward = {
+            "w_gate": {"w": (None, "embed", "mlp")},
+            "w_up": {"w": (None, "embed", "mlp")},
+            "w_down": {"w": (None, "mlp", "embed")},
+        }
     return {
         "embed": {"w": ("vocab", "embed")},
         "layers": {
@@ -165,27 +274,53 @@ def logical_axes(config: Config) -> Params:
             "wv": {"w": (None, "embed", "kv_heads")},
             "wo": {"w": (None, "heads", "embed")},
             "mlp_norm": {"scale": (None, "stats")},
-            "w_gate": {"w": (None, "embed", "mlp")},
-            "w_up": {"w": (None, "embed", "mlp")},
-            "w_down": {"w": (None, "mlp", "embed")},
+            **feed_forward,
         },
         "final_norm": {"scale": ("stats",)},
         "lm_head": {"w": ("embed", "vocab")},
     }
 
 
-def _rmsnorm(x, scale, eps):
+def _rmsnorm32(x, scale, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * scale).astype(x.dtype)
+    return y * scale
 
 
-def _rope_tables(t, dh, theta, dtype):
+def _rmsnorm(x, scale, eps):
+    return _rmsnorm32(x, scale, eps).astype(x.dtype)
+
+
+def yarn_ramp(dh: int, theta: float, yarn: Yarn):
+    """(low, high): the dimensions between which YaRN's ramp runs.
+    ``dim(r) = dh * ln(original_len / (2 pi r)) / (2 ln theta)`` is the
+    dimension whose frequency turns ``r`` times within the original
+    context; low = floor(dim(beta_fast)), high = ceil(dim(beta_slow))."""
+    dim = lambda r: (dh * math.log(yarn.original_len / (2 * math.pi * r))
+                     / (2 * math.log(theta)))
+    low = max(math.floor(dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim(yarn.beta_slow)), dh - 1)
+    return low, high
+
+
+def rope_frequencies(dh: int, theta: float, yarn: Optional[Yarn] = None):
+    """The dh/2 rotation frequencies, YaRN-scaled where there is one."""
+    half = dh // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        return freqs
+    low, high = yarn_ramp(dh, theta, yarn)
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 1e-3),
+        0.0, 1.0)
+    return freqs * ((1.0 - ramp) + ramp / yarn.factor)
+
+
+def _rope_tables(t, dh, theta, dtype, yarn=None):
     """cos/sin rotation tables [T, Dh/2] for global positions 0..T-1
     (arrays are global-view; sequence sharding is XLA's problem, not
     RoPE's). Shared by both layout variants so the math can never drift."""
-    half = dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = rope_frequencies(dh, theta, yarn)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * freqs[None, :]
     return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
 
@@ -195,16 +330,16 @@ def _rotate(x, cos, sin):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
 
-def _rope(x, theta):
+def _rope(x, theta, yarn=None):
     """RoPE, model layout: x [B,T,H,Dh], positions along axis 1."""
-    cos, sin = _rope_tables(x.shape[1], x.shape[-1], theta, x.dtype)
+    cos, sin = _rope_tables(x.shape[1], x.shape[-1], theta, x.dtype, yarn)
     return _rotate(x, cos[None, :, None, :], sin[None, :, None, :])
 
 
-def _rope_bhtd(x, theta):
+def _rope_bhtd(x, theta, yarn=None):
     """RoPE, kernel heads-major layout: x [B,H,T,Dh], positions along
     axis 2 (same tables, different broadcast)."""
-    cos, sin = _rope_tables(x.shape[2], x.shape[-1], theta, x.dtype)
+    cos, sin = _rope_tables(x.shape[2], x.shape[-1], theta, x.dtype, yarn)
     return _rotate(x, cos[None, None], sin[None, None])
 
 
@@ -224,6 +359,13 @@ def apply(
     With a mesh that has a ``sequence`` axis, attention runs as ring
     attention over ICI; otherwise dense causal attention. All other ops are
     global-view and sharded by constraint propagation."""
+    return _forward(config, params, tokens, mesh=mesh, rules=rules,
+                    return_features=return_features)[0]
+
+
+def _forward(config, params, tokens, *, mesh, rules, return_features):
+    """:func:`apply`, and beside its result the router's counters of each
+    layer ({name: one value a layer}), empty for a dense model."""
     c = config
     dt = c.compute_dtype
 
@@ -252,16 +394,26 @@ def apply(
         x = emb[tokens]
         x = constrain_fwd(x, ["batch", "seq", "embed"])
 
-    def attention(h, lp):
+    seq_sharded = (
+        mesh is not None
+        and AXIS_SEQ in mesh.axis_names
+        and mesh.shape[AXIS_SEQ] > 1
+    )
+    if seq_sharded and c.window is not None:
+        raise ValueError(
+            "a window needs the whole sequence on one chip: the ring "
+            "(mesh axis 'sequence') has no band")
+
+    def attention(h, lp, kind):
         y = _rmsnorm(h, lp["attn_norm"]["scale"], c.norm_eps)
         b, t, _ = y.shape
         # K/V stay at n_kv_heads: every attention path is GQA-aware, so the
         # ring never carries expanded K/V
-        seq_sharded = (
-            mesh is not None
-            and AXIS_SEQ in mesh.axis_names
-            and mesh.shape[AXIS_SEQ] > 1
-        )
+        window = c.window if kind == "window" else None
+        yarn = c.yarn_full if kind == "full" else None
+        # YaRN's factor on cos and sin of q and k alike is its square on
+        # their product
+        scale = c.head_dim**-0.5 * (yarn.attention_factor**2 if yarn else 1)
         use_flash = not seq_sharded and c.attention_impl != "dense"
         if use_flash:
             from mpi_operator_tpu.kernels import flash_attention
@@ -275,12 +427,14 @@ def apply(
             wq3 = lp["wq"]["w"].astype(dt).reshape(-1, c.n_heads, c.head_dim)
             wk3 = lp["wk"]["w"].astype(dt).reshape(-1, c.n_kv_heads, c.head_dim)
             wv3 = lp["wv"]["w"].astype(dt).reshape(-1, c.n_kv_heads, c.head_dim)
-            q = _rope_bhtd(jnp.einsum("btd,dhx->bhtx", y, wq3), c.rope_theta)
-            k = _rope_bhtd(jnp.einsum("btd,dhx->bhtx", y, wk3), c.rope_theta)
+            q = _rope_bhtd(
+                jnp.einsum("btd,dhx->bhtx", y, wq3), c.rope_theta, yarn)
+            k = _rope_bhtd(
+                jnp.einsum("btd,dhx->bhtx", y, wk3), c.rope_theta, yarn)
             v = jnp.einsum("btd,dhx->bhtx", y, wv3)
             attn = flash_attention(
-                q, k, v, causal=True, scale=c.head_dim**-0.5, mesh=mesh,
-                layout="bhtd",
+                q, k, v, causal=True, scale=scale, mesh=mesh,
+                layout="bhtd", window=window,
             )
             wo3 = lp["wo"]["w"].astype(dt).reshape(c.n_heads, c.head_dim, -1)
             h = h + jnp.einsum("bhtx,hxd->btd", attn, wo3)
@@ -288,14 +442,14 @@ def apply(
             q = (y @ lp["wq"]["w"].astype(dt)).reshape(b, t, c.n_heads, c.head_dim)
             k = (y @ lp["wk"]["w"].astype(dt)).reshape(b, t, c.n_kv_heads, c.head_dim)
             v = (y @ lp["wv"]["w"].astype(dt)).reshape(b, t, c.n_kv_heads, c.head_dim)
-            q = _rope(q, c.rope_theta)
-            k = _rope(k, c.rope_theta)
+            q = _rope(q, c.rope_theta, yarn)
+            k = _rope(k, c.rope_theta, yarn)
             if seq_sharded:
                 # ring attention: the only exact option over a sharded sequence
-                attn = ring_attention(q, k, v, mesh, causal=True)
+                attn = ring_attention(q, k, v, mesh, causal=True, scale=scale)
             else:
                 attn = dense_attention(
-                    q, k, v, causal=True, scale=c.head_dim**-0.5
+                    q, k, v, causal=True, scale=scale, window=window,
                 )
             attn = attn.reshape(b, t, c.q_dim)
             h = h + attn @ lp["wo"]["w"].astype(dt)
@@ -323,30 +477,75 @@ def apply(
             )
         return constrain_fwd(h, ["batch", "seq", "embed"])
 
-    def layer(carry, lp):
-        with jax.named_scope("attention"):
-            h = attention(carry, lp)
-        with jax.named_scope("mlp"):
-            h = mlp(h, lp)
-        return h, None
+    def routed(h, lp):
+        # the router reads the norm's float32 result, the experts its
+        # rounding to the compute dtype: top-k is a discontinuous choice
+        y32 = _rmsnorm32(h, lp["mlp_norm"]["scale"], c.norm_eps)
+        out, counters = moe.apply(
+            {k: lp[k] for k in ("router", "w_gate", "w_up", "w_down")},
+            y32.astype(h.dtype), router_in=y32,
+            experts_per_token=c.experts_per_token,
+            first_expert=c.first_expert, compute_dtype=dt,
+            matmul_precision=c.matmul_precision, mesh=mesh)
+        return constrain_fwd(h + out, ["batch", "seq", "embed"]), counters
 
-    if c.remat_layers:
-        # save the flash kernel's (o, lse) residuals across the remat
-        # boundary: recomputing them in the backward costs a full kernel
-        # pass (~4% of the llama step on v5e) for ~70MB/layer of HBM
-        layer = jax.checkpoint(
-            layer,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "flash_o", "flash_lse"
-            ),
-        )
-    x, _ = lax.scan(layer, x, params["layers"])
+    def layer_of(kind):
+        def layer(carry, lp):
+            with jax.named_scope("attention"), \
+                    jax.named_scope(f"attention_{kind}"):
+                h = attention(carry, lp, kind)
+            with jax.named_scope("mlp"):
+                if c.routed:
+                    with jax.named_scope("moe"):
+                        return routed(h, lp)
+                return mlp(h, lp), {}
+
+        if c.remat_layers:
+            # save the flash kernel's (o, lse) residuals across the remat
+            # boundary: recomputing them in the backward costs a full kernel
+            # pass (~4% of the llama step on v5e) for ~70MB/layer of HBM
+            layer = jax.checkpoint(
+                layer,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    "flash_o", "flash_lse"
+                ),
+            )
+        return layer
+
+    # the scan walks periods; the kinds inside one are unrolled in its
+    # body. A model of one kind is the plain scan over its layers.
+    kinds = c.layer_kinds
+    if len(kinds) == 1:
+        x, counters = lax.scan(layer_of(kinds[0]), x, params["layers"])
+    else:
+        layers = {kind: layer_of(kind) for kind in set(kinds)}
+
+        def period(carry, pp):
+            outs = []
+            for i, kind in enumerate(kinds):
+                carry, out = layers[kind](
+                    carry, jax.tree.map(lambda a: a[i], pp))
+                outs.append(out)
+            return carry, jax.tree.map(lambda *a: jnp.stack(a), *outs)
+
+        x, counters = lax.scan(period, x, jax.tree.map(
+            lambda a: a.reshape(-1, len(kinds), *a.shape[1:]),
+            params["layers"]))
     with jax.named_scope("head_loss"):
         x = _rmsnorm(x, params["final_norm"]["scale"], c.norm_eps)
         if return_features:
-            return x
+            return x, counters
         logits = x @ params["lm_head"]["w"].astype(dt)
-        return logits.astype(jnp.float32)
+        return logits.astype(jnp.float32), counters
+
+
+def _step_counters(counters):
+    """One scalar a counter for the step, from each layer's: how many
+    assignments fell to held experts (mean a layer), the fullest held
+    expert over the mean (worst layer), assignments without a row (sum)."""
+    fold = {moe.ASSIGNMENTS_HELD: jnp.mean, moe.LOAD_MAX_OVER_MEAN: jnp.max,
+            moe.ASSIGNMENTS_DROPPED: jnp.sum}
+    return {name: fold[name](values) for name, values in counters.items()}
 
 
 def loss_fn(
@@ -357,9 +556,11 @@ def loss_fn(
     mesh=None,
     rules=None,
     ce_chunk: int = 2048,
-) -> jnp.ndarray:
+):
     """Next-token cross-entropy. batch = {"tokens": [B,T]}; position t
-    predicts token t+1; the final position is dropped.
+    predicts token t+1; the final position is dropped. A routed model
+    returns ``(loss, counters)``: the router's named scalars of the step
+    (parallel/moe.py), which ``Trainer`` puts into the step's metrics.
 
     Above ``ce_chunk`` positions the loss is computed blockwise over the
     sequence (checkpointed lax.map): the [B,T,vocab] f32 logits plus their
@@ -368,21 +569,20 @@ def loss_fn(
     memory at O(B·chunk·vocab) with exact results."""
     tokens = batch["tokens"]
     t = tokens.shape[1]
-    if t - 1 <= ce_chunk:
-        logits = apply(config, params, tokens, mesh=mesh, rules=rules)
-        with jax.named_scope("head_loss"):
-            targets = tokens[:, 1:]
-            lp = jax.nn.log_softmax(logits[:, :-1])
-            ll = jnp.take_along_axis(lp, targets[..., None], axis=-1)[..., 0]
-            return -jnp.mean(ll)
-
-    feats = apply(
-        config, params, tokens, mesh=mesh, rules=rules, return_features=True
-    )  # [B, T, D] compute dtype
+    chunked = t - 1 > ce_chunk
+    out, counters = _forward(
+        config, params, tokens, mesh=mesh, rules=rules,
+        return_features=chunked,
+    )  # logits, or above ce_chunk the features [B, T, D] in compute dtype
     with jax.named_scope("head_loss"):
-        return _chunked_nll(
-            feats, params["lm_head"]["w"], tokens, ce_chunk
-        )
+        if chunked:
+            loss = _chunked_nll(out, params["lm_head"]["w"], tokens, ce_chunk)
+        else:
+            targets = tokens[:, 1:]
+            lp = jax.nn.log_softmax(out[:, :-1])
+            ll = jnp.take_along_axis(lp, targets[..., None], axis=-1)[..., 0]
+            loss = -jnp.mean(ll)
+    return (loss, _step_counters(counters)) if counters else loss
 
 
 def _chunked_nll(feats, head, tokens, ce_chunk):
@@ -418,12 +618,22 @@ def _chunked_nll(feats, head, tokens, ce_chunk):
     return -jnp.sum(totals) / (b * n)
 
 
+def _feed_forward_params(c: Config, active: bool) -> int:
+    """A layer's feed-forward matrices: all that are held, or with
+    ``active`` those one token meets (a routed token meets
+    ``experts_per_token`` experts wherever they are held)."""
+    if not c.routed:
+        return 3 * c.d_model * c.d_ff
+    experts = c.experts_per_token if active else c.experts_held
+    return c.d_model * c.n_experts + experts * 3 * c.d_model * c.d_expert
+
+
 def param_count(config: Config) -> int:
     c = config
     per_layer = (
         c.d_model * (c.q_dim + 2 * c.kv_dim)
         + c.q_dim * c.d_model
-        + 3 * c.d_model * c.d_ff
+        + _feed_forward_params(c, active=False)
         + 2 * c.d_model
     )
     return (
@@ -440,7 +650,7 @@ def flops_per_token(config: Config, seq_len: int) -> float:
     matmul_params = (
         c.d_model * (c.q_dim + 2 * c.kv_dim)
         + c.q_dim * c.d_model
-        + 3 * c.d_model * c.d_ff
+        + _feed_forward_params(c, active=True)
     )
     per_layer = 2 * matmul_params + 4 * seq_len * c.q_dim  # scores + PV
     return float(c.n_layers * per_layer + 2 * c.d_model * c.vocab)

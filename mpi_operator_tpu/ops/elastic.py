@@ -226,6 +226,9 @@ def run_elastic(
             with stats.phase("compute"):
                 state, metrics = trainer.train_step(state, batch)
             step += 1
+            # the loss's named scalars (a router's counters) ride the blob:
+            # the newest finished step's, read at a flush with no sync
+            stats.set_counters(metrics)
             profiler.observe(step)
             prof_watch.observe(step)
             stats.step_done(step)

@@ -94,7 +94,10 @@ class Trainer:
 
     Args:
       loss_fn: ``(params, batch) -> loss`` or, with ``has_model_state``,
-        ``(params, model_state, batch) -> (loss, new_model_state)``.
+        ``(params, model_state, batch) -> (loss, new_model_state)``. A
+        stateless loss may return ``(loss, {name: scalar})``: named scalars
+        of the step (a router's counters), which ride the step's metrics
+        under their names.
       params_axes: logical-axes pytree matching params (models.*.logical_axes).
       mesh: the job mesh (runtime.mesh_from_context / build_mesh).
       model_state_axes: logical-axes pytree for model_state when stateful.
@@ -237,17 +240,19 @@ class Trainer:
         # the two scopes put the step's phases into every operation's
         # `op_name` (metadata only): a device trace then splits forward,
         # backward, layer replay and optimizer (PERF.md §3)
+        metrics = {}
         with jax.named_scope("model"):
             if self.has_model_state:
                 (loss, new_ms), grads = jax.value_and_grad(
                     self._loss_fn, has_aux=True
                 )(state.params, state.model_state, batch)
             else:
-                loss, grads = jax.value_and_grad(self._loss_fn)(
-                    state.params, batch
-                )
+                (loss, scalars), grads = jax.value_and_grad(
+                    self._loss_and_scalars, has_aux=True
+                )(state.params, batch)
                 new_ms = state.model_state
-        metrics = {"loss": loss}
+                metrics.update(scalars)
+        metrics["loss"] = loss
         with jax.named_scope("optimizer"):
             updates, new_opt = self.tx.update(
                 grads, state.opt_state, state.params
@@ -268,14 +273,19 @@ class Trainer:
             metrics,
         )
 
+    def _loss_and_scalars(self, params, batch):
+        """(loss, the loss's named scalars: none where it returns its
+        value alone)."""
+        out = self._loss_fn(params, batch)
+        return out if isinstance(out, tuple) else (out, {})
+
     def _jit_wrap(self, fn, state, batch_example):
         """jit a (state, batch) -> (state, metrics) function with the
         trainer's shardings + donation (shared by train_step/multi_step so
         the two paths can never drift)."""
         state_sh = self.state_sharding(state)
-        metrics_sh = {"loss": NamedSharding(self.mesh, PartitionSpec())}
-        if self.config.grad_clip_norm > 0:
-            metrics_sh["grad_norm"] = NamedSharding(self.mesh, PartitionSpec())
+        # every metric is a replicated scalar, whatever the loss names
+        metrics_sh = NamedSharding(self.mesh, PartitionSpec())
         return jax.jit(
             fn,
             in_shardings=(state_sh, self.batch_sharding(batch_example)),

@@ -174,13 +174,17 @@ def ring_attention(
     )(q, k, v)
 
 
-def dense_attention(q, k, v, *, causal: bool, scale: float):
+def dense_attention(q, k, v, *, causal: bool, scale: float, window=None):
     """Reference (and no-sequence-axis fallback) attention; also the oracle
-    the tests compare ring attention against. GQA-aware like the ring path."""
+    the tests compare ring attention against. GQA-aware like the ring path.
+    ``window`` (causal only): query i sees key j iff i - window < j <= i."""
     s = _scores(q, k, scale)
     if causal:
         t_q, t_k = q.shape[1], k.shape[1]
         mask = jnp.arange(t_q)[:, None] >= jnp.arange(t_k)[None, :]
+        if window is not None:
+            mask = jnp.logical_and(
+                mask, jnp.arange(t_q)[:, None] - jnp.arange(t_k)[None, :] < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return _weighted_v(p, v).astype(q.dtype)

@@ -71,6 +71,7 @@ from typing import Any, Dict, Optional
 from mpi_operator_tpu.machinery.objects import (
     SETUP_SPANS,
     TRAIN_BUCKETS,
+    TRAIN_COUNTERS,
     bounded_train_stats,
 )
 
@@ -172,6 +173,10 @@ class StepStatsRecorder:
         self._step_start = clock()
         self._compiled = False
         self._profile: Optional[Dict[str, str]] = None
+        # the last steps' named scalars, newest last: device values that a
+        # flush reads only once their step has finished
+        self._counters: collections.deque = collections.deque(maxlen=3)
+        self._closing = False
         self._last_flush = 0.0
         self._warned = False
 
@@ -219,6 +224,26 @@ class StepStatsRecorder:
         if self.path and now - self._last_flush >= self.interval:
             self.flush(now=now)
 
+    def set_counters(self, metrics: Optional[Dict[str, Any]]) -> None:
+        """Keep the step's named scalars (those of ``metrics`` that are
+        TRAIN_COUNTERS) for the blob. They may be device values still being
+        computed: nothing here waits for them."""
+        kept = {k: v for k, v in (metrics or {}).items()
+                if k in TRAIN_COUNTERS}
+        if kept:
+            self._counters.append(kept)
+
+    def _finished_counters(self) -> Optional[Dict[str, float]]:
+        """The newest kept step's scalars whose values are all there
+        (``is_ready`` of a jax array; a plain number always is). Once the
+        recorder is closing the newest step's are waited for: the loop has
+        ended, and its last step is what the blob should say."""
+        for kept in reversed(self._counters):
+            if self._closing or all(getattr(v, "is_ready", lambda: True)()
+                                    for v in kept.values()):
+                return {k: float(v) for k, v in kept.items()}
+        return None
+
     def set_profile(self, req_id: str, state: str, directory: str) -> None:
         """Record the on-demand profile ack (rides the blob so the
         operator side sees capture progress through pod status). Flushed
@@ -249,6 +274,7 @@ class StepStatsRecorder:
                            if compile_cache.is_configured() else None),
             setup=_setup,
             setup_overlapped=bootstrap.setup_overlapped_seconds(),
+            counters=self._finished_counters(),
         )
 
     def flush(self, force: bool = False, now: Optional[float] = None) -> None:
@@ -275,6 +301,7 @@ class StepStatsRecorder:
                             exc_info=True)
 
     def close(self) -> None:
+        self._closing = True
         if self.path:
             self.flush(force=True)
 

@@ -1,0 +1,268 @@
+"""The one decoder over periods of unlike layers (models/llama.py): window
+and full attention with RoPE by kind, the routed feed-forward's counters
+out of the loss, and the dense model's program left as it was."""
+
+import dataclasses
+import hashlib
+import json
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mpi_operator_tpu.models import llama
+from mpi_operator_tpu.ops import (
+    ElasticConfig, Trainer, TrainerConfig, run_elastic)
+from mpi_operator_tpu.ops.data import make_global_batch
+from mpi_operator_tpu.parallel import moe
+from mpi_operator_tpu.runtime import MeshPlan, build_mesh, stepstats
+from mpi_operator_tpu.runtime.topology import AXIS_DATA, AXIS_SEQ
+
+TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0, 256)
+
+
+@pytest.fixture(scope="module")
+def routed():
+    cfg = llama.tiny_routed()
+    return cfg, llama.init(cfg, jax.random.PRNGKey(0))
+
+
+# the published settings of the full layers' RoPE that the benchmark's
+# configuration states: theta 5e5, head 128, factor 16 over 8192 positions
+MELLUM_YARN = llama.Yarn(factor=16.0, original_len=8192, beta_fast=32.0,
+                         beta_slow=1.0, attention_factor=1.2772588722239782)
+
+
+def test_yarn_ramp_by_hand():
+    # dim(r) = 128 ln(8192 / (2 pi r)) / (2 ln 5e5): dim(32) = 18.08,
+    # dim(1) = 34.98
+    assert llama.yarn_ramp(128, 5e5, MELLUM_YARN) == (18, 35)
+
+
+@pytest.mark.parametrize("k,scale", [
+    (10, 1.0),                                   # below the ramp: kept
+    (25, (1 - 7 / 17) + (7 / 17) / 16),          # on it: (25 - 18) / 17
+    (40, 1 / 16),                                # past it: divided
+])
+def test_yarn_frequencies_by_hand(k, scale):
+    freqs = llama.rope_frequencies(128, 5e5, MELLUM_YARN)
+    assert float(freqs[k]) == pytest.approx(
+        5e5 ** (-2 * k / 128) * scale, rel=1e-5)
+    plain = llama.rope_frequencies(128, 5e5)
+    assert float(plain[k]) == pytest.approx(5e5 ** (-2 * k / 128), rel=1e-5)
+
+
+def test_yarn_tables_are_plain_and_the_factor_is_on_the_scores(routed):
+    """cos and sin times the factor on q and k alike is the factor's square
+    on their product: the tables stay cos and sin (1.277 is no bf16 number,
+    and a bf16 table of it reads low at every position), and the scores'
+    scale carries the square in float32."""
+    cos, sin = llama._rope_tables(16, 128, 5e5, jnp.float32, MELLUM_YARN)
+    assert float(cos[0, 0]) == 1.0
+    angle = 7 * 5e5 ** (-2 * 40 / 128) / 16
+    assert float(sin[7, 40]) == pytest.approx(math.sin(angle), rel=1e-4)
+    # the tiny model's full layer: a factor of 1 moves the logits
+    cfg, params = routed
+    unit = dataclasses.replace(cfg, yarn_full=dataclasses.replace(
+        cfg.yarn_full, attention_factor=1.0))
+    gap = jnp.abs(llama.apply(cfg, params, TOKENS)
+                  - llama.apply(unit, params, TOKENS))
+    assert float(jnp.max(gap)) > 1e-3
+
+
+def test_a_routed_loss_returns_its_counters(routed):
+    cfg, params = routed
+    loss, counters = llama.loss_fn(cfg, params, {"tokens": TOKENS})
+    assert set(counters) == {moe.ASSIGNMENTS_HELD, moe.LOAD_MAX_OVER_MEAN,
+                             moe.ASSIGNMENTS_DROPPED}
+    # half the experts held: about half of the 2 x 24 x 2 assignments
+    assert 30 < float(counters[moe.ASSIGNMENTS_HELD]) < 66
+    assert float(counters[moe.ASSIGNMENTS_DROPPED]) == 0
+    assert np.isfinite(float(loss))
+    # a dense model's loss is its value alone
+    dense = llama.tiny()
+    out = llama.loss_fn(dense, llama.init(dense, jax.random.PRNGKey(0)),
+                        {"tokens": TOKENS})
+    assert out.shape == ()
+
+
+def test_flash_and_dense_paths_agree_on_a_period(routed):
+    cfg, params = routed
+    auto = llama.apply(cfg, params, TOKENS)
+    dense = llama.apply(dataclasses.replace(cfg, attention_impl="dense"),
+                        params, TOKENS)
+    np.testing.assert_allclose(auto, dense, atol=2e-2)
+    assert auto.shape == (2, 24, cfg.vocab)
+
+
+def test_the_window_and_the_kinds_reach_the_attention(routed):
+    """A model whose every layer is full differs from the period's, and so
+    does one without YaRN: neither setting is dropped on the way."""
+    cfg, params = routed
+    base = llama.apply(cfg, params, TOKENS)
+    wide = llama.apply(dataclasses.replace(cfg, window=24), params, TOKENS)
+    plain = llama.apply(dataclasses.replace(cfg, yarn_full=None), params,
+                        TOKENS)
+    assert float(jnp.max(jnp.abs(base - wide))) > 1e-3
+    assert float(jnp.max(jnp.abs(base - plain))) > 1e-3
+    # positions inside the first window see the same keys either way, and
+    # YaRN only touches the full layer, the last: nothing earlier moves
+    np.testing.assert_allclose(base[:, :8], wide[:, :8], atol=2e-2)
+
+
+def test_periods_are_the_layers_in_order():
+    """Two periods of (full, full) are four full layers: the scan over
+    periods walks the stacked weights in the order the plain scan does."""
+    one = dataclasses.replace(llama.tiny(), n_layers=4,
+                              compute_dtype=jnp.float32)
+    two = dataclasses.replace(one, layer_kinds=("full", "full"))
+    params = llama.init(one, jax.random.PRNGKey(2))
+    np.testing.assert_allclose(
+        llama.apply(one, params, TOKENS), llama.apply(two, params, TOKENS),
+        atol=1e-5)
+
+
+@pytest.mark.parametrize("change,match", [
+    (dict(layer_kinds=("full", "banded")), "layer_kinds"),
+    (dict(n_layers=3, layer_kinds=("full", "full")), "periods"),
+    (dict(window=8), "window"),
+    (dict(layer_kinds=("window",)), "window"),
+    (dict(n_experts=8, experts_per_token=9, d_expert=4), "routed"),
+    (dict(n_experts=8, experts_per_token=2, d_expert=4, first_expert=6,
+          n_experts_held=4), "routed"),
+])
+def test_a_configuration_that_cannot_be_is_refused(change, match):
+    with pytest.raises(ValueError, match=match):
+        dataclasses.replace(llama.tiny(), **change)
+
+
+def test_a_window_over_a_sequence_mesh_raises(routed):
+    cfg, params = routed
+    mesh = build_mesh(MeshPlan(axes={AXIS_DATA: 1, AXIS_SEQ: 2}),
+                      jax.devices()[:2])
+    with pytest.raises(ValueError, match="ring"):
+        llama.apply(cfg, params, TOKENS, mesh=mesh)
+
+
+def test_param_count_counts_the_experts_held(routed):
+    cfg, params = routed
+    assert llama.param_count(cfg) == sum(
+        a.size for a in jax.tree.leaves(params))
+    # a token meets experts_per_token experts, wherever they are held
+    per_token = llama.flops_per_token(cfg, 24)
+    more = llama.flops_per_token(
+        dataclasses.replace(cfg, experts_per_token=3), 24)
+    assert more - per_token == cfg.n_layers * 2 * 3 * cfg.d_model * cfg.d_expert
+
+
+# -- the dense model's step is the program it was ---------------------------
+
+# sha256 of the lowered text of Trainer._bare_step for llama.tiny() on one
+# CPU device, as the commit before the period scan lowered it (2 x 32 ids
+# plain; 2 x 4096 with remat_layers, where the chunked loss is on). A
+# change that means to alter the dense decoder's step updates these.
+DENSE_STEP_DIGESTS = {
+    (False, 32):
+        "40f64e3e07493cacb6520d6aefd03e9622b134c0e71ac4774af4af21a85f9050",
+    (True, 4096):
+        "c676add91bda82c64fdee8fe4b539c97ffb6aaae6c5ff66c15fda9c074c65f33",
+}
+
+
+@pytest.mark.parametrize("remat,seq", sorted(DENSE_STEP_DIGESTS))
+def test_the_dense_step_lowers_to_the_text_it_lowered_to(remat, seq):
+    cfg = dataclasses.replace(llama.tiny(), remat_layers=remat)
+    mesh = build_mesh(MeshPlan.data_parallel(1), jax.devices()[:1])
+    trainer = Trainer(lambda p, b: llama.loss_fn(cfg, p, b, mesh=mesh),
+                      llama.logical_axes(cfg), mesh, TrainerConfig())
+    state = trainer.init_state(llama.init(cfg, jax.random.PRNGKey(0)))
+    batch = {"tokens": jnp.zeros((2, seq), jnp.int32)}
+    text = trainer._jit_wrap(trainer._bare_step, state, batch).lower(
+        state, batch).as_text()
+    assert "ragged" not in text and "stablehlo.sort" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        DENSE_STEP_DIGESTS[remat, seq]
+
+
+# -- the counters' way out of the step ---------------------------------------
+
+def _routed_trainer(cfg, mesh):
+    return Trainer(lambda p, b: llama.loss_fn(cfg, p, b, mesh=mesh),
+                   llama.logical_axes(cfg), mesh, TrainerConfig(),
+                   donate=False)  # the fixture's weights are used again
+
+
+def test_the_step_puts_the_losss_scalars_into_its_metrics(routed):
+    cfg, params = routed
+    mesh = build_mesh(MeshPlan.data_parallel(1), jax.devices()[:1])
+    trainer = _routed_trainer(cfg, mesh)
+    state, metrics = trainer.train_step(
+        trainer.init_state(params), {"tokens": TOKENS})
+    assert {"loss", "grad_norm", moe.ASSIGNMENTS_HELD,
+            moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED} == set(metrics)
+    _, counters = llama.loss_fn(cfg, params, {"tokens": TOKENS})
+    assert float(metrics[moe.ASSIGNMENTS_HELD]) == float(
+        counters[moe.ASSIGNMENTS_HELD])
+    assert int(state.step) == 1
+
+
+def test_the_counters_reach_the_blob(routed, tmp_path, monkeypatch):
+    """Through run_elastic: the newest finished step's named scalars are
+    in the stats file the executor mirrors, under ``counters``."""
+    cfg, params = routed
+    stats_file = tmp_path / "stats.json"
+    monkeypatch.setenv(stepstats.ENV_STATS_FILE, str(stats_file))
+    monkeypatch.setenv(stepstats.ENV_STATS_INTERVAL, "0")
+    mesh = build_mesh(MeshPlan.data_parallel(1), jax.devices()[:1])
+    trainer = _routed_trainer(cfg, mesh)
+
+    def batches():
+        while True:
+            yield make_global_batch(mesh, {"tokens": np.asarray(TOKENS)})
+
+    result = run_elastic(
+        trainer, batches(), total_steps=3,
+        config=ElasticConfig(checkpoint_dir=str(tmp_path / "ckpt"),
+                             save_interval_steps=10 ** 9),
+        init_state=lambda: trainer.init_state(params),
+        membership=lambda: 1, current_world=1)
+    assert result.outcome == "done"
+    blob = json.loads(stats_file.read_text())
+    assert set(blob["counters"]) == {
+        moe.ASSIGNMENTS_HELD, moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED}
+    assert blob["counters"][moe.ASSIGNMENTS_DROPPED] == 0
+    assert blob["counters"][moe.ASSIGNMENTS_HELD] == pytest.approx(
+        result.metrics[moe.ASSIGNMENTS_HELD], abs=1e-3)
+
+
+class _NotYet:
+    def is_ready(self):
+        return False
+
+
+def test_a_flush_reads_only_a_finished_steps_counters():
+    rec = stepstats.StepStatsRecorder()
+    assert "counters" not in rec.snapshot()
+    rec.set_counters({"loss": 1.0})  # no counter among them: nothing kept
+    assert "counters" not in rec.snapshot()
+    rec.set_counters({moe.ASSIGNMENTS_HELD: 7.0, "loss": 1.0})
+    rec.set_counters({moe.ASSIGNMENTS_HELD: _NotYet()})
+    # the newest step still runs: the one before it is what the blob says
+    assert rec.snapshot()["counters"] == {moe.ASSIGNMENTS_HELD: 7.0}
+    rec.set_counters({moe.ASSIGNMENTS_HELD: 9.0, "unknown.counter": 1.0})
+    assert rec.snapshot()["counters"] == {moe.ASSIGNMENTS_HELD: 9.0}
+
+
+def test_a_closing_recorder_waits_for_the_last_step():
+    class Late(_NotYet):
+        def __float__(self):  # what float() of a device value does: waits
+            return 11.0
+
+    rec = stepstats.StepStatsRecorder()
+    rec.set_counters({moe.ASSIGNMENTS_HELD: 7.0})
+    rec.set_counters({moe.ASSIGNMENTS_HELD: Late()})
+    assert rec.snapshot()["counters"] == {moe.ASSIGNMENTS_HELD: 7.0}
+    rec.close()
+    assert rec.snapshot()["counters"] == {moe.ASSIGNMENTS_HELD: 11.0}

@@ -1,0 +1,166 @@
+"""The routed feed-forward (parallel/moe.py): a share of the experts,
+dropless. On the CPU ``lax.ragged_dot`` is a masked dense product, so what
+is checked here is the layer's own arithmetic: the routing, the sort, both
+gathers and their hand-written transposes, the counters, the shares over a
+mesh. The plain loop it is held against visits each expert over every
+token (no sort, no grouped product, no capacity)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mpi_operator_tpu.kernels.quant_matmul import quant_ragged_dot
+from mpi_operator_tpu.parallel import moe
+from mpi_operator_tpu.runtime import MeshPlan, build_mesh
+from mpi_operator_tpu.runtime.topology import AXIS_DATA, AXIS_EXPERT
+
+D, F, E, K = 32, 48, 8, 2
+F32 = dict(compute_dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def whole():
+    p = moe.init(jax.random.PRNGKey(0), d_model=D, d_expert=F, n_experts=E,
+                 n_held=E)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, D), jnp.float32)
+    return p, x
+
+
+def _share(p, first, count):
+    return {"router": p["router"], **{
+        n: {"w": p[n]["w"][first:first + count]}
+        for n in ("w_gate", "w_up", "w_down")}}
+
+
+def _loop(p, x, first=0):
+    xf = x.reshape(-1, D)
+    w, e = moe.route(xf, p["router"]["w"], K)
+    out = jnp.zeros_like(xf)
+    for i in range(p["w_gate"]["w"].shape[0]):
+        gate = jax.nn.silu(xf @ p["w_gate"]["w"][i])
+        y = (gate * (xf @ p["w_up"]["w"][i])) @ p["w_down"]["w"][i]
+        out = out + y * jnp.sum(
+            jnp.where(e == first + i, w, 0.0), -1, keepdims=True)
+    return out.reshape(x.shape)
+
+
+def test_the_whole_layer_is_the_plain_loop(whole):
+    p, x = whole
+    y, counters = moe.apply(p, x, experts_per_token=K, **F32)
+    np.testing.assert_allclose(y, _loop(p, x), atol=1e-5, rtol=1e-5)
+    assert float(counters[moe.ASSIGNMENTS_HELD]) == 2 * 24 * K
+    assert float(counters[moe.ASSIGNMENTS_DROPPED]) == 0
+
+
+@pytest.mark.parametrize("shares", [2, 4, 8])
+def test_the_shares_add_up_to_the_uncut_layer(whole, shares):
+    """The partial results of all shares, each computed by the layer as a
+    one-chip share, sum to the whole layer; so do their counts."""
+    p, x = whole
+    each = E // shares
+    total, held = 0.0, 0.0
+    for first in range(0, E, each):
+        y, counters = moe.apply(_share(p, first, each), x, first_expert=first,
+                                experts_per_token=K, **F32)
+        np.testing.assert_allclose(
+            y, _loop(_share(p, first, each), x, first), atol=1e-5, rtol=1e-5)
+        total, held = total + y, held + float(counters[moe.ASSIGNMENTS_HELD])
+    np.testing.assert_allclose(total, _loop(p, x), atol=2e-5, rtol=2e-5)
+    assert held == 2 * 24 * K
+
+
+@pytest.mark.parametrize("expert", [0, 2])
+def test_dropless_under_total_imbalance(whole, expert):
+    """A router that sends every token to one held expert first: that
+    expert gets a row for every token, nothing is dropped, and the result
+    is the plain loop's."""
+    p, x = whole
+    x = jnp.abs(x)  # the loaded column's score then beats every other
+    router = jnp.zeros((D, E)).at[:, 4 + expert].set(50.0)
+    share = dict(_share(p, 4, 3), router={"w": router})
+    y, counters = moe.apply(share, x, first_expert=4, experts_per_token=K,
+                            **F32)
+    np.testing.assert_allclose(y, _loop(share, x, 4), atol=1e-5, rtol=1e-5)
+    assert float(counters[moe.ASSIGNMENTS_DROPPED]) == 0
+    # the loaded expert has a row of every token: the fullest holds at
+    # least 3 / (1 + the others' share) of the mean
+    assert float(counters[moe.ASSIGNMENTS_HELD]) >= 2 * 24
+    assert float(counters[moe.LOAD_MAX_OVER_MEAN]) > 1.5
+
+
+@pytest.mark.parametrize("leaf", ["x", "router", "w_gate", "w_up", "w_down"])
+def test_gradients_are_the_plain_loops(whole, leaf):
+    """Each gather's hand-written transpose (a gather too) against
+    autodiff of the plain loop, for a share in the middle of the experts."""
+    p, x = whole
+    share = _share(p, 2, 4)
+    cot = jax.random.normal(jax.random.PRNGKey(3), x.shape)
+
+    def through(fn):
+        g = jax.grad(lambda p, x: jnp.sum(fn(p, x) * cot), argnums=(0, 1))(
+            share, x)
+        return g[1] if leaf == "x" else g[0][leaf]["w"]
+
+    got = through(lambda p, x: moe.apply(
+        p, x, first_expert=2, experts_per_token=K, **F32)[0])
+    want = through(lambda p, x: _loop(p, x, 2))
+    assert float(jnp.max(jnp.abs(want))) > 0
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
+
+
+@pytest.mark.parametrize("axes", [
+    {AXIS_EXPERT: 4}, {AXIS_EXPERT: 2, AXIS_DATA: 2}, {AXIS_DATA: 2}])
+def test_over_a_mesh_the_shares_are_summed(whole, axes):
+    """An ``expert`` axis: each member holds its share and the partial
+    results are summed; batch axes: each member routes its own tokens."""
+    p, x = whole
+    size = int(np.prod(list(axes.values())))
+    mesh = build_mesh(MeshPlan(axes=axes), jax.devices()[:size])
+    y, counters = jax.jit(lambda p, x: moe.apply(
+        p, x, experts_per_token=K, mesh=mesh, **F32))(p, x)
+    want, want_counters = moe.apply(p, x, experts_per_token=K, **F32)
+    np.testing.assert_allclose(y, want, atol=2e-5, rtol=2e-5)
+    for name in want_counters:
+        assert float(counters[name]) == pytest.approx(
+            float(want_counters[name]))
+
+
+def test_int8_expert_products_are_near_and_not_the_same(whole):
+    p, x = whole
+    y, _ = moe.apply(p, x, experts_per_token=K, **F32)
+    y8, _ = moe.apply(p, x, experts_per_token=K, matmul_precision="int8",
+                      **F32)
+    gap = float(jnp.linalg.norm(y8 - y) / jnp.linalg.norm(y))
+    assert 1e-3 < gap < 5e-2
+    # backward is straight-through: the plain grouped product's transposes
+    g = jax.grad(lambda p: jnp.sum(moe.apply(
+        p, x, experts_per_token=K, matmul_precision="int8", **F32)[0] ** 2))(p)
+    assert all(bool(jnp.all(jnp.isfinite(a))) and float(jnp.abs(a).max()) > 0
+               for a in jax.tree.leaves(g))
+
+
+def test_quant_ragged_dot_scales_each_experts_columns():
+    key = jax.random.PRNGKey(5)
+    x = jax.random.normal(key, (20, 16))
+    # one expert a hundred times the other: a scale shared between them
+    # would round the small one away
+    w = jax.random.normal(jax.random.fold_in(key, 1), (2, 16, 8))
+    w = w * jnp.array([1.0, 100.0])[:, None, None]
+    sizes = jnp.array([12, 5], jnp.int32)  # three rows belong to no group
+    got = quant_ragged_dot(x, w, sizes, precision="int8")
+    want = jax.lax.ragged_dot(x, w, sizes)
+    for rows in (slice(0, 12), slice(12, 17)):
+        gap = float(jnp.linalg.norm(got[rows] - want[rows])
+                    / jnp.linalg.norm(want[rows]))
+        assert 1e-4 < gap < 3e-2
+    with pytest.raises(ValueError, match="precision"):
+        quant_ragged_dot(x, w, sizes, precision="int4")
+
+
+def test_the_router_is_float32_whatever_the_activations(whole):
+    p, x = whole
+    w, e = moe.route(x.reshape(-1, D).astype(jnp.bfloat16),
+                     p["router"]["w"], K)
+    assert w.dtype == jnp.float32 and e.shape == (48, K)
+    np.testing.assert_allclose(jnp.sum(w, -1), 1.0, atol=1e-6)

@@ -1,18 +1,17 @@
-"""Pipeline (PP) and Mixture-of-Experts (EP) tests.
+"""Pipeline (PP) tests.
 
-Both strategies are absent from the reference (SURVEY.md §2.5); these tests
-pin their correctness: the SPMD pipeline must equal sequential layer
-application, and sharded experts must equal local experts."""
+The strategy is absent from the reference (SURVEY.md §2.5); these tests pin
+its correctness: the SPMD pipeline must equal sequential layer application.
+(The expert layer's tests are tests/test_moe.py.)"""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mpi_operator_tpu.parallel import moe
 from mpi_operator_tpu.parallel.pipeline import run_pipeline
 from mpi_operator_tpu.runtime import MeshPlan, build_mesh
-from mpi_operator_tpu.runtime.topology import AXIS_DATA, AXIS_EXPERT, AXIS_PIPE
+from mpi_operator_tpu.runtime.topology import AXIS_DATA, AXIS_PIPE
 
 # slow tier: XLA compiles / subprocess gangs (see pytest.ini)
 pytestmark = pytest.mark.slow
@@ -63,61 +62,3 @@ def test_pipeline_no_pipe_axis_falls_back():
     want = _sequential(params, x, n_layers)
     got = run_pipeline(_stage_fn, params, x, mesh, n_microbatches=2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)
-
-
-# ---------- moe ----------
-
-
-@pytest.fixture(scope="module")
-def moe_setup():
-    cfg = moe.MoEConfig(d_model=16, d_ff=32, n_experts=8, capacity_factor=2.0)
-    params = moe.init(cfg, jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16))
-    return cfg, params, x
-
-
-def test_moe_local_shapes_and_aux(moe_setup):
-    cfg, params, x = moe_setup
-    y, aux = moe.apply(cfg, params, x)
-    assert y.shape == x.shape
-    assert jnp.isfinite(aux)
-    # perfectly balanced load-balance loss is 1.0; any routing is >= 1
-    assert float(aux) >= 0.99
-
-
-def test_moe_sharded_matches_local(moe_setup):
-    cfg, params, x = moe_setup
-    y_local, aux_local = moe.apply(cfg, params, x)
-    mesh = build_mesh(MeshPlan(axes={AXIS_EXPERT: 8}))
-    y_shard, aux_shard = jax.jit(
-        lambda p, xx: moe.apply(cfg, p, xx, mesh=mesh)
-    )(params, x)
-    np.testing.assert_allclose(
-        np.asarray(y_local, np.float32), np.asarray(y_shard, np.float32),
-        atol=2e-2, rtol=2e-2,
-    )
-    np.testing.assert_allclose(float(aux_local), float(aux_shard), rtol=1e-5)
-
-
-def test_moe_gradients_flow(moe_setup):
-    cfg, params, x = moe_setup
-
-    def loss(p):
-        y, aux = moe.apply(cfg, p, x)
-        return jnp.mean(y**2) + 0.01 * aux
-
-    g = jax.grad(loss)(params)
-    gnorm = sum(float(jnp.sum(jnp.abs(l))) for l in jax.tree.leaves(g))
-    assert np.isfinite(gnorm) and gnorm > 0
-    # router gets gradient through the gate
-    assert float(jnp.sum(jnp.abs(g["router"]["w"]))) > 0
-
-
-def test_moe_capacity_drops_tokens():
-    cfg = moe.MoEConfig(d_model=8, d_ff=16, n_experts=2, capacity_factor=0.1)
-    params = moe.init(cfg, jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 8))
-    y, _ = moe.apply(cfg, params, x)
-    # capacity 1 per expert → most tokens dropped → mostly zero rows
-    zero_rows = np.sum(np.all(np.asarray(y[0]) == 0, axis=-1))
-    assert zero_rows >= 28

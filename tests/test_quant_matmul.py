@@ -154,3 +154,29 @@ def test_llama_config_rejects_bad_precision():
 
     with pytest.raises(ValueError, match="matmul_precision"):
         dataclasses.replace(llama.tiny(), matmul_precision="int4")
+
+
+@pytest.mark.parametrize("grouped", [False, True])
+def test_fp8_is_rounded_by_arithmetic_the_compiler_keeps(grouped):
+    """A chip with no fp8 product widens the operands again, and XLA drops
+    a narrowing convert that a widening one follows: on a v5e the cast alone
+    read as bf16 on every number (PERF.md section 7, row 18f). The rounding
+    is a ``reduce_precision`` to e4m3's three mantissa bits before the cast,
+    which the cast then leaves as it is."""
+    from mpi_operator_tpu.kernels.quant_matmul import quant_ragged_dot
+
+    x, w = _xw(jax.random.PRNGKey(4))
+    if grouped:
+        sizes = jnp.array([20, 12], jnp.int32)
+        fn = lambda x, w: quant_ragged_dot(  # noqa: E731
+            x, jnp.stack([w, -w]), sizes, precision="fp8")
+    else:
+        fn = lambda x, w: quant_matmul(x, w, precision="fp8")  # noqa: E731
+    text = jax.jit(fn).lower(x, w).as_text()
+    assert text.count("reduce_precision") == 2, text  # both operands
+    assert text.count("format = e5m3") == 2, text
+    scaled = x / (jnp.max(jnp.abs(x), axis=-1, keepdims=True) / 448.0)
+    rounded = jax.lax.reduce_precision(scaled, 5, 3)
+    np.testing.assert_array_equal(
+        np.asarray(rounded),
+        np.asarray(scaled.astype(jnp.float8_e4m3fn).astype(jnp.float32)))

@@ -112,3 +112,38 @@ def test_long_context_16k_trains():
     grads = jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
     for g in grads:
         assert bool(jnp.all(jnp.isfinite(g.astype(jnp.float32))))
+
+
+def test_banded_kernels_at_the_mellum_shape_match_reference():
+    """The shape ``mellum2.steady-8k`` runs on a chip: b 2, 32 / 4 heads of
+    128, T 8192, window 1024 — the forward and the three gradients of the
+    banded kernels at their 1024 x 1024 tiles against the chunked
+    reference with the band as a mask on positions."""
+    q, k, v = _qkv(jax.random.PRNGKey(7), b=2, t=8192, h=32, hkv=4, d=128)
+    window = 1024
+    got = jax.jit(lambda *a: flash_attention(*a, causal=True, window=window))(
+        q, k, v)
+    want = jax.jit(lambda *a: chunked_reference(*a, window=window))(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=3e-2, rtol=3e-2,
+    )
+    # a band is not the causal mask: the two differ past the window
+    full = jax.jit(lambda *a: flash_attention(*a, causal=True))(q, k, v)
+    assert float(jnp.max(jnp.abs(
+        (full - got).astype(jnp.float32)[:, window:]))) > 0.1
+
+    def sq(fn):
+        return lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_).astype(jnp.float32) ** 2)
+
+    g1 = jax.jit(jax.grad(sq(lambda *a: flash_attention(
+        *a, causal=True, window=window)), argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(sq(lambda *a: chunked_reference(
+        *a, window=window)), argnums=(0, 1, 2)))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), g1, g2):
+        scale = max(1.0, float(jnp.max(jnp.abs(b.astype(jnp.float32)))))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32) / scale,
+            np.asarray(b, np.float32) / scale,
+            atol=5e-2, rtol=5e-2, err_msg=name,
+        )

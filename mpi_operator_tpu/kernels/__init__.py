@@ -3,12 +3,16 @@
 The reference has no kernels of its own — its hot path is Horovod/NCCL plus
 whatever cuDNN the workload images carry. Here the XLA-compiled model is
 already fast; these kernels target the ops where hand scheduling beats the
-compiler: attention (VMEM-resident online softmax, no [T,T] materialization).
+compiler: attention (VMEM-resident online softmax, no [T,T] materialization)
+and the routed feed-forward's grouped products (whole-width tiles over the
+row tiles that hold real rows).
 Written per /opt/skills/guides/pallas_guide.md; every kernel has an
 interpret-mode path so the CPU test suite checks numerics.
 """
 
 from mpi_operator_tpu.kernels.flash_attention import flash_attention
+from mpi_operator_tpu.kernels.grouped_matmul import grouped_matmul
 from mpi_operator_tpu.kernels.quant_matmul import quant_matmul, quant_ragged_dot
 
-__all__ = ["flash_attention", "quant_matmul", "quant_ragged_dot"]
+__all__ = ["flash_attention", "grouped_matmul", "quant_matmul",
+           "quant_ragged_dot"]

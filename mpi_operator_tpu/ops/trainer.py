@@ -197,7 +197,11 @@ class Trainer:
                 jax.device_put, model_state, self.model_state_sharding()
             )
         return TrainState(
-            step=jnp.zeros((), jnp.int32),
+            # on the mesh like the rest: left off it, the step's output (the
+            # next step's input) is of another type than this one, and the
+            # second step traces, lowers and loads the whole program again
+            step=jax.device_put(jnp.zeros((), jnp.int32),
+                                NamedSharding(self.mesh, PartitionSpec())),
             params=params,
             opt_state=opt_state,
             model_state=model_state if self.has_model_state else {},

@@ -16,9 +16,10 @@ assignment (tokens x experts_per_token rows), whatever the imbalance.
 
 How: the assignments are sorted by held expert (those to absent experts
 last), the tokens' rows gathered in that order, the three products made as
-grouped products over the held groups (``lax.ragged_dot``, which the TPU
-compiler lowers to its own grouped Mosaic kernel: rows past the last
-group are never touched), and the rows gathered back to their tokens with
+grouped products over the held groups (``kernels.grouped_matmul``: on a TPU
+the repo's own Pallas kernels ``moe_gmm`` / ``moe_gmm_dx`` / ``moe_gmm_dw``,
+elsewhere ``lax.ragged_dot``; rows past the last group are never
+touched), and the rows gathered back to their tokens with
 their weights. Both gathers have hand-written transposes that are gathers
 too: the sort is a permutation, so no scatter-add is ever needed.
 
@@ -43,6 +44,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from mpi_operator_tpu.kernels.grouped_matmul import grouped_matmul
 from mpi_operator_tpu.kernels.quant_matmul import quant_ragged_dot
 from mpi_operator_tpu.runtime.topology import AXIS_DATA, AXIS_EXPERT, AXIS_FSDP
 
@@ -150,7 +152,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 def _grouped(xs, w, group_sizes, precision: str):
     """xs[rows of group g] @ w[g] for every held group at once."""
     if precision == "bf16":
-        return lax.ragged_dot(xs, w, group_sizes)
+        return grouped_matmul(xs, w, group_sizes)
     return quant_ragged_dot(xs, w, group_sizes, precision=precision)
 
 

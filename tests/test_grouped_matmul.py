@@ -1,0 +1,191 @@
+"""kernels/grouped_matmul.py: the three kernels' bodies under the Pallas
+interpreter, at sizes the MXU tiles, against ``lax.ragged_dot`` and its
+``jax.vjp``. The chip's half is tests_tpu/test_moe_on_tpu.py."""
+
+import importlib
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+from jax.sharding import SingleDeviceSharding
+
+from mpi_operator_tpu.parallel import moe
+
+# the package exports the function under the module's name
+gm = importlib.import_module("mpi_operator_tpu.kernels.grouped_matmul")
+
+R, G = 1024, 4  # two row tiles of 512
+
+LAYOUTS = {
+    "even_groups": [256, 256, 256, 256],
+    "an_empty_group": [384, 0, 384, 256],
+    "every_row_in_one_group": [0, 1024, 0, 0],
+    "a_boundary_inside_a_tile": [100, 413, 1, 510],
+    "rows_past_the_last_group": [300, 0, 250, 63],
+}
+
+
+def _operands(sizes, k, n, dtype=jnp.bfloat16):
+    """Rows past the last group hold NaN, in ``xs`` and in the cotangent:
+    no result on a real row, and no ``d_w``, may have read them."""
+    key = jax.random.PRNGKey(sum(sizes) + k)
+    real = sum(sizes)
+    past = (jnp.arange(R) >= real)[:, None]
+    xs = jnp.where(past, jnp.nan, jax.random.normal(key, (R, k))).astype(dtype)
+    w = (jax.random.normal(jax.random.fold_in(key, 1), (G, k, n))
+         * k ** -0.5).astype(dtype)
+    ct = jnp.where(past, jnp.nan, jax.random.normal(
+        jax.random.fold_in(key, 2), (R, n))).astype(dtype)
+    return xs, w, ct, jnp.asarray(sizes, jnp.int32), real
+
+
+def _near(got, want, what):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.all(np.isfinite(got)), what
+    # both round a float32 sum to bf16 once: an ulp of the largest entry
+    np.testing.assert_allclose(got, want, atol=2 ** -7 * np.abs(want).max(),
+                               err_msg=what)
+
+
+@pytest.mark.parametrize("k,n", [(384, 896), (896, 384)])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("product", ["forward", "d_xs", "d_w"])
+def test_each_product_is_ragged_dots(product, layout, k, n):
+    xs, w, ct, sizes, real = _operands(LAYOUTS[layout], k, n)
+    zero_past = lambda a: jnp.where((jnp.arange(R) < real)[:, None], a, 0)
+    got, got_vjp = jax.vjp(
+        lambda a, b: gm.grouped_matmul(a, b, sizes, interpret=True), xs, w)
+    want, want_vjp = jax.vjp(
+        lambda a, b: lax.ragged_dot(a, b, sizes), zero_past(xs), w)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    if product == "forward":
+        _near(got[:real], want[:real], "forward")
+        return
+    d_xs, d_w = got_vjp(ct)
+    want_d_xs, want_d_w = want_vjp(zero_past(ct))
+    assert d_xs.dtype == want_d_xs.dtype and d_w.dtype == want_d_w.dtype
+    if product == "d_xs":
+        _near(d_xs[:real], want_d_xs[:real], "d_xs")
+    else:  # every group's, an empty group's too: zeros
+        _near(d_w, want_d_w, "d_w")
+
+
+@pytest.mark.parametrize("product", ["forward", "d_xs", "d_w"])
+def test_widths_the_budget_splits_are_accumulated_in_float32(
+        monkeypatch, product):
+    """A VMEM budget that 896 x 384 does not fit whole: 128 x 128 tiles,
+    seven or three steps of contraction kept in the float32 scratch."""
+    monkeypatch.setattr(gm, "_VMEM_BUDGET", 3 << 19)
+    assert gm._tiles(R, 896, 384, 2, gm._gmm_bytes) == (512, 128, 128)
+    assert gm._tiles(R, 896, 384, 2, gm._dw_bytes) == (512, 128, 128)
+    test_each_product_is_ragged_dots(
+        product, "a_boundary_inside_a_tile", 896, 384)
+
+
+def test_tiles_come_from_the_shapes():
+    """The cell's two products, whole-width where the budget allows; a row
+    count that is no multiple of 512 takes the power of two it has."""
+    assert gm._row_tile(131072) == 512 and gm._row_tile(96) == 32
+    for k, n in ((2304, 896), (896, 2304)):
+        for footprint in (gm._gmm_bytes, gm._dw_bytes):
+            tm, tk, tn = gm._tiles(131072, k, n, 2, footprint)
+            assert (131072 % tm, k % tk, n % tn) == (0, 0, 0)
+            assert tk % 128 == 0 and tn % 128 == 0
+            assert footprint(tm, tk, tn, 2) <= gm._VMEM_BUDGET
+    assert gm.tileable(4096, 256, 128)
+    assert not gm.tileable(96, 32, 48) and not gm.tileable(100, 128, 128)
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` of a jaxpr, through calls and kernel bodies."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dots(sub)
+
+
+def test_operands_are_bf16_and_the_accumulator_float32():
+    xs, w, ct, sizes, _ = _operands(LAYOUTS["even_groups"], 384, 896)
+
+    def all_three(xs, w, ct):
+        y, vjp = jax.vjp(
+            lambda a, b: gm.grouped_matmul(a, b, sizes, interpret=True), xs, w)
+        return y, vjp(ct)
+
+    closed = jax.make_jaxpr(all_three)(xs, w, ct)
+    dots = list(_dots(closed.jaxpr))
+    assert len(dots) >= 3  # moe_gmm, moe_gmm_dx, moe_gmm_dw
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [jnp.bfloat16] * 2
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+    y, (d_xs, d_w) = jax.eval_shape(all_three, xs, w, ct)
+    assert {y.dtype, d_xs.dtype, d_w.dtype} == {jnp.dtype(jnp.bfloat16)}
+
+
+def test_the_bf16_layer_holds_no_narrower_type(monkeypatch):
+    """``moe.apply`` at ``matmul_precision="bf16"``, as the CPU lowers it
+    and with the kernels in the grouped product's place: no int8 and no
+    fp8 anywhere in the text."""
+    p = moe.init(jax.random.PRNGKey(0), d_model=128, d_expert=256,
+                 n_experts=8, n_held=4)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 128), jnp.bfloat16)
+
+    def lowered():  # a new function each time: jit keeps no trace of the last
+        return jax.jit(jax.grad(lambda p, x: jnp.sum(moe.apply(
+            p, x, experts_per_token=2, matmul_precision="bf16")[0]
+            .astype(jnp.float32)))).lower(p, x).as_text()
+
+    texts = [lowered()]
+    monkeypatch.setattr(
+        moe, "_grouped", lambda xs, w, sizes, precision:
+        gm.grouped_matmul(xs, w, sizes, interpret=True))
+    texts.append(lowered())
+    assert texts[0] != texts[1]
+    for text in texts:
+        assert "bf16" in text
+        for narrow in ("i8", "f8E", "f8e"):
+            assert narrow not in text
+
+
+# -- the cell's shapes, compiled for the chip's compiler (no chip needed) -----
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip: Mosaic refuses here what it would refuse there
+    (a tile the VMEM limit does not hold, a slice off the tiling)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304)])
+def test_the_cells_products_compile_for_a_v5e_at_whole_width(one_chip, k, n):
+    """``mellum2.steady-8k``'s 131,072 rows in 16 groups: the three kernels
+    at the tiles the rule gives, under the names the trace is read by."""
+    rows, groups = 131072, 16
+    for footprint in (gm._gmm_bytes, gm._dw_bytes):
+        assert gm._tiles(rows, k, n, 2, footprint) == (512, k, n)
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def all_three(xs, w, ct, sizes):
+        y, vjp = jax.vjp(lambda a, b: gm._grouped(a, b, sizes, False), xs, w)
+        return y, vjp(ct)
+
+    text = jax.jit(all_three).lower(
+        spec((rows, k)), spec((groups, k, n)), spec((rows, n)),
+        spec((groups,), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in (r"moe_gmm_*\.\d", r"moe_gmm_dx_*\.\d", r"moe_gmm_dw_*\.\d"):
+        assert re.search(name, text), name

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mpi_operator_tpu.kernels import grouped_matmul
 from mpi_operator_tpu.kernels.quant_matmul import quant_ragged_dot
 from mpi_operator_tpu.parallel import moe
 from mpi_operator_tpu.runtime import MeshPlan, build_mesh
@@ -124,6 +125,51 @@ def test_over_a_mesh_the_shares_are_summed(whole, axes):
     for name in want_counters:
         assert float(counters[name]) == pytest.approx(
             float(want_counters[name]))
+
+
+@pytest.fixture(scope="module")
+def both_paths():
+    """The layer and its five gradients at a size the kernels tile (128
+    rows of 128, experts 256 wide, 4 of 8 held), by ``lax.ragged_dot`` (the
+    CPU's path) and with the Pallas kernels, interpreted, put in
+    ``_grouped``'s place."""
+    p = _share(moe.init(jax.random.PRNGKey(7), d_model=128, d_expert=256,
+                        n_experts=8, n_held=8), 2, 4)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 32, 128), jnp.float32)
+    cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def layer_and_gradients():
+        apply = lambda p, x: moe.apply(
+            p, x, first_expert=2, experts_per_token=K, **F32)[0]
+        y, vjp = jax.vjp(apply, p, x)
+        d_p, d_x = vjp(cot)
+        return {"y": y, "x": d_x, **{n: d_p[n]["w"] for n in d_p}}
+
+    want = layer_and_gradients()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "_grouped", lambda xs, w, sizes, precision:
+                      grouped_matmul(xs, w, sizes, interpret=True))
+        return layer_and_gradients(), want
+
+
+@pytest.mark.parametrize(
+    "leaf", ["y", "x", "router", "w_gate", "w_up", "w_down"])
+def test_with_the_kernels_the_layer_and_its_gradients_are_the_same(
+        both_paths, leaf):
+    got, want = both_paths
+    assert float(jnp.max(jnp.abs(want[leaf]))) > 0
+    np.testing.assert_allclose(got[leaf], want[leaf], atol=2e-5, rtol=2e-4)
+
+
+def test_off_the_tpu_the_grouped_products_are_ragged_dots(whole):
+    """The choice is an observation of the backend: on the CPU the step
+    holds ``lax.ragged_dot`` and its two transposes, and no kernel."""
+    p, x = whole
+    text = str(jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(moe.apply(
+        p, x, experts_per_token=K)[0].astype(jnp.float32)),
+        argnums=(0, 1)))(p, x))
+    assert text.count("ragged_dot_general") == 9  # three products, thrice
+    assert "pallas_call" not in text
 
 
 def test_int8_expert_products_are_near_and_not_the_same(whole):

@@ -58,6 +58,16 @@ def test_train_step_decreases_loss(dp_mesh):
     assert np.isfinite(losses).all()
 
 
+def test_the_step_is_traced_once(dp_mesh):
+    """A fresh state is of the type the step gives back (every leaf on the
+    mesh, ``step`` too): the second step finds the first's program and does
+    not trace, lower and load it again (seconds of a start at full size)."""
+    tr, state, batch = _mnist_setup(dp_mesh)
+    for _ in range(3):
+        state, _ = tr.train_step(state, batch)
+    assert tr._step_fn._cache_size() == 1
+
+
 def test_batch_is_sharded_over_data_axis(dp_mesh):
     tr, state, batch = _mnist_setup(dp_mesh)
     shard_shapes = {s.data.shape for s in batch["image"].addressable_shards}

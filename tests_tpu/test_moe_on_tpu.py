@@ -2,17 +2,23 @@
 
 tests/test_moe.py checks the layer against a plain loop over the experts
 on the CPU, where ``lax.ragged_dot`` is a masked dense product; this is the
-hardware half: the TPU compiler's own grouped Mosaic call, which leaves the
-rows past the last group untouched, the sort, both gathers and their
-hand-written transposes, at a width the MXU tiles (d 256, experts 128
-wide, 16 published of which 4 held, 2 a token), against that plain loop in
-float32 at ``highest`` precision."""
+hardware half: the grouped products as the Pallas kernels of
+kernels/grouped_matmul.py, which leave the rows past the last group
+untouched, the sort, both gathers and their hand-written transposes, at a
+width the MXU tiles (d 256, experts 128 wide, 16 published of which 4 held,
+2 a token), against that plain loop in float32 at ``highest`` precision;
+and one product at the benchmark cell's shapes against the compiler's own
+``lax.ragged_dot``, both timed."""
+
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
+from mpi_operator_tpu.kernels.grouped_matmul import grouped_matmul
 from mpi_operator_tpu.parallel import moe
 
 D, F, E, HELD, FIRST, K = 256, 128, 16, 4, 4, 2
@@ -90,3 +96,61 @@ def test_narrow_expert_products_run_and_differ(precision, low, high):
     gap = float(jnp.linalg.norm((yq - y).astype(jnp.float32))
                 / jnp.linalg.norm(y.astype(jnp.float32)))
     assert low < gap < high, gap
+
+
+def _ms(fn, *args, calls=20):
+    """Milliseconds a call, the best of three rounds, after two warm calls."""
+    for _ in range(2):
+        out = jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(3):
+        start = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - start) / calls * 1e3)
+    return best, out
+
+
+@pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304)])
+def test_the_cells_products_are_ragged_dots_and_faster(k, n):
+    """``mellum2.steady-8k``'s two shapes: 131,072 rows of which a quarter
+    are real, in 16 uneven groups. Forward and both transposes against
+    ``lax.ragged_dot``'s on the real rows (the others hold NaN going in),
+    each alone in its own program, both timed and printed (``-s``)."""
+    rows, groups = 131072, 16
+    rng = np.random.default_rng(k)
+    sizes = rng.multinomial(rows, [1 / 64] * 64)[:groups].astype(np.int32)
+    real = int(sizes.sum())
+    sizes = jnp.asarray(sizes)
+    key = jax.random.PRNGKey(n)
+    past = (jnp.arange(rows) >= real)[:, None]
+    xs = jnp.where(past, jnp.nan, jax.random.normal(
+        key, (rows, k), jnp.bfloat16))
+    w = (jax.random.normal(jax.random.fold_in(key, 1), (groups, k, n))
+         * k ** -0.5).astype(jnp.bfloat16)
+    ct = jnp.where(past, jnp.nan, jax.random.normal(
+        jax.random.fold_in(key, 2), (rows, n), jnp.bfloat16))
+
+    def three(product):
+        forward = jax.jit(lambda xs, w: product(xs, w, sizes))
+        d_xs = jax.jit(lambda ct, w: jax.vjp(
+            lambda a: product(a, w, sizes), jnp.zeros_like(xs))[1](ct)[0])
+        d_w = jax.jit(lambda xs, ct: jax.vjp(
+            lambda b: product(xs, b, sizes), jnp.zeros_like(w))[1](ct)[0])
+        return {"forward": _ms(forward, xs, w), "d_xs": _ms(d_xs, ct, w),
+                "d_w": _ms(d_w, xs, ct)}
+
+    got, want = three(grouped_matmul), three(lax.ragged_dot)
+    for name in got:
+        (ms, a), (ragged_ms, b) = got[name], want[name]
+        print(f"{k}x{n} {name}: kernel {ms:.3f} ms, "
+              f"lax.ragged_dot {ragged_ms:.3f} ms, {real} real rows")
+        if name != "d_w":
+            a, b = a[:real], b[:real]
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.all(np.isfinite(a)), name
+        # both round a float32 sum to bf16 once
+        np.testing.assert_allclose(a, b, atol=2 ** -7 * np.abs(b).max(),
+                                   err_msg=name)
+        assert ms < ragged_ms, name

@@ -15,7 +15,6 @@ and then a steady-state storm re-reconciles every job for R rounds while
 measuring per-sync latency and the server's read counters. Run it via::
 
   python bench_controlplane.py                      # both modes + compare
-  BENCH_MODEL=controlplane python bench.py          # same, no TPU work
 
 Knobs: BENCH_CP_JOBS, BENCH_CP_PODS, BENCH_CP_ROUNDS, BENCH_CP_MODES
 ("store", "informer", "write", "replica", "hist", "traceoverhead",

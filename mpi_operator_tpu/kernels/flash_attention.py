@@ -535,8 +535,8 @@ def _dense_reference(q, k, v, *, causal: bool, scale: float, window=None):
 def chunked_reference(q, k, v, *, causal: bool = True, scale=None,
                       block_q: int = 256, window=None):
     """The chunked XLA reference in *model* layout (q [B,T,H,D]) — the
-    independent lowering that on-hardware checks (bench.py's pre-timing
-    gate, tests_tpu/) compare the compiled kernel against."""
+    independent lowering that the on-hardware checks (tests_tpu/) compare
+    the compiled kernel against: same math, same bound on memory."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     return _chunked_reference(

@@ -10,12 +10,12 @@ first-class library, TPU-native:
 - every model exposes a ``logical_axes`` pytree (same structure as params)
   consumed by parallel/sharding.py — the same model runs pure-DP, FSDP, TP,
   or sequence-parallel by swapping the rule table, never by editing the model;
-- bf16 compute / f32 params+optimizer by default (MXU-native);
-- ``flops_per_sample`` accounting so bench.py can report MFU.
+- bf16 compute / f32 params+optimizer by default (MXU-native).
 
-Families: mnist (≙ examples/horovod/tensorflow_mnist.py and the MXNet MNIST),
-resnet (≙ tf_cnn_benchmarks --model=resnet101, the headline benchmark),
-llama (the BASELINE.md Llama-3-8B DP/long-context config).
+Families: mnist (≙ examples/horovod/tensorflow_mnist.py and the MXNet MNIST)
+and resnet (≙ tf_cnn_benchmarks --model=resnet101), the upstream-parity
+examples; llama, the decoder the benchmark's cells train (its operation
+counts live with the benchmark, benchmark/counts/).
 """
 
 from mpi_operator_tpu.models import llama, mnist, resnet

@@ -1,8 +1,9 @@
-"""Llama-family decoder (the BASELINE.md Llama-3-8B config).
+"""Llama-family decoder: the one model the training jobs of this repo
+run, at whatever widths a ``Config`` states (``Config()`` is Llama-3-8B;
+the benchmark's cells state Mistral-7B's and Mellum2's).
 
 The reference has no LLM workload — its examples top out at CNN scale
-(SURVEY.md §2.6) — but BASELINE.md's acceptance configs require a
-Llama-3-8B-class data-parallel + long-context workload. TPU-native design:
+(SURVEY.md §2.6). TPU-native design:
 
 - scan-over-layers: all layer params stacked on a leading axis and the
   decoder body is one ``lax.scan`` — O(1) HLO size regardless of depth,
@@ -176,20 +177,12 @@ def llama3_8b() -> Config:
 def bench_single_chip() -> Config:
     """Llama-3-architecture decoder (~0.79B params) sized so AdamW training
     fits one 16 GiB v5e chip: every matmul dim a multiple of 128 (MXU tiles),
-    GQA 4:1, d_ff = 3.5x like the 8B config. The compute-bound MFU
-    demonstration workload for bench.py's llama mode."""
+    GQA 4:1, d_ff = 3.5x like the 8B config. What ``chip_smoke.py`` and
+    the example worker's ``LLAMA_CONFIG=bench`` run."""
     return Config(
         vocab=32_768, d_model=2048, n_layers=12, n_heads=16, n_kv_heads=4,
         head_dim=128, d_ff=7168, remat_layers=True,
     )
-
-
-def bench_long_context() -> Config:
-    """The bench_single_chip architecture with a 16k vocab: the embed +
-    lm_head state (params + AdamW moments + grads, ~1 GB f32) is what
-    doesn't fit next to 16k-token activations on a 16 GiB chip. Used by
-    bench.py's llama mode above 8k sequence."""
-    return dataclasses.replace(bench_single_chip(), vocab=16_384)
 
 
 def tiny(vocab: int = 256) -> Config:
@@ -618,22 +611,18 @@ def _chunked_nll(feats, head, tokens, ce_chunk):
     return -jnp.sum(totals) / (b * n)
 
 
-def _feed_forward_params(c: Config, active: bool) -> int:
-    """A layer's feed-forward matrices: all that are held, or with
-    ``active`` those one token meets (a routed token meets
-    ``experts_per_token`` experts wherever they are held)."""
-    if not c.routed:
-        return 3 * c.d_model * c.d_ff
-    experts = c.experts_per_token if active else c.experts_held
-    return c.d_model * c.n_experts + experts * 3 * c.d_model * c.d_expert
-
-
 def param_count(config: Config) -> int:
+    """The parameters held: of a routed model's experts, this share's."""
     c = config
+    if c.routed:
+        feed_forward = (c.d_model * c.n_experts
+                        + c.experts_held * 3 * c.d_model * c.d_expert)
+    else:
+        feed_forward = 3 * c.d_model * c.d_ff
     per_layer = (
         c.d_model * (c.q_dim + 2 * c.kv_dim)
         + c.q_dim * c.d_model
-        + _feed_forward_params(c, active=False)
+        + feed_forward
         + 2 * c.d_model
     )
     return (
@@ -642,15 +631,3 @@ def param_count(config: Config) -> int:
         + c.d_model
         + c.d_model * c.vocab
     )
-
-
-def flops_per_token(config: Config, seq_len: int) -> float:
-    """Forward matmul FLOPs per token (2·MACs); attention term included."""
-    c = config
-    matmul_params = (
-        c.d_model * (c.q_dim + 2 * c.kv_dim)
-        + c.q_dim * c.d_model
-        + _feed_forward_params(c, active=True)
-    )
-    per_layer = 2 * matmul_params + 4 * seq_len * c.q_dim  # scores + PV
-    return float(c.n_layers * per_layer + 2 * c.d_model * c.vocab)

@@ -43,11 +43,6 @@ class TrainerConfig:
     grad_clip_norm: float = 1.0
     optimizer: str = "adamw"  # or "sgd", "momentum"
     momentum: float = 0.9
-    remat: bool = False  # jax.checkpoint the loss fn (trade FLOPs for HBM)
-    # adamw only: store the first moment in bf16 — halves its HBM footprint
-    # and per-step traffic for ~1 ulp of update noise (the second moment
-    # stays f32: its rsqrt is precision-sensitive)
-    adam_mu_bf16: bool = False
 
 
 @jax.tree_util.register_dataclass
@@ -76,7 +71,6 @@ def _optimizer(config: TrainerConfig) -> optax.GradientTransformation:
         opt = optax.adamw(
             sched, b1=config.beta1, b2=config.beta2,
             weight_decay=config.weight_decay,
-            mu_dtype=jnp.bfloat16 if config.adam_mu_bf16 else None,
         )
     elif config.optimizer == "momentum":
         opt = optax.sgd(sched, momentum=config.momentum)
@@ -122,13 +116,10 @@ class Trainer:
         self.rules = rules
         self.has_model_state = has_model_state
         self.tx = _optimizer(config)
-        if config.remat:
-            loss_fn = jax.checkpoint(loss_fn)
         self._loss_fn = loss_fn
         self._params_axes = params_axes
         self._model_state_axes = model_state_axes if has_model_state else {}
         self._step_fn = None
-        self._multi_fns = None  # n → compiled n-step scan (multi_step)
         self._donate = donate
 
     # -- shardings ---------------------------------------------------------
@@ -240,7 +231,7 @@ class Trainer:
     # -- the step ----------------------------------------------------------
 
     def _bare_step(self, state: TrainState, batch):
-        """The un-jitted step body (shared by train_step and multi_step)."""
+        """The un-jitted step body: what ``train_step`` jits."""
         # the two scopes put the step's phases into every operation's
         # `op_name` (metadata only): a device trace then splits forward,
         # backward, layer replay and optimizer (PERF.md §3)
@@ -285,8 +276,7 @@ class Trainer:
 
     def _jit_wrap(self, fn, state, batch_example):
         """jit a (state, batch) -> (state, metrics) function with the
-        trainer's shardings + donation (shared by train_step/multi_step so
-        the two paths can never drift)."""
+        trainer's shardings + donation."""
         state_sh = self.state_sharding(state)
         # every metric is a replicated scalar, whatever the loss names
         metrics_sh = NamedSharding(self.mesh, PartitionSpec())
@@ -301,32 +291,6 @@ class Trainer:
         if self._step_fn is None:
             self._step_fn = self._jit_wrap(self._bare_step, state, batch)
         return self._step_fn(state, batch)
-
-    def multi_step(self, state: TrainState, batch, n: int):
-        """Run ``n`` steps on one batch inside a single dispatch
-        (lax.scan over the step; ≙ tf_cnn_benchmarks' steps-per-session-run).
-        Per-dispatch host work — pytree flatten of hundreds of param leaves,
-        argument donation bookkeeping — is real wall time at small step
-        latencies (~5 ms/step on ResNet-101 v5e, measured); amortizing it
-        across n steps removes that gap. Returns (state, last metrics).
-        Intended for benchmarking/synthetic batches: every step consumes the
-        SAME batch (a production loop feeds fresh data per step)."""
-        if self._multi_fns is None:
-            self._multi_fns = {}
-        fn = self._multi_fns.get(n)
-        if fn is None:
-
-            def run(state, batch):
-                def body(s, _):
-                    s, m = self._bare_step(s, batch)
-                    return s, m
-
-                state, ms = jax.lax.scan(body, state, None, length=n)
-                return state, jax.tree.map(lambda x: x[-1], ms)
-
-            fn = self._jit_wrap(run, state, batch)
-            self._multi_fns[n] = fn
-        return fn(state, batch)
 
     def compile(self, state: TrainState, batch):
         """AOT-compile the step (returns the lowered+compiled executable;

@@ -11,8 +11,8 @@ read, IF the cache directory is the same one every time — the directory
 is part of jax's cache key, so a directory that moves never hits.
 
 Where the cache lives is decided in ONE place, :func:`configure_from_env`,
-called by the worker bootstrap (runtime/bootstrap.initialize), bench.py
-and anything else that compiles:
+called by the worker bootstrap (runtime/bootstrap.initialize) before
+anything of the job compiles:
 
 - ``$JAX_COMPILATION_CACHE_DIR`` set — jax read it at import; this module
   sets no other directory and appends nothing to it. That is how a

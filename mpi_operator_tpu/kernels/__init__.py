@@ -4,8 +4,10 @@ The reference has no kernels of its own — its hot path is Horovod/NCCL plus
 whatever cuDNN the workload images carry. Here the XLA-compiled model is
 already fast; these kernels target the ops where hand scheduling beats the
 compiler: attention (VMEM-resident online softmax, no [T,T] materialization)
-and the routed feed-forward's grouped products (whole-width tiles over the
-row tiles that hold real rows).
+the routed feed-forward's grouped products (whole-width tiles over the
+row tiles that hold real rows) and its elementwise passes over the same
+buffers (``row_map``: a map that stops at the last held row's tile, where
+the compiler's fusion runs over every row the static shape has).
 Written per /opt/skills/guides/pallas_guide.md; every kernel has an
 interpret-mode path so the CPU test suite checks numerics.
 """

@@ -432,9 +432,12 @@ SETUP_OVERLAPPED = ("ckpt_import",)
 # and the blob carries under `counters`: the routed feed-forward's
 # (parallel/moe.py) — how many of the step's assignments fell to experts
 # held here (mean a layer), the fullest held expert over the mean (worst
-# layer), assignments that found no row (0: the layer is dropless).
+# layer), assignments that found no row (0: the layer is dropless), the
+# rows of the assignment buffer that the passes in row order visit (mean a
+# layer: the held rows' tiles where those passes are bounded, every row
+# where they are not).
 TRAIN_COUNTERS = ("moe.assignments_held", "moe.load_max_over_mean",
-                  "moe.assignments_dropped")
+                  "moe.assignments_dropped", "moe.rows_worked")
 
 _PROFILE_KEYS = ("id", "state", "dir")
 

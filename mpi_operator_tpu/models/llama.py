@@ -535,9 +535,10 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
 def _step_counters(counters):
     """One scalar a counter for the step, from each layer's: how many
     assignments fell to held experts (mean a layer), the fullest held
-    expert over the mean (worst layer), assignments without a row (sum)."""
+    expert over the mean (worst layer), assignments without a row (sum),
+    rows the passes in row order visited (mean a layer)."""
     fold = {moe.ASSIGNMENTS_HELD: jnp.mean, moe.LOAD_MAX_OVER_MEAN: jnp.max,
-            moe.ASSIGNMENTS_DROPPED: jnp.sum}
+            moe.ASSIGNMENTS_DROPPED: jnp.sum, moe.ROWS_WORKED: jnp.mean}
     return {name: fold[name](values) for name, values in counters.items()}
 
 

@@ -21,7 +21,26 @@ the repo's own Pallas kernels ``moe_gmm`` / ``moe_gmm_dx`` / ``moe_gmm_dw``,
 elsewhere ``lax.ragged_dot``; rows past the last group are never
 touched), and the rows gathered back to their tokens with
 their weights. Both gathers have hand-written transposes that are gathers
-too: the sort is a permutation, so no scatter-add is ever needed.
+too: the sort is a permutation, so no scatter-add is ever needed. The
+gathers say that their indices are in bounds (a permutation's are), so no
+pass exists only to fill.
+
+The buffer has a row for every assignment, R = tokens x experts_per_token,
+and the held ones are its first H = sum(counts) rows. What runs over it *in
+row order* outside the grouped products stops at the tile that holds row
+H - 1: ``silu(gate) * up`` (``moe_silu_up``), its transpose
+(``moe_silu_up_t``), the sum of the two products' cotangents on the gathered
+rows (``moe_add``, in place of autodiff's ``add_any``) and the combine's
+transpose, ``d_ys = d_rows * w`` with ``d_w = sum(d_rows * ys)`` beside it
+(``moe_combine_t``, which gathers ``d_rows``, the tokens' cotangents in row
+order, itself and so for the visited rows only): each a ``kernels.row_map``
+whose grid's extent is ``cdiv(H, tile)``, read on the device. Rows past
+that tile are left as the buffer held them, and every reader masks them
+with ``held``. That path is taken where the grouped kernels are (a TPU,
+shapes that tile) and the share leaves some published expert out; anywhere
+else the same expressions run over all R rows in ``jax.numpy``. The other
+three gathers still move all R rows: the dispatch's, and the two in token
+order (the combine's and the dispatch's transpose).
 
 With a mesh that has an ``expert`` axis each member holds its share of
 ``w_gate`` / ``w_up`` / ``w_down`` and the partial results are summed over
@@ -31,19 +50,23 @@ layer runs without that exchange, and nothing stands in for absent chips.
 Scopes in the device trace: ``moe_router``, ``moe_dispatch``,
 ``moe_experts``, ``moe_combine``. Counters, as scalars of the step (no
 sync): ``moe.assignments_held``, ``moe.load_max_over_mean``,
-``moe.assignments_dropped`` (0, computed and not assumed).
+``moe.assignments_dropped`` (0, computed and not assumed),
+``moe.rows_worked`` (the rows the row-order passes visit:
+``cdiv(H, tile) * tile`` where they are bounded, R where they are not; over
+a mesh the members' sum).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from mpi_operator_tpu.kernels import row_map
 from mpi_operator_tpu.kernels.grouped_matmul import grouped_matmul
 from mpi_operator_tpu.kernels.quant_matmul import quant_ragged_dot
 from mpi_operator_tpu.runtime.topology import AXIS_DATA, AXIS_EXPERT, AXIS_FSDP
@@ -53,6 +76,7 @@ Params = Dict[str, Any]
 ASSIGNMENTS_HELD = "moe.assignments_held"
 LOAD_MAX_OVER_MEAN = "moe.load_max_over_mean"
 ASSIGNMENTS_DROPPED = "moe.assignments_dropped"
+ROWS_WORKED = "moe.rows_worked"
 
 
 def init(key, *, d_model: int, d_expert: int, n_experts: int,
@@ -98,13 +122,25 @@ def route(x32, router_w, experts_per_token: int):
 # ``pos[t, j]`` is the row of token t's j-th assignment in the sorted order,
 # ``row_token[r]`` the token of row r: one permutation and its inverse. Rows
 # of assignments to absent experts sort last; the grouped products never
-# touch them, so what they hold is undefined and every read of them is
-# masked with ``held`` (a select, not a product: it may be NaN).
+# touch them and the row-order passes stop before them, so what they hold
+# is undefined and every read of them is masked with ``held`` (a select, not
+# a product: it may be NaN).
+#
+# ``rows`` is H, the number of held rows (a scalar on the device);
+# ``interpret`` says how a pass in row order runs (``_row_passes``) and is
+# what ``kernels.row_map`` takes under that name.
+
+def _take(a, idx):
+    """a[idx] along the rows, for indices that are a permutation's: in
+    bounds, and said so (``take``'s default would follow the gather with a
+    pass that puts NaN where an index was not)."""
+    return jnp.take(a, idx, axis=0, mode="clip")
+
 
 @jax.custom_vjp
 def _dispatch(x, row_token, pos, held):
     """xs[r] = x[row_token[r]]."""
-    return jnp.take(x, row_token, axis=0)
+    return _take(x, row_token)
 
 
 def _dispatch_fwd(x, row_token, pos, held):
@@ -113,7 +149,7 @@ def _dispatch_fwd(x, row_token, pos, held):
 
 def _dispatch_bwd(res, d_xs):
     pos, held = res
-    rows = jnp.take(d_xs, pos, axis=0)  # [N, k, D]
+    rows = _take(d_xs, pos)  # [N, k, D]
     d_x = jnp.sum(jnp.where(held[..., None], rows, 0).astype(jnp.float32),
                   axis=1)
     return d_x.astype(d_xs.dtype), None, None, None
@@ -122,31 +158,111 @@ def _dispatch_bwd(res, d_xs):
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _combine(ys, weights, row_token, row_slot, pos, held):
+def _combine_t(d_rows, ys, w_rows):
+    """A row's part of the combine's transpose: (d_ys, d_w)."""
+    return d_rows * w_rows, jnp.sum(d_rows * ys, axis=-1, keepdims=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _combine(ys, weights, row_token, row_slot, pos, held, rows, interpret):
     """y[t] = sum_j weights[t, j] * ys[pos[t, j]] over the held j."""
-    rows = jnp.take(ys, pos, axis=0)  # [N, k, D]
-    rows = jnp.where(held[..., None], rows, 0).astype(jnp.float32)
-    return jnp.sum(rows * weights[..., None], axis=1).astype(ys.dtype)
+    gathered = _take(ys, pos)  # [N, k, D]
+    gathered = jnp.where(held[..., None], gathered, 0).astype(jnp.float32)
+    return jnp.sum(gathered * weights[..., None], axis=1).astype(ys.dtype)
 
 
-def _combine_fwd(ys, weights, row_token, row_slot, pos, held):
-    return (_combine(ys, weights, row_token, row_slot, pos, held),
-            (ys, weights, row_token, row_slot, pos, held))
+def _combine_fwd(ys, weights, row_token, row_slot, pos, held, rows,
+                 interpret):
+    return (_combine(ys, weights, row_token, row_slot, pos, held, rows,
+                     interpret),
+            (ys, weights, row_token, row_slot, pos, held, rows))
 
 
-def _combine_bwd(res, d_y):
-    ys, weights, row_token, row_slot, pos, held = res
-    # in row order: one gather of d_y serves both cotangents
-    d_rows = jnp.take(d_y, row_token, axis=0).astype(jnp.float32)  # [R, D]
-    w_rows = jnp.where(held, weights, 0.0).reshape(-1)[row_slot]  # [R]
-    d_ys = (d_rows * w_rows[:, None]).astype(ys.dtype)
-    d_w_rows = jnp.sum(d_rows * ys.astype(jnp.float32), axis=-1)  # [R]
-    d_w = jnp.where(held, jnp.take(d_w_rows, pos, axis=0), 0.0)
-    return d_ys, d_w.astype(weights.dtype), None, None, None, None
+def _moved(numbers, to):
+    """out[to[i]] = numbers[i], ``to`` a permutation: a sort by it. (As a
+    gather of 131,072 numbers by the inverse it takes the chip 1 to 2.5 ms,
+    a number at a time; the sort takes a tenth of one.)"""
+    return lax.sort((to, numbers), num_keys=1)[1]
+
+
+def _combine_bwd(interpret, res, d_y):
+    ys, weights, row_token, row_slot, pos, held, rows = res
+    # in row order: one gather of d_y [R, D] serves both cotangents
+    w_rows = _moved(jnp.where(held, weights, 0.0).reshape(-1),
+                    pos.reshape(-1))  # [R]
+    d_ys, d_w_rows = row_map.row_map(
+        _combine_t, ((d_y, row_token), ys, w_rows),
+        ((ys.shape[1], ys.dtype), (None, jnp.float32)), rows,
+        name="moe_combine_t", interpret=interpret)
+    d_w = jnp.where(held, _moved(d_w_rows, row_slot).reshape(pos.shape), 0.0)
+    return d_ys, d_w.astype(weights.dtype), None, None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+# -- between the products: the activation, and the rows read twice ------------
+
+def _silu_up(gate, up):
+    return (jax.nn.silu(gate) * up,)
+
+
+def _silu_up_t(d_h, gate, up):
+    """(d_gate, d_up) of ``silu(gate) * up``: silu'(g) = s (1 + g (1 - s)),
+    s the logistic of g."""
+    s = jax.nn.sigmoid(gate)
+    return d_h * up * s * (1.0 + gate * (1.0 - s)), d_h * gate * s
+
+
+def _add(a, b):
+    return (a + b,)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _activate(gate, up, rows, interpret):
+    """h = silu(gate) * up on the held rows."""
+    h, = row_map.row_map(_silu_up, (gate, up), ((gate.shape[1], gate.dtype),),
+                         rows, name="moe_silu_up", interpret=interpret)
+    return h
+
+
+def _activate_fwd(gate, up, rows, interpret):
+    return _activate(gate, up, rows, interpret), (gate, up, rows)
+
+
+def _activate_bwd(interpret, res, d_h):
+    gate, up, rows = res
+    d_gate, d_up = row_map.row_map(
+        _silu_up_t, (d_h, gate, up),
+        ((gate.shape[1], gate.dtype), (up.shape[1], up.dtype)), rows,
+        name="moe_silu_up_t", interpret=interpret)
+    return d_gate, d_up, None
+
+
+_activate.defvjp(_activate_fwd, _activate_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _twice(xs, rows, interpret):
+    """``xs`` for each of its two readers (the gate's product and the up's),
+    so that the sum of their two cotangents is this layer's to bound and not
+    autodiff's ``add_any`` over every row."""
+    return xs, xs
+
+
+def _twice_fwd(xs, rows, interpret):
+    return (xs, xs), rows
+
+
+def _twice_bwd(interpret, rows, d_both):
+    d_gate_xs, d_up_xs = d_both
+    d_xs, = row_map.row_map(
+        _add, (d_gate_xs, d_up_xs), ((d_gate_xs.shape[1], d_gate_xs.dtype),),
+        rows, name="moe_add", interpret=interpret)
+    return d_xs, None
+
+
+_twice.defvjp(_twice_fwd, _twice_bwd)
 
 
 def _grouped(xs, w, group_sizes, precision: str):
@@ -156,15 +272,31 @@ def _grouped(xs, w, group_sizes, precision: str):
     return quant_ragged_dot(xs, w, group_sizes, precision=precision)
 
 
+def _row_passes(r: int, d: int, f: int, held_here: int,
+                n_experts: int) -> Optional[bool]:
+    """How the passes in row order run, from what can be seen: as kernels
+    bounded by the held rows (``False``: compiled) where the grouped kernels
+    run (a TPU, widths that tile) and the rows come in lines of 128; over
+    all ``r`` rows in ``jax.numpy`` (``None``) anywhere else, and where the
+    share holds every published expert: no row can be absent then, so there
+    is nothing to pass over."""
+    if held_here == n_experts or jax.default_backend() != "tpu":
+        return None
+    return False if row_map.mappable(r, d, f) else None
+
+
 def _share(x, router_in, router_w, w_gate, w_up, w_down, first_expert, *,
            experts_per_token: int, compute_dtype, matmul_precision: str):
     """One member's part: x [N, D] -> (y [N, D], this share's counts [G],
-    assignments it could not give a row). ``router_in`` is what the router
-    reads (float32), ``first_expert`` may be traced (a mesh member's)."""
+    assignments it could not give a row, rows its row-order passes visit).
+    ``router_in`` is what the router reads (float32), ``first_expert`` may
+    be traced (a mesh member's)."""
     n, d = x.shape
     held_here = w_gate.shape[0]
     k = experts_per_token
     dt = compute_dtype
+    interpret = _row_passes(n * k, d, w_gate.shape[2], held_here,
+                            router_w.shape[1])
 
     with jax.named_scope("moe_router"):
         weights, experts = route(router_in, router_w, k)
@@ -175,6 +307,9 @@ def _share(x, router_in, router_w, w_gate, w_up, w_down, first_expert, *,
         counts = jnp.sum(
             key[:, None] == jnp.arange(held_here, dtype=key.dtype)[None, :],
             axis=0, dtype=jnp.int32)  # [G]
+        rows = jnp.sum(counts)  # H: the held rows are the buffer's first
+        worked = (n * k if interpret is None
+                  else row_map.rows_worked(rows, n * k))
 
     with jax.named_scope("moe_dispatch"):
         row_slot = jnp.argsort(key, stable=True).astype(jnp.int32)  # [R]
@@ -185,21 +320,23 @@ def _share(x, router_in, router_w, w_gate, w_up, w_down, first_expert, *,
         # for every assignment (R = N * k), so this reads 0; it is counted
         # from the buffer as built, for the day one is built smaller
         starts = jnp.cumsum(counts) - counts
-        dropped = jnp.sum(counts) - jnp.sum(
+        dropped = rows - jnp.sum(
             jnp.minimum(counts, jnp.maximum(xs.shape[0] - starts, 0)))
 
     with jax.named_scope("moe_experts"):
-        gate = _grouped(xs, w_gate.astype(dt), counts, matmul_precision)
-        up = _grouped(xs, w_up.astype(dt), counts, matmul_precision)
-        ys = _grouped(jax.nn.silu(gate) * up, w_down.astype(dt), counts,
-                      matmul_precision)
+        xs_gate, xs_up = _twice(xs, rows, interpret)
+        gate = _grouped(xs_gate, w_gate.astype(dt), counts, matmul_precision)
+        up = _grouped(xs_up, w_up.astype(dt), counts, matmul_precision)
+        ys = _grouped(_activate(gate, up, rows, interpret), w_down.astype(dt),
+                      counts, matmul_precision)
 
     with jax.named_scope("moe_combine"):
-        y = _combine(ys, weights, row_token, row_slot, pos, held)
-    return y, counts, dropped
+        y = _combine(ys, weights, row_token, row_slot, pos, held, rows,
+                     interpret)
+    return y, counts, dropped, worked
 
 
-def _counters(counts, dropped) -> Dict[str, jnp.ndarray]:
+def _counters(counts, dropped, worked) -> Dict[str, jnp.ndarray]:
     """From every held expert's count (all shares together)."""
     total = jnp.sum(counts).astype(jnp.float32)
     mean = jnp.maximum(total / counts.shape[0], 1e-9)
@@ -207,6 +344,7 @@ def _counters(counts, dropped) -> Dict[str, jnp.ndarray]:
         ASSIGNMENTS_HELD: total,
         LOAD_MAX_OVER_MEAN: jnp.max(counts).astype(jnp.float32) / mean,
         ASSIGNMENTS_DROPPED: jnp.asarray(dropped, jnp.float32),
+        ROWS_WORKED: jnp.asarray(worked, jnp.float32),
     }
 
 
@@ -238,33 +376,34 @@ def apply(params: Params, x, *, experts_per_token: int, first_expert: int = 0,
     batch_axes, experts_spread = _mesh_axes(mesh)
 
     if not batch_axes and not experts_spread:
-        y, counts, dropped = share(
+        y, *counted = share(
             x.reshape(b * t, d), router_in.reshape(b * t, d), *w,
             first_expert)
-        return y.reshape(b, t, d), _counters(counts, dropped)
+        return y.reshape(b, t, d), _counters(*counted)
 
     def member(x_, r_, router_w, w_gate, w_up, w_down):
         held_here = w_gate.shape[0]
         first = first_expert
         if experts_spread:
             first = first + lax.axis_index(AXIS_EXPERT) * held_here
-        y, counts, dropped = share(
+        y, counts, dropped, worked = share(
             x_.reshape(-1, d), r_.reshape(-1, d), router_w, w_gate, w_up,
             w_down, first)
+        worked = jnp.asarray(worked, jnp.int32)
         if batch_axes:  # every token's assignments, wherever its rows are
             counts = lax.psum(counts, batch_axes)
-            dropped = lax.psum(dropped, batch_axes)
+            dropped, worked = lax.psum((dropped, worked), batch_axes)
         if experts_spread:  # the shares' partial results add up
             y = lax.psum(y, AXIS_EXPERT)
             counts = lax.all_gather(counts, AXIS_EXPERT, tiled=True)
-            dropped = lax.psum(dropped, AXIS_EXPERT)
-        return y.reshape(x_.shape), counts, dropped
+            dropped, worked = lax.psum((dropped, worked), AXIS_EXPERT)
+        return y.reshape(x_.shape), counts, dropped, worked
 
     tokens = P(batch_axes or None, None, None)
     held = P(AXIS_EXPERT if experts_spread else None, None, None)
-    y, counts, dropped = jax.shard_map(
+    y, *counted = jax.shard_map(
         member, mesh=mesh,
         in_specs=(tokens, tokens, P(), held, held, held),
-        out_specs=(tokens, P(), P()), check_vma=False,
+        out_specs=(tokens, P(), P(), P()), check_vma=False,
     )(x, router_in, *w)
-    return y, _counters(counts, dropped)
+    return y, _counters(*counted)

@@ -1,6 +1,8 @@
 """kernels/grouped_matmul.py: the three kernels' bodies under the Pallas
 interpreter, at sizes the MXU tiles, against ``lax.ragged_dot`` and its
-``jax.vjp``. The chip's half is tests_tpu/test_moe_on_tpu.py."""
+``jax.vjp``. The chip's half is tests_tpu/test_moe_on_tpu.py. The compiles
+for a described chip at the end take in kernels/row_map.py's maps too: one
+file holds every test that loads the TPU's compiler."""
 
 import importlib
 import os
@@ -13,7 +15,9 @@ import pytest
 from jax import lax
 from jax.sharding import SingleDeviceSharding
 
+from mpi_operator_tpu.kernels import row_map
 from mpi_operator_tpu.parallel import moe
+from tests import poisoned_rows
 
 # the package exports the function under the module's name
 gm = importlib.import_module("mpi_operator_tpu.kernels.grouped_matmul")
@@ -129,12 +133,12 @@ def test_operands_are_bf16_and_the_accumulator_float32():
 
 
 def test_the_bf16_layer_holds_no_narrower_type(monkeypatch):
-    """``moe.apply`` at ``matmul_precision="bf16"``, as the CPU lowers it
-    and with the kernels in the grouped product's place: no int8 and no
-    fp8 anywhere in the text."""
-    p = moe.init(jax.random.PRNGKey(0), d_model=128, d_expert=256,
+    """``moe.apply`` at ``matmul_precision="bf16"``, as the CPU lowers it,
+    with the kernels in the grouped product's place, and with the passes in
+    row order as kernels too: no int8 and no fp8 anywhere in the text."""
+    p = moe.init(jax.random.PRNGKey(0), d_model=256, d_expert=128,
                  n_experts=8, n_held=4)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 128), jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 256), jnp.bfloat16)
 
     def lowered():  # a new function each time: jit keeps no trace of the last
         return jax.jit(jax.grad(lambda p, x: jnp.sum(moe.apply(
@@ -146,7 +150,9 @@ def test_the_bf16_layer_holds_no_narrower_type(monkeypatch):
         moe, "_grouped", lambda xs, w, sizes, precision:
         gm.grouped_matmul(xs, w, sizes, interpret=True))
     texts.append(lowered())
-    assert texts[0] != texts[1]
+    monkeypatch.setattr(moe, "_row_passes", lambda *shapes: True)
+    texts.append(lowered())
+    assert len(set(texts)) == 3
     for text in texts:
         assert "bf16" in text
         for narrow in ("i8", "f8E", "f8e"):
@@ -189,3 +195,36 @@ def test_the_cells_products_compile_for_a_v5e_at_whole_width(one_chip, k, n):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     for name in (r"moe_gmm_*\.\d", r"moe_gmm_dx_*\.\d", r"moe_gmm_dw_*\.\d"):
         assert re.search(name, text), name
+
+
+@pytest.mark.parametrize("name,width", [
+    ("moe_silu_up", 896), ("moe_silu_up_t", 896), ("moe_add", 2304),
+    ("moe_combine_t", 2304)])
+def test_the_cells_row_passes_compile_for_a_v5e(one_chip, name, width):
+    """The routed feed-forward's four maps over ``mellum2.steady-8k``'s
+    131,072 rows by 896 and by 2304, blocks of 512 rows by the whole width:
+    each one Mosaic call under the name the trace will show, inside the
+    VMEM limit its launcher states (Mosaic refuses a kernel that is not)."""
+    rows = 131072
+    assert row_map.row_tile(rows) == 512 and row_map.mappable(rows, width)
+    wide = jax.ShapeDtypeStruct((rows, width), jnp.bfloat16,
+                                sharding=one_chip)
+    a_row = jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=one_chip)
+    held = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    # the combine's transpose gathers its first operand's rows itself, from
+    # the 16,384 tokens' cotangent held whole in VMEM (75 MB)
+    tokens = jax.ShapeDtypeStruct((rows // 8, width), jnp.bfloat16,
+                                  sharding=one_chip)
+    row_of = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    assert row_map._gathers_inside(tokens) == (width == 2304)
+    body, operands, outs = poisoned_rows.the_layers_maps(
+        (wide,) * 3, a_row, tokens, row_of)[name]
+    text = jax.jit(lambda rows, *operands: row_map.row_map(
+        body, operands, outs, rows, name=name, interpret=False)).lower(
+        held, *operands).compile().as_text()
+    assert "jit(_take)" not in text  # no gather of the compiler's
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(name + r"_*\.\d", text), name
+    # a number a row goes in and comes out as it lies in HBM: no copy into
+    # a layout that pads it to a tile's width
+    assert not re.search(r"f32\[131072,1\]", text)

@@ -76,7 +76,9 @@ def test_a_routed_loss_returns_its_counters(routed):
     cfg, params = routed
     loss, counters = llama.loss_fn(cfg, params, {"tokens": TOKENS})
     assert set(counters) == {moe.ASSIGNMENTS_HELD, moe.LOAD_MAX_OVER_MEAN,
-                             moe.ASSIGNMENTS_DROPPED}
+                             moe.ASSIGNMENTS_DROPPED, moe.ROWS_WORKED}
+    # off the TPU the passes in row order run over the whole buffer
+    assert float(counters[moe.ROWS_WORKED]) == 2 * 24 * 2
     # half the experts held: about half of the 2 x 24 x 2 assignments
     assert 30 < float(counters[moe.ASSIGNMENTS_HELD]) < 66
     assert float(counters[moe.ASSIGNMENTS_DROPPED]) == 0
@@ -166,14 +168,17 @@ DENSE_STEP_DIGESTS = {
 }
 
 
-# the same for llama.tiny_routed(), as PR 30's commit lowered it (the
-# grouped products' kernels under the Pallas interpreter, which is what a
-# CPU lowers)
+# the same for llama.tiny_routed(), as PR 32's commit lowered it (on the
+# CPU: ``lax.ragged_dot``, there a masked dense product, and the passes in
+# row order over every row in ``jax.numpy``; PR 32 moved it: gathers that
+# say their indices are in bounds, the activation and the rows read twice
+# behind hand-written transposes, two gathers of numbers as sorts, one
+# counter more)
 ROUTED_STEP_DIGESTS = {
     (False, 32):
-        "5b216406c0e17e4c260bb63da9e1fbae385737947e854336af9327738c5d31f3",
+        "90162fcfebe465a9a1fafb98644a901ac958e3d5b3025f1454be516786d79d10",
     (True, 4096):
-        "c39fcb0377976cecba924495bca04012142b71d29dd49e6c64fd396e0f7493e8",
+        "08c5e9c0dacaeb4853edd296bcfe92699537caee6907a67f211864e276c97619",
 }
 
 
@@ -219,7 +224,8 @@ def test_the_step_puts_the_losss_scalars_into_its_metrics(routed):
     state, metrics = trainer.train_step(
         trainer.init_state(params), {"tokens": TOKENS})
     assert {"loss", "grad_norm", moe.ASSIGNMENTS_HELD,
-            moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED} == set(metrics)
+            moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED,
+            moe.ROWS_WORKED} == set(metrics)
     _, counters = llama.loss_fn(cfg, params, {"tokens": TOKENS})
     assert float(metrics[moe.ASSIGNMENTS_HELD]) == float(
         counters[moe.ASSIGNMENTS_HELD])
@@ -249,8 +255,10 @@ def test_the_counters_reach_the_blob(routed, tmp_path, monkeypatch):
     assert result.outcome == "done"
     blob = json.loads(stats_file.read_text())
     assert set(blob["counters"]) == {
-        moe.ASSIGNMENTS_HELD, moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED}
+        moe.ASSIGNMENTS_HELD, moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED,
+        moe.ROWS_WORKED}
     assert blob["counters"][moe.ASSIGNMENTS_DROPPED] == 0
+    assert blob["counters"][moe.ROWS_WORKED] == 2 * 24 * 2
     assert blob["counters"][moe.ASSIGNMENTS_HELD] == pytest.approx(
         result.metrics[moe.ASSIGNMENTS_HELD], abs=1e-3)
 

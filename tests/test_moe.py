@@ -10,11 +10,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mpi_operator_tpu.kernels import grouped_matmul
+from mpi_operator_tpu.kernels import grouped_matmul, row_map
 from mpi_operator_tpu.kernels.quant_matmul import quant_ragged_dot
 from mpi_operator_tpu.parallel import moe
 from mpi_operator_tpu.runtime import MeshPlan, build_mesh
 from mpi_operator_tpu.runtime.topology import AXIS_DATA, AXIS_EXPERT
+from tests import poisoned_rows
 
 D, F, E, K = 32, 48, 8, 2
 F32 = dict(compute_dtype=jnp.float32)
@@ -52,6 +53,7 @@ def test_the_whole_layer_is_the_plain_loop(whole):
     np.testing.assert_allclose(y, _loop(p, x), atol=1e-5, rtol=1e-5)
     assert float(counters[moe.ASSIGNMENTS_HELD]) == 2 * 24 * K
     assert float(counters[moe.ASSIGNMENTS_DROPPED]) == 0
+    assert float(counters[moe.ROWS_WORKED]) == 2 * 24 * K
 
 
 @pytest.mark.parametrize("shares", [2, 4, 8])
@@ -122,9 +124,11 @@ def test_over_a_mesh_the_shares_are_summed(whole, axes):
         p, x, experts_per_token=K, mesh=mesh, **F32))(p, x)
     want, want_counters = moe.apply(p, x, experts_per_token=K, **F32)
     np.testing.assert_allclose(y, want, atol=2e-5, rtol=2e-5)
+    # every member of the expert axis works a buffer of its own
+    buffers = {moe.ROWS_WORKED: axes.get(AXIS_EXPERT, 1)}
     for name in want_counters:
         assert float(counters[name]) == pytest.approx(
-            float(want_counters[name]))
+            float(want_counters[name]) * buffers.get(name, 1))
 
 
 @pytest.fixture(scope="module")
@@ -159,6 +163,102 @@ def test_with_the_kernels_the_layer_and_its_gradients_are_the_same(
     got, want = both_paths
     assert float(jnp.max(jnp.abs(want[leaf]))) > 0
     np.testing.assert_allclose(got[leaf], want[leaf], atol=2e-5, rtol=2e-4)
+
+
+# -- the passes in row order, bounded by the held rows -----------------------
+
+ROWS, TILE = 1152, 128  # 2 x 288 tokens, two assignments each: nine tiles
+
+
+@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+def bounded(request):
+    """The same layer over 1152 rows (tiles of 128) of which about half are
+    held, by the CPU's path and on the bounded one: every kernel under the
+    Pallas interpreter, every row past the held ones NaN before and after
+    every pass (tests/poisoned_rows.py). In bfloat16 the combine's
+    transpose gathers its rows inside the kernel."""
+    dtype = jnp.dtype(request.param)
+    p = _share(moe.init(jax.random.PRNGKey(7), d_model=256, d_expert=128,
+                        n_experts=8, n_held=8), 2, 4)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 288, 256), jnp.float32)
+    cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def layer_and_gradients():
+        counters = {}
+
+        def apply(p, x):
+            y, seen = moe.apply(p, x.astype(dtype), first_expert=2,
+                                experts_per_token=K, compute_dtype=dtype,
+                                router_in=x)
+            counters.update(jax.tree.map(jax.lax.stop_gradient, seen))
+            return y.astype(jnp.float32)
+
+        y, vjp = jax.vjp(apply, p, x)
+        d_p, d_x = vjp(cot)
+        return ({"y": y, "x": d_x, **{n: d_p[n]["w"] for n in d_p}},
+                {n: float(v) for n, v in counters.items()})
+
+    want = layer_and_gradients()
+    with poisoned_rows.patched(interpret=True):
+        return layer_and_gradients(), want, dtype
+
+
+@pytest.mark.parametrize(
+    "leaf", ["y", "x", "router", "w_gate", "w_up", "w_down"])
+def test_bounded_and_poisoned_the_layer_and_its_gradients_are_the_same(
+        bounded, leaf):
+    (got, _), (want, _), dtype = bounded
+    scale = float(jnp.max(jnp.abs(want[leaf])))
+    assert scale > 0
+    assert bool(jnp.all(jnp.isfinite(got[leaf])))
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got[leaf], want[leaf], atol=2e-5,
+                                   rtol=2e-4)
+    else:  # both round float32 results to bf16, in another order of sums
+        np.testing.assert_allclose(got[leaf] / scale, want[leaf] / scale,
+                                   atol=2 ** -6)
+
+
+def test_rows_worked_is_the_visited_tiles_or_the_whole_buffer(bounded):
+    (_, got), (_, want), _ = bounded
+    held = want[moe.ASSIGNMENTS_HELD]
+    assert 0 < held < ROWS - TILE and held % TILE  # a tile is cut
+    assert row_map.row_tile(ROWS) == TILE
+    assert got[moe.ROWS_WORKED] == -(-held // TILE) * TILE
+    assert want[moe.ROWS_WORKED] == ROWS
+    for name in (moe.ASSIGNMENTS_HELD, moe.LOAD_MAX_OVER_MEAN,
+                 moe.ASSIGNMENTS_DROPPED):
+        assert got[name] == want[name]
+
+
+@pytest.mark.parametrize("shapes,path", [
+    ((131072, 2304, 896, 16, 64), False),  # the cell's, on a TPU
+    ((131072, 2304, 896, 64, 64), None),   # every published expert held
+    ((96, 32, 48, 4, 8), None),            # widths the kernels do not tile
+    ((144, 128, 128, 4, 8), None),         # rows they do not
+])
+def test_the_path_is_chosen_from_what_can_be_seen(monkeypatch, shapes, path):
+    """Bounded where the grouped kernels run and a published expert is
+    left out; off the TPU never."""
+    assert moe._row_passes(*shapes) is None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe._row_passes(*shapes) is path
+
+
+def test_no_gather_is_followed_by_a_fill(whole):
+    """Four gathers of rows by a permutation's indices, each told that they
+    are in bounds: the lowered step holds no select under ``jit(_take)``
+    (what ``take``'s default puts NaN with, a pass over the whole buffer)."""
+    p, x = whole
+    text = jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(moe.apply(
+        _share(p, 2, 4), x, first_expert=2, experts_per_token=K)[0]
+        .astype(jnp.float32)), argnums=(0, 1))).lower(p, x).as_text()
+    takes = text.split("func.func private @_take")[1:]
+    assert len(takes) == text.count("call @_take") == 4
+    for body in takes:
+        body = body.split("func.func")[0]
+        assert "stablehlo.gather" in body
+        assert "stablehlo.select" not in body and "nan" not in body.lower()
 
 
 def test_off_the_tpu_the_grouped_products_are_ragged_dots(whole):

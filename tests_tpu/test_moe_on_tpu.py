@@ -6,9 +6,12 @@ hardware half: the grouped products as the Pallas kernels of
 kernels/grouped_matmul.py, which leave the rows past the last group
 untouched, the sort, both gathers and their hand-written transposes, at a
 width the MXU tiles (d 256, experts 128 wide, 16 published of which 4 held,
-2 a token), against that plain loop in float32 at ``highest`` precision;
-and one product at the benchmark cell's shapes against the compiler's own
-``lax.ragged_dot``, both timed."""
+2 a token), against that plain loop in float32 at ``highest`` precision,
+once more with every row past the held ones NaN around every pass
+(tests/poisoned_rows.py); one product at the benchmark cell's shapes against
+the compiler's own ``lax.ragged_dot``, both timed; and the passes in row
+order (kernels/row_map.py) at those shapes, bounded by a quarter of the rows
+and by all of them, against the compiler's own fusions over all rows."""
 
 import time
 
@@ -18,8 +21,10 @@ import numpy as np
 import pytest
 from jax import lax
 
+from mpi_operator_tpu.kernels import row_map
 from mpi_operator_tpu.kernels.grouped_matmul import grouped_matmul
 from mpi_operator_tpu.parallel import moe
+from tests import poisoned_rows
 
 D, F, E, HELD, FIRST, K = 256, 128, 16, 4, 4, 2
 
@@ -63,8 +68,33 @@ def test_compiled_share_matches_the_plain_loop_and_drops_nothing():
         atol=3e-2)
     assert float(counters[moe.ASSIGNMENTS_DROPPED]) == 0.0
     # a quarter of the experts held: about a quarter of the assignments
-    held = float(counters[moe.ASSIGNMENTS_HELD]) / (x.shape[0] * x.shape[1] * K)
-    assert 0.15 < held < 0.35
+    rows = x.shape[0] * x.shape[1] * K
+    held = float(counters[moe.ASSIGNMENTS_HELD])
+    assert 0.15 < held / rows < 0.35
+    # on the chip the passes in row order stop at the last held row's tile
+    tile = row_map.row_tile(rows)
+    assert tile == 512
+    assert float(counters[moe.ROWS_WORKED]) == -(-held // tile) * tile < rows
+
+
+def test_with_poisoned_tails_the_compiled_share_and_its_gradients_hold():
+    """NaN in every row past the held ones of every buffer, before and
+    after every grouped product and every pass in row order, forward and
+    backward: the layer and its five gradients are finite and the plain
+    loop's. What a row past the held ones holds reaches nothing."""
+    p, x = _setup(jax.random.PRNGKey(4))
+    through = lambda fn: jax.jit(jax.value_and_grad(
+        lambda p, x: jnp.mean(fn(p, x).astype(jnp.float32) ** 2),
+        argnums=(0, 1)))(p, x)
+    with poisoned_rows.patched(interpret=False):
+        got = through(lambda p, x: _apply(p, x)[0])
+    want = through(_loop)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32) / scale, np.asarray(b) / scale,
+            atol=5e-2)
 
 
 def test_compiled_gradients_match_the_plain_loop():
@@ -154,3 +184,44 @@ def test_the_cells_products_are_ragged_dots_and_faster(k, n):
         np.testing.assert_allclose(a, b, atol=2 ** -7 * np.abs(b).max(),
                                    err_msg=name)
         assert ms < ragged_ms, name
+
+
+@pytest.mark.parametrize("name,width", [
+    ("moe_silu_up", 896), ("moe_silu_up_t", 896), ("moe_add", 2304),
+    ("moe_combine_t", 2304)])
+def test_the_cells_row_passes_are_their_expressions_and_faster(name, width):
+    """``mellum2.steady-8k``'s buffers, 131,072 rows: each map bounded by a
+    quarter of the rows (what the cell holds) and by all of them (the worst
+    case) against the same body as the compiler fuses it over all rows, all
+    timed and printed (``-s``). Bounded by a quarter it must win; by all of
+    them it may cost 5 % more, or ``moe._row_passes`` is wrong to take it
+    whenever an expert is absent."""
+    rows, bf16 = 131072, jnp.bfloat16
+    key = jax.random.PRNGKey(width)
+    wide = [jax.random.normal(jax.random.fold_in(key, i), (rows, width), bf16)
+            for i in range(3)]
+    a_row = jax.random.normal(key, (rows,), jnp.float32)
+    # the combine's transpose gathers its first operand from the tokens'
+    # rows, eight assignments a token
+    tokens = jax.random.normal(jax.random.fold_in(key, 3),
+                               (rows // 8, width), bf16)
+    row_of = jax.random.permutation(key, rows).astype(jnp.int32) // 8
+    body, operands, outs = poisoned_rows.the_layers_maps(
+        wide, a_row, tokens, row_of)[name]
+    fused = jax.jit(lambda *a: row_map.row_map(body, a, outs, rows, name=name))
+    kernel = jax.jit(lambda held, *a: row_map.row_map(
+        body, a, outs, held, name=name, interpret=False))
+    fused_ms, want = _ms(fused, *operands)
+    quarter_ms, got = _ms(kernel, jnp.int32(rows // 4 + 5), *operands)
+    all_ms, _ = _ms(kernel, jnp.int32(rows), *operands)
+    jax.block_until_ready(kernel(jnp.int32(0), *operands))  # no tile at all
+    print(f"{name} {rows}x{width}: fused over all rows {fused_ms:.3f} ms, "
+          f"bounded by a quarter {quarter_ms:.3f} ms, by all {all_ms:.3f} ms")
+    worked = int(row_map.rows_worked(rows // 4 + 5, rows))
+    for a, b in zip(got, want):
+        a = np.asarray(a[:worked], np.float32)
+        b = np.asarray(b[:worked], np.float32)
+        np.testing.assert_allclose(a, b, rtol=2 ** -6, atol=2 ** -6,
+                                   err_msg=name)
+    assert quarter_ms < 0.5 * fused_ms, name
+    assert all_ms < 1.05 * fused_ms, name

@@ -8,13 +8,17 @@ the routed feed-forward's grouped products (whole-width tiles over the
 row tiles that hold real rows) and its elementwise passes over the same
 buffers (``row_map``: a map that stops at the last held row's tile, where
 the compiler's fusion runs over every row the static shape has).
+``ssd`` is the state-space layers' chunked scan and short convolution: not
+a Pallas kernel yet but ``jax.numpy`` products the compiler lowers, kept
+here so that the kernel which replaces them has its place and its tests.
 Written per /opt/skills/guides/pallas_guide.md; every kernel has an
 interpret-mode path so the CPU test suite checks numerics.
 """
 
+from mpi_operator_tpu.kernels import ssd
 from mpi_operator_tpu.kernels.flash_attention import flash_attention
 from mpi_operator_tpu.kernels.grouped_matmul import grouped_matmul
 from mpi_operator_tpu.kernels.quant_matmul import quant_matmul, quant_ragged_dot
 
 __all__ = ["flash_attention", "grouped_matmul", "quant_matmul",
-           "quant_ragged_dot"]
+           "quant_ragged_dot", "ssd"]

@@ -34,7 +34,9 @@ Tiles come from the shapes: ``tm`` the largest power of two up to 512 that
 divides R; ``tk`` and ``tn`` the largest multiples of 128 that divide K and
 N and fit the VMEM budget double-buffered (whole widths for experts 896
 and 2304 wide: a product is then ~80 grid steps of MXU work, not the ~8,000
-of 128 x 128 tiles).
+of 128 x 128 tiles). A width that is no multiple of 128 (experts 1856 wide)
+is taken whole, as the one block that may end off a lane tile: one equal to
+the array's extent.
 """
 
 from __future__ import annotations
@@ -66,7 +68,10 @@ def _row_tile(r: int) -> int:
 
 
 def _lane_tiles(width: int):
-    """Multiples of 128 that divide ``width``, largest first."""
+    """Multiples of 128 that divide ``width``, largest first; of a width
+    that is no multiple of 128, the whole width alone."""
+    if width % _LANE:
+        return [width]
     return [d * _LANE for d in range(width // _LANE, 0, -1)
             if width % (d * _LANE) == 0]
 
@@ -92,9 +97,24 @@ def _tiles(r: int, k: int, n: int, itemsize: int, footprint):
     raise ValueError(f"no tile of a {k} x {n} product fits the VMEM budget")
 
 
+def whole_or_tiled(width: int) -> bool:
+    """A width a block can span: a multiple of 128 (tiled), or one past 128
+    in whole packed sublanes (a single whole-width block)."""
+    return width % _LANE == 0 or (width > _LANE and width % _MIN_TM == 0)
+
+
 def tileable(r: int, k: int, n: int) -> bool:
-    """Whether the kernels take these shapes as they are (no padding)."""
-    return (k % _LANE == 0 and n % _LANE == 0 and r % _MIN_TM == 0)
+    """Whether the kernels take these shapes as they are (no padding):
+    widths in whole packed sublanes (a multiple of 128 is tiled, another
+    is one whole-width block), and tiles that fit the VMEM budget."""
+    if r % _MIN_TM or not (whole_or_tiled(k) and whole_or_tiled(n)):
+        return False
+    try:
+        _tiles(r, k, n, 2, _gmm_bytes)
+        _tiles(r, k, n, 2, _dw_bytes)
+    except ValueError:
+        return False
+    return True
 
 
 def _visits(group_sizes, r: int, tm: int, *, empty_groups: bool):
@@ -324,7 +344,7 @@ def grouped_matmul(xs, w, group_sizes, *, interpret: Optional[bool] = None):
     as the buffer held them. Differentiable in ``xs`` and ``w``.
 
     ``interpret=None`` runs the kernels on a TPU where the shapes tile
-    (K and N multiples of 128, R of 16) and ``lax.ragged_dot`` anywhere
+    (:func:`tileable`) and ``lax.ragged_dot`` anywhere
     else (on the CPU a masked dense product); ``interpret=True`` reaches
     the kernels' bodies off the TPU, for their tests."""
     r, k = xs.shape

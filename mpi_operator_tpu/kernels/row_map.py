@@ -19,7 +19,8 @@ what a map over the whole buffer computes.
 
 Three kinds of operand, all seen by ``body`` as float32 ``[rows, W]``:
 
-- a buffer ``[R, W]``, W a multiple of 128: a block is ``tile`` rows (the
+- a buffer ``[R, W]``, W a multiple of 16 (a block is the whole width, so
+  it may end off a lane tile): a block is ``tile`` rows (the
   grouped products' own ``_row_tile``) by the whole width, worked through
   in chunks of 128 rows by a loop (the compiler unrolls an array's
   operations, not a loop);
@@ -53,6 +54,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from mpi_operator_tpu.kernels.grouped_matmul import _LANE
 from mpi_operator_tpu.kernels.grouped_matmul import _row_tile as row_tile
+from mpi_operator_tpu.kernels.grouped_matmul import whole_or_tiled
 
 _VMEM = 128 << 20  # a v5e core's
 _SOURCE_LIMIT = 96 << 20  # what a gathered source may take of it
@@ -67,8 +69,9 @@ def rows_worked(rows, r: int):
 
 
 def mappable(r: int, *widths: int) -> bool:
-    """Whether the kernel takes buffers of these shapes as they are."""
-    return r % _LANE == 0 and all(w % _LANE == 0 for w in widths)
+    """Whether the kernel takes buffers of these shapes as they are: rows
+    in lines of 128, widths (each a whole block) in packed sublanes."""
+    return r % _LANE == 0 and all(whole_or_tiled(w) for w in widths)
 
 
 def _gathers_inside(source) -> bool:

@@ -435,9 +435,12 @@ SETUP_OVERLAPPED = ("ckpt_import",)
 # layer), assignments that found no row (0: the layer is dropless), the
 # rows of the assignment buffer that the passes in row order visit (mean a
 # layer: the held rows' tiles where those passes are bounded, every row
-# where they are not).
+# where they are not); and the state-space layers' (models/mamba2.py) — the
+# share of the scan's (row, chunk, head) whose decay over the whole chunk
+# exceeds 0.1, so that state is handed from chunk to chunk (mean a layer).
 TRAIN_COUNTERS = ("moe.assignments_held", "moe.load_max_over_mean",
-                  "moe.assignments_dropped", "moe.rows_worked")
+                  "moe.assignments_dropped", "moe.rows_worked",
+                  "ssm.carry_share")
 
 _PROFILE_KEYS = ("id", "state", "dir")
 

@@ -16,12 +16,21 @@ The reference has no LLM workload — its examples top out at CNN scale
   global-view), only the attention inner loop is manually ring-scheduled;
 - logical-axis pytree drives DP/FSDP/TP/SP resharding with zero model edits;
 - one decoder for every family it runs: the scan walks *periods* of layers,
-  and the kinds inside a period (``Config.layer_kinds``: full causal
-  attention, or a sliding window of keys, each with its own RoPE) are
-  unrolled in the scan's body, so a model of one kind compiles to the plain
-  scan over layers; the feed-forward is dense (SwiGLU) or routed
-  (``Config.n_experts`` > 0: parallel/moe.py's share of the experts). What
-  a model is follows from its configuration's shape; there is no switch.
+  and the kinds inside a period (``Config.layer_kinds``) are unrolled in the
+  scan's body, so a model of one kind compiles to the plain scan over
+  layers. A layer is a *pair* or a *single mixer*. A pair (``full``,
+  ``window``) is attention then a feed-forward, each behind its own norm and
+  residual: full causal attention or a sliding window of keys, each with its
+  own RoPE; the feed-forward dense (SwiGLU) or routed (``Config.n_experts``
+  > 0: parallel/moe.py's share of the experts). A single mixer (``mamba``,
+  ``experts``, ``attention``) is ``x + mixer(rmsnorm(x))`` and nothing else:
+  a Mamba-2 state-space layer (models/mamba2.py), a routed layer alone, or
+  causal attention with no positional embedding (position comes through the
+  state-space layers). Pairs share one parameter tree, stacked over layers;
+  single mixers have unlike trees, so theirs stack by kind
+  (``params["layers"][kind]``) and a period's body takes the next layer of
+  each kind as it walks the pattern. What a model is follows from its
+  configuration's shape; there is no switch.
 """
 
 from __future__ import annotations
@@ -34,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from mpi_operator_tpu.models import mamba2
 from mpi_operator_tpu.parallel import moe
 from mpi_operator_tpu.parallel.ring_attention import (
     dense_attention,
@@ -48,7 +58,9 @@ from mpi_operator_tpu.runtime.topology import AXIS_SEQ
 Params = Dict[str, Any]
 
 
-LAYER_KINDS = ("full", "window")
+PAIR_KINDS = ("full", "window")  # attention, then a feed-forward
+MIXER_KINDS = ("mamba", "experts", "attention")  # one mixer a layer
+LAYER_KINDS = PAIR_KINDS + MIXER_KINDS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,9 +115,11 @@ class Config:
     # jax.checkpoint would not: its backward recomputation re-materializes
     # all layer intermediates at once)
     remat_layers: bool = False
-    # one period of the layer pattern: the attention of each of its layers,
-    # "full" (causal) or "window" (query i sees the ``window`` keys up to
-    # its own). The stack is n_layers / len(layer_kinds) periods.
+    # one period of the layer pattern. Pairs, by their attention: "full"
+    # (causal) or "window" (query i sees the ``window`` keys up to its
+    # own). Or single mixers: "mamba", "experts" (the routed layer alone),
+    # "attention" (causal, no positional embedding). One or the other, not
+    # both in one pattern. The stack is n_layers / len(layer_kinds) periods.
     layer_kinds: Tuple[str, ...] = ("full",)
     window: Optional[int] = None
     # RoPE by kind: window layers rotate at the plain ``rope_theta``, full
@@ -120,6 +134,23 @@ class Config:
     first_expert: int = 0
     experts_per_token: int = 0
     d_expert: int = 0
+    # the routed layer's form (parallel/moe.py): the score function
+    # ("softmax", or "sigmoid" with a correction bias in the choice), a
+    # factor on the weights, gated SwiGLU experts or ungated relu ** 2 ones,
+    # and the width of a shared expert (0: none; ungated experts only)
+    router_score: str = "softmax"
+    router_scale: float = 1.0
+    experts_gated: bool = True
+    d_shared: int = 0
+    # the state-space layers (models/mamba2.py): heads and their size,
+    # groups that share B and C, the state's size, the convolution's
+    # kernel and the scan's chunk (a sequence is whole chunks)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    conv_kernel: int = 4
+    ssm_chunk: int = 128
 
     def __post_init__(self):
         if self.attention_impl not in ("auto", "dense", "flash"):
@@ -135,6 +166,28 @@ class Config:
         kinds = self.layer_kinds
         if not kinds or any(k not in LAYER_KINDS for k in kinds):
             raise ValueError(f"layer_kinds={kinds!r}; each of {LAYER_KINDS}")
+        if len({k in MIXER_KINDS for k in kinds}) != 1:
+            raise ValueError(
+                f"layer_kinds={kinds!r}: pairs {PAIR_KINDS} or single "
+                f"mixers {MIXER_KINDS}, not both in one pattern")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score={self.router_score!r}; "
+                             "expected softmax|sigmoid")
+        if self.d_shared and self.experts_gated:
+            raise ValueError("a shared expert is an ungated relu ** 2 "
+                             "feed-forward: experts_gated must be False")
+        if "experts" in kinds and not self.n_experts:
+            raise ValueError("an 'experts' layer needs n_experts")
+        if "mamba" in kinds and not (
+                self.ssm_heads > 0 and self.ssm_head_dim > 0
+                and self.ssm_state > 0 and self.ssm_chunk > 0
+                and self.conv_kernel > 0 and self.ssm_groups > 0
+                and self.ssm_heads % self.ssm_groups == 0):
+            raise ValueError(
+                f"state-space layers: {self.ssm_heads} heads of "
+                f"{self.ssm_head_dim} in {self.ssm_groups} groups, state "
+                f"{self.ssm_state}, kernel {self.conv_kernel}, chunk "
+                f"{self.ssm_chunk}")
         if self.n_layers % len(kinds):
             raise ValueError(
                 f"n_layers={self.n_layers} is no whole number of periods "
@@ -156,6 +209,10 @@ class Config:
     @property
     def routed(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def single_mixers(self) -> bool:
+        return self.layer_kinds[0] in MIXER_KINDS
 
     @property
     def experts_held(self) -> int:
@@ -206,8 +263,64 @@ def tiny_routed(vocab: int = 256) -> Config:
     )
 
 
+def tiny_hybrid(vocab: int = 256) -> Config:
+    """Test-scale config of the single-mixer shape: a period holding all
+    three kinds (state-space, routed with a shared expert, attention without
+    positions), sigmoid routing over 8 experts of which 2 a token and the
+    first 4 held, 4 state-space heads in 2 groups, chunks of 8."""
+    return Config(
+        vocab=vocab, d_model=48, n_layers=5, n_heads=4, n_kv_heads=2,
+        head_dim=16, norm_eps=1e-5,
+        layer_kinds=("mamba", "experts", "mamba", "attention", "experts"),
+        n_experts=8, n_experts_held=4, experts_per_token=2, d_expert=24,
+        router_score="sigmoid", router_scale=2.5, experts_gated=False,
+        d_shared=40, ssm_heads=4, ssm_head_dim=8, ssm_groups=2, ssm_state=16,
+        ssm_chunk=8,
+    )
+
+
 def _normal(key, shape, scale):
     return jax.random.normal(key, shape, jnp.float32) * scale
+
+
+def _moe_form(c: Config) -> Dict[str, bool]:
+    """What of the routed layer's optional parts this configuration has."""
+    return {"gated": c.experts_gated,
+            "score_bias": c.router_score == "sigmoid"}
+
+
+def _init_attention(c: Config, keys, lead=()) -> Params:
+    d = c.d_model
+    return {
+        "attn_norm": {"scale": jnp.ones((*lead, d), jnp.float32)},
+        "wq": {"w": _normal(keys[0], (*lead, d, c.q_dim), d**-0.5)},
+        "wk": {"w": _normal(keys[1], (*lead, d, c.kv_dim), d**-0.5)},
+        "wv": {"w": _normal(keys[2], (*lead, d, c.kv_dim), d**-0.5)},
+        "wo": {"w": _normal(keys[3], (*lead, c.q_dim, d), c.q_dim**-0.5)},
+    }
+
+
+def _init_routed(c: Config, key) -> Params:
+    return moe.init(
+        key, d_model=c.d_model, d_expert=c.d_expert, n_experts=c.n_experts,
+        n_held=c.experts_held, d_shared=c.d_shared, **_moe_form(c))
+
+
+def _init_mixer(c: Config, kind: str, key) -> Params:
+    """One single-mixer layer's tree: the mixer's weights and its norm."""
+    if kind == "mamba":
+        return mamba2.init(key, c)
+    if kind == "attention":
+        return _init_attention(c, jax.random.split(key, 4))
+    return {"mlp_norm": {"scale": jnp.ones((c.d_model,), jnp.float32)},
+            **_init_routed(c, key)}
+
+
+def _per_kind(c: Config) -> Dict[str, int]:
+    """How many layers of each kind the whole stack has."""
+    periods = c.n_layers // len(c.layer_kinds)
+    return {kind: c.layer_kinds.count(kind) * periods
+            for kind in sorted(set(c.layer_kinds))}
 
 
 def init(config: Config, key) -> Params:
@@ -217,58 +330,71 @@ def init(config: Config, key) -> Params:
     n, d = c.n_layers, c.d_model
     s_d = d**-0.5
     s_ff = c.d_ff**-0.5
-    s_q = c.q_dim**-0.5
-    if c.routed:
-        # every layer's router and held experts, stacked like the rest
-        feed_forward = jax.vmap(lambda k: moe.init(
-            k, d_model=d, d_expert=c.d_expert, n_experts=c.n_experts,
-            n_held=c.experts_held))(jax.random.split(lk[4], n))
+    if c.single_mixers:
+        # unlike trees: each kind's layers stacked on an axis of their own
+        layers = {
+            kind: jax.vmap(lambda k, kind=kind: _init_mixer(c, kind, k))(
+                jax.random.split(lk[i], count))
+            for i, (kind, count) in enumerate(_per_kind(c).items())}
     else:
-        feed_forward = {
-            "w_gate": {"w": _normal(lk[4], (n, d, c.d_ff), s_d)},
-            "w_up": {"w": _normal(lk[5], (n, d, c.d_ff), s_d)},
-            "w_down": {"w": _normal(lk[6], (n, c.d_ff, d), s_ff)},
+        if c.routed:
+            # every layer's router and held experts, stacked like the rest
+            feed_forward = jax.vmap(lambda k: _init_routed(c, k))(
+                jax.random.split(lk[4], n))
+        else:
+            feed_forward = {
+                "w_gate": {"w": _normal(lk[4], (n, d, c.d_ff), s_d)},
+                "w_up": {"w": _normal(lk[5], (n, d, c.d_ff), s_d)},
+                "w_down": {"w": _normal(lk[6], (n, c.d_ff, d), s_ff)},
+            }
+        # all layers stacked on axis 0 → lax.scan over the leading axis
+        layers = {
+            **_init_attention(c, lk, lead=(n,)),
+            "mlp_norm": {"scale": jnp.ones((n, d), jnp.float32)},
+            **feed_forward,
         }
     return {
         "embed": {"w": _normal(ke, (c.vocab, d), 1.0)},
-        # all layers stacked on axis 0 → lax.scan over the leading axis
-        "layers": {
-            "attn_norm": {"scale": jnp.ones((n, d), jnp.float32)},
-            "wq": {"w": _normal(lk[0], (n, d, c.q_dim), s_d)},
-            "wk": {"w": _normal(lk[1], (n, d, c.kv_dim), s_d)},
-            "wv": {"w": _normal(lk[2], (n, d, c.kv_dim), s_d)},
-            "wo": {"w": _normal(lk[3], (n, c.q_dim, d), s_q)},
-            "mlp_norm": {"scale": jnp.ones((n, d), jnp.float32)},
-            **feed_forward,
-        },
+        "layers": layers,
         "final_norm": {"scale": jnp.ones((d,), jnp.float32)},
         "lm_head": {"w": _normal(kh, (d, c.vocab), s_d)},
     }
 
 
+_ATTENTION_AXES = {
+    "attn_norm": {"scale": ("stats",)},
+    "wq": {"w": ("embed", "heads")},
+    "wk": {"w": ("embed", "kv_heads")},
+    "wv": {"w": ("embed", "kv_heads")},
+    "wo": {"w": ("heads", "embed")},
+}
+
+
 def logical_axes(config: Config) -> Params:
-    # leading "layers" stack axis is always replicated (None)
-    if config.routed:
-        feed_forward = jax.tree.map(
-            lambda axes: (None, *axes), moe.logical_axes(),
-            is_leaf=lambda x: isinstance(x, tuple))
+    c = config
+    routed_axes = lambda: {
+        "mlp_norm": {"scale": ("stats",)},
+        **moe.logical_axes(shared=c.d_shared > 0, **_moe_form(c))}
+    if c.single_mixers:
+        by_kind = {"mamba": mamba2.logical_axes,
+                   "attention": lambda: _ATTENTION_AXES,
+                   "experts": routed_axes}
+        layers = {kind: by_kind[kind]() for kind in _per_kind(c)}
+    elif c.routed:
+        layers = {**_ATTENTION_AXES, **routed_axes()}
     else:
-        feed_forward = {
-            "w_gate": {"w": (None, "embed", "mlp")},
-            "w_up": {"w": (None, "embed", "mlp")},
-            "w_down": {"w": (None, "mlp", "embed")},
+        layers = {
+            **_ATTENTION_AXES,
+            "mlp_norm": {"scale": ("stats",)},
+            "w_gate": {"w": ("embed", "mlp")},
+            "w_up": {"w": ("embed", "mlp")},
+            "w_down": {"w": ("mlp", "embed")},
         }
     return {
         "embed": {"w": ("vocab", "embed")},
-        "layers": {
-            "attn_norm": {"scale": (None, "stats")},
-            "wq": {"w": (None, "embed", "heads")},
-            "wk": {"w": (None, "embed", "kv_heads")},
-            "wv": {"w": (None, "embed", "kv_heads")},
-            "wo": {"w": (None, "heads", "embed")},
-            "mlp_norm": {"scale": (None, "stats")},
-            **feed_forward,
-        },
+        # the leading stack axis is always replicated (None)
+        "layers": jax.tree.map(lambda axes: (None, *axes), layers,
+                               is_leaf=lambda x: isinstance(x, tuple)),
         "final_norm": {"scale": ("stats",)},
         "lm_head": {"w": ("embed", "vocab")},
     }
@@ -404,6 +530,8 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
         # ring never carries expanded K/V
         window = c.window if kind == "window" else None
         yarn = c.yarn_full if kind == "full" else None
+        # the single mixer "attention" has no positional embedding
+        rotates = kind != "attention"
         # YaRN's factor on cos and sin of q and k alike is its square on
         # their product
         scale = c.head_dim**-0.5 * (yarn.attention_factor**2 if yarn else 1)
@@ -420,10 +548,10 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
             wq3 = lp["wq"]["w"].astype(dt).reshape(-1, c.n_heads, c.head_dim)
             wk3 = lp["wk"]["w"].astype(dt).reshape(-1, c.n_kv_heads, c.head_dim)
             wv3 = lp["wv"]["w"].astype(dt).reshape(-1, c.n_kv_heads, c.head_dim)
-            q = _rope_bhtd(
-                jnp.einsum("btd,dhx->bhtx", y, wq3), c.rope_theta, yarn)
-            k = _rope_bhtd(
-                jnp.einsum("btd,dhx->bhtx", y, wk3), c.rope_theta, yarn)
+            rope = (lambda a: _rope_bhtd(a, c.rope_theta, yarn)) if rotates \
+                else (lambda a: a)
+            q = rope(jnp.einsum("btd,dhx->bhtx", y, wq3))
+            k = rope(jnp.einsum("btd,dhx->bhtx", y, wk3))
             v = jnp.einsum("btd,dhx->bhtx", y, wv3)
             attn = flash_attention(
                 q, k, v, causal=True, scale=scale, mesh=mesh,
@@ -435,8 +563,9 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
             q = (y @ lp["wq"]["w"].astype(dt)).reshape(b, t, c.n_heads, c.head_dim)
             k = (y @ lp["wk"]["w"].astype(dt)).reshape(b, t, c.n_kv_heads, c.head_dim)
             v = (y @ lp["wv"]["w"].astype(dt)).reshape(b, t, c.n_kv_heads, c.head_dim)
-            q = _rope(q, c.rope_theta, yarn)
-            k = _rope(k, c.rope_theta, yarn)
+            if rotates:
+                q = _rope(q, c.rope_theta, yarn)
+                k = _rope(k, c.rope_theta, yarn)
             if seq_sharded:
                 # ring attention: the only exact option over a sharded sequence
                 attn = ring_attention(q, k, v, mesh, causal=True, scale=scale)
@@ -475,40 +604,86 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
         # rounding to the compute dtype: top-k is a discontinuous choice
         y32 = _rmsnorm32(h, lp["mlp_norm"]["scale"], c.norm_eps)
         out, counters = moe.apply(
-            {k: lp[k] for k in ("router", "w_gate", "w_up", "w_down")},
+            {k: v for k, v in lp.items() if not k.endswith("_norm")},
             y32.astype(h.dtype), router_in=y32,
             experts_per_token=c.experts_per_token,
-            first_expert=c.first_expert, compute_dtype=dt,
+            first_expert=c.first_expert, router_scale=c.router_scale,
+            compute_dtype=dt,
             matmul_precision=c.matmul_precision, mesh=mesh)
         return constrain_fwd(h + out, ["batch", "seq", "embed"]), counters
 
+    def remat(layer):
+        if not c.remat_layers:
+            return layer
+        # save the flash kernel's (o, lse) residuals across the remat
+        # boundary: recomputing them in the backward costs a full kernel
+        # pass (~4% of the llama step on v5e) for ~70MB/layer of HBM
+        return jax.checkpoint(
+            layer,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                "flash_o", "flash_lse"
+            ),
+        )
+
+    def attend(carry, lp, kind):
+        # the single mixer is causal over every key: "full" in the trace
+        name = "full" if kind == "attention" else kind
+        with jax.named_scope("attention"), \
+                jax.named_scope(f"attention_{name}"):
+            return attention(carry, lp, kind)
+
     def layer_of(kind):
+        """A pair: attention of ``kind``, then the feed-forward."""
         def layer(carry, lp):
-            with jax.named_scope("attention"), \
-                    jax.named_scope(f"attention_{kind}"):
-                h = attention(carry, lp, kind)
+            h = attend(carry, lp, kind)
             with jax.named_scope("mlp"):
                 if c.routed:
                     with jax.named_scope("moe"):
                         return routed(h, lp)
                 return mlp(h, lp), {}
 
-        if c.remat_layers:
-            # save the flash kernel's (o, lse) residuals across the remat
-            # boundary: recomputing them in the backward costs a full kernel
-            # pass (~4% of the llama step on v5e) for ~70MB/layer of HBM
-            layer = jax.checkpoint(
-                layer,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    "flash_o", "flash_lse"
-                ),
-            )
-        return layer
+        return remat(layer)
+
+    def mixer_of(kind):
+        """A single mixer: ``x + mixer(rmsnorm(x))`` and nothing else."""
+        def layer(carry, lp):
+            if kind == "attention":
+                return attend(carry, lp, kind), {}
+            if kind == "experts":
+                with jax.named_scope("mlp"), jax.named_scope("moe"):
+                    return routed(carry, lp)
+            with jax.named_scope("mamba"):
+                out, counters = mamba2.apply(
+                    c, lp, _rmsnorm(carry, lp["norm"]["scale"], c.norm_eps))
+                return constrain_fwd(
+                    carry + out, ["batch", "seq", "embed"]), counters
+
+        return remat(layer)
 
     # the scan walks periods; the kinds inside one are unrolled in its
     # body. A model of one kind is the plain scan over its layers.
     kinds = c.layer_kinds
-    if len(kinds) == 1:
+    if c.single_mixers:
+        # each kind's layers are a stack of their own: the body takes the
+        # next layer of its kind as it walks the pattern
+        layers = {kind: mixer_of(kind) for kind in set(kinds)}
+
+        def period(carry, pp):
+            taken, outs = dict.fromkeys(pp, 0), {}
+            for kind in kinds:
+                i, taken[kind] = taken[kind], taken[kind] + 1
+                carry, out = layers[kind](
+                    carry, jax.tree.map(lambda a: a[i], pp[kind]))
+                for name, value in out.items():
+                    outs.setdefault(name, []).append(value)
+            return carry, {n: jnp.stack(v) for n, v in outs.items()}
+
+        x, counters = lax.scan(period, x, {
+            kind: jax.tree.map(
+                lambda a: a.reshape(-1, kinds.count(kind), *a.shape[1:]),
+                params["layers"][kind])
+            for kind in set(kinds)})
+    elif len(kinds) == 1:
         x, counters = lax.scan(layer_of(kinds[0]), x, params["layers"])
     else:
         layers = {kind: layer_of(kind) for kind in set(kinds)}
@@ -536,9 +711,11 @@ def _step_counters(counters):
     """One scalar a counter for the step, from each layer's: how many
     assignments fell to held experts (mean a layer), the fullest held
     expert over the mean (worst layer), assignments without a row (sum),
-    rows the passes in row order visited (mean a layer)."""
+    rows the passes in row order visited (mean a layer), the share of the
+    scan's chunks that hand state on (mean a layer)."""
     fold = {moe.ASSIGNMENTS_HELD: jnp.mean, moe.LOAD_MAX_OVER_MEAN: jnp.max,
-            moe.ASSIGNMENTS_DROPPED: jnp.sum, moe.ROWS_WORKED: jnp.mean}
+            moe.ASSIGNMENTS_DROPPED: jnp.sum, moe.ROWS_WORKED: jnp.mean,
+            mamba2.CARRY_SHARE: jnp.mean}
     return {name: fold[name](values) for name, values in counters.items()}
 
 
@@ -552,9 +729,10 @@ def loss_fn(
     ce_chunk: int = 2048,
 ):
     """Next-token cross-entropy. batch = {"tokens": [B,T]}; position t
-    predicts token t+1; the final position is dropped. A routed model
-    returns ``(loss, counters)``: the router's named scalars of the step
-    (parallel/moe.py), which ``Trainer`` puts into the step's metrics.
+    predicts token t+1; the final position is dropped. A model with routed
+    or state-space layers returns ``(loss, counters)``: their named scalars
+    of the step (parallel/moe.py, models/mamba2.py), which ``Trainer`` puts
+    into the step's metrics.
 
     Above ``ce_chunk`` positions the loss is computed blockwise over the
     sequence (checkpointed lax.map): the [B,T,vocab] f32 logits plus their
@@ -615,20 +793,20 @@ def _chunked_nll(feats, head, tokens, ce_chunk):
 def param_count(config: Config) -> int:
     """The parameters held: of a routed model's experts, this share's."""
     c = config
-    if c.routed:
-        feed_forward = (c.d_model * c.n_experts
-                        + c.experts_held * 3 * c.d_model * c.d_expert)
+    d = c.d_model
+    attention = d * (c.q_dim + 2 * c.kv_dim) + c.q_dim * d + d
+    routed = (d * c.n_experts
+              + (c.n_experts if c.router_score == "sigmoid" else 0)
+              + c.experts_held * (3 if c.experts_gated else 2) * d * c.d_expert
+              + 2 * d * c.d_shared + d)
+    if c.single_mixers:
+        inner, conv, proj = mamba2.widths(c)
+        mamba = (d + d * proj + conv * (c.conv_kernel + 1) + 3 * c.ssm_heads
+                 + inner + inner * d)
+        per_kind = {"mamba": mamba, "attention": attention, "experts": routed}
+        layers = sum(per_kind[kind] * count
+                     for kind, count in _per_kind(c).items())
     else:
-        feed_forward = 3 * c.d_model * c.d_ff
-    per_layer = (
-        c.d_model * (c.q_dim + 2 * c.kv_dim)
-        + c.q_dim * c.d_model
-        + feed_forward
-        + 2 * c.d_model
-    )
-    return (
-        c.vocab * c.d_model
-        + c.n_layers * per_layer
-        + c.d_model
-        + c.d_model * c.vocab
-    )
+        feed_forward = routed if c.routed else 3 * d * c.d_ff + d
+        layers = c.n_layers * (attention + feed_forward)
+    return c.vocab * d + layers + d + d * c.vocab

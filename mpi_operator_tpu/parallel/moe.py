@@ -1,16 +1,26 @@
 """Routed (mixture-of-experts) feed-forward: a share of the experts, dropless.
 
 The layer is told which experts it holds: ``n_experts`` published ones that
-the router scores, of which the ``w_gate.shape[0]`` from ``first_expert`` on
-live here. It routes every token over all published experts (softmax in
-float32, the ``experts_per_token`` largest, their weights divided by their
-sum), and computes its own experts' part of the result:
+the router scores, of which the ``w_up.shape[0]`` from ``first_expert`` on
+live here. It routes every token over all published experts
+(:func:`route`, in float32, by one of two score functions: softmax, the
+``experts_per_token`` largest, their weights divided by their sum; or
+sigmoid, the largest of score plus a correction bias, weighed by the
+unbiased scores over their sum times a scale), and computes its own
+experts' part of the result:
 
-    y[t] = sum over the chosen experts e held here of
-           w[t, e] * W_down[e] . (silu(W_gate[e] . x[t]) * (W_up[e] . x[t]))
+    y[t] = sum over the chosen experts e held here of  w[t, e] * W_down[e] . h
+    h = silu(W_gate[e] . x[t]) * (W_up[e] . x[t])      gated: three products
+    h = relu(W_up[e] . x[t]) ** 2          no ``w_gate`` in the tree: two
+
+A *shared expert* (``shared_up`` / ``shared_down`` in the tree) is a dense
+``relu ** 2`` feed-forward of every token, added to the routed result once:
+what every chip computes alike, so over an ``expert`` mesh axis it is added
+after the members' sum and not once a member.
 
 What the absent experts would add is left out; the partial results of all
-shares add up to the whole layer (tests/test_moe.py). No assignment to a
+shares (the shared expert counted once) add up to the whole layer
+(tests/test_moe.py). No assignment to a
 held expert is ever dropped: shapes are static with room for every
 assignment (tokens x experts_per_token rows), whatever the imbalance.
 
@@ -30,7 +40,8 @@ and the held ones are its first H = sum(counts) rows. What runs over it *in
 row order* outside the grouped products stops at the tile that holds row
 H - 1: ``silu(gate) * up`` (``moe_silu_up``), its transpose
 (``moe_silu_up_t``), the sum of the two products' cotangents on the gathered
-rows (``moe_add``, in place of autodiff's ``add_any``) and the combine's
+rows (``moe_add``, in place of autodiff's ``add_any``; ungated experts read
+their rows once and have ``moe_relu2`` and ``moe_relu2_t``) and the combine's
 transpose, ``d_ys = d_rows * w`` with ``d_w = sum(d_rows * ys)`` beside it
 (``moe_combine_t``, which gathers ``d_rows``, the tokens' cotangents in row
 order, itself and so for the visited rows only): each a ``kernels.row_map``
@@ -48,7 +59,7 @@ that axis; tokens stay where their batch axes put them. On one chip the
 layer runs without that exchange, and nothing stands in for absent chips.
 
 Scopes in the device trace: ``moe_router``, ``moe_dispatch``,
-``moe_experts``, ``moe_combine``. Counters, as scalars of the step (no
+``moe_experts``, ``moe_combine``, ``moe_shared``. Counters, as scalars of the step (no
 sync): ``moe.assignments_held``, ``moe.load_max_over_mean``,
 ``moe.assignments_dropped`` (0, computed and not assumed),
 ``moe.rows_worked`` (the rows the row-order passes visit:
@@ -80,41 +91,76 @@ ROWS_WORKED = "moe.rows_worked"
 
 
 def init(key, *, d_model: int, d_expert: int, n_experts: int,
-         n_held: int) -> Params:
-    """One layer's weights: the router over all published experts, the
-    three matrices of the ``n_held`` experts held here. Projections std
-    fan_in**-0.5, as the dense feed-forward's."""
+         n_held: int, gated: bool = True, d_shared: int = 0,
+         score_bias: bool = False) -> Params:
+    """One layer's weights: the router over all published experts (with
+    ``score_bias`` its correction bias, nought), the matrices of the
+    ``n_held`` experts held here (three, or without ``gated`` two), and with
+    ``d_shared`` the shared expert's two. Projections std fan_in**-0.5, as
+    the dense feed-forward's."""
     kr, kg, ku, kd = jax.random.split(key, 4)
+    ksu, ksd = jax.random.split(jax.random.fold_in(key, 4))
     normal = lambda k, shape, fan_in: (
         jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5)
-    return {
+    p = {
         "router": {"w": normal(kr, (d_model, n_experts), d_model)},
-        "w_gate": {"w": normal(kg, (n_held, d_model, d_expert), d_model)},
         "w_up": {"w": normal(ku, (n_held, d_model, d_expert), d_model)},
         "w_down": {"w": normal(kd, (n_held, d_expert, d_model), d_expert)},
     }
+    if gated:
+        p["w_gate"] = {"w": normal(kg, (n_held, d_model, d_expert), d_model)}
+    if score_bias:
+        p["router"]["bias"] = jnp.zeros((n_experts,), jnp.float32)
+    if d_shared:
+        p["shared_up"] = {"w": normal(ksu, (d_model, d_shared), d_model)}
+        p["shared_down"] = {"w": normal(ksd, (d_shared, d_model), d_shared)}
+    return p
 
 
-def logical_axes() -> Params:
-    return {
+def logical_axes(gated: bool = True, shared: bool = False,
+                 score_bias: bool = False) -> Params:
+    axes = {
         "router": {"w": ("embed", None)},
-        "w_gate": {"w": ("expert", "embed", "mlp")},
         "w_up": {"w": ("expert", "embed", "mlp")},
         "w_down": {"w": ("expert", "mlp", "embed")},
     }
+    if gated:
+        axes["w_gate"] = {"w": ("expert", "embed", "mlp")}
+    if score_bias:
+        axes["router"]["bias"] = (None,)
+    if shared:
+        axes["shared_up"] = {"w": ("embed", "mlp")}
+        axes["shared_down"] = {"w": ("mlp", "embed")}
+    return axes
 
 
-def route(x32, router_w, experts_per_token: int):
-    """(weights [N, k] float32 summing to 1, experts [N, k] int32): softmax
-    over every published expert in float32, the k largest, renormalised.
+def route(x32, router_w, experts_per_token: int, *, bias=None,
+          scale: float = 1.0):
+    """(weights [N, k] float32, experts [N, k] int32) over every published
+    expert, in float32, by one of two score functions.
+
+    Softmax (no ``bias``): the k largest of the softmax, divided by their
+    sum (they sum to 1). Sigmoid (``bias`` [E], a correction that is no
+    parameter: it enters the choice alone, so nothing flows back to it):
+    the k largest of ``sigmoid(logits) + bias``, weighed by their unbiased
+    scores over those scores' sum, times ``scale`` (they sum to ``scale``).
+
     The product is made at ``highest`` precision: a default float32 product
     on a TPU rounds its inputs to bf16, and the k-th and (k+1)-th scores of
     some token always lie within that rounding."""
     logits = jnp.matmul(x32.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    top_w, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1),
-                             experts_per_token)
-    return top_w / jnp.sum(top_w, axis=-1, keepdims=True), top_e
+    if bias is None:
+        top_w, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                 experts_per_token)
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        return (top_w if scale == 1.0 else top_w * scale), top_e
+    scores = jax.nn.sigmoid(logits)
+    _, top_e = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
+                         experts_per_token)
+    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    return (top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20) * scale,
+            top_e)
 
 
 # -- the two gathers, each with a gather for its transpose --------------------
@@ -242,6 +288,37 @@ def _activate_bwd(interpret, res, d_h):
 _activate.defvjp(_activate_fwd, _activate_bwd)
 
 
+def _relu2(up):
+    return (jnp.square(jax.nn.relu(up)),)
+
+
+def _relu2_t(d_h, up):
+    return (d_h * 2.0 * jax.nn.relu(up),)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _activate_ungated(up, rows, interpret):
+    """h = relu(up) ** 2 on the held rows."""
+    h, = row_map.row_map(_relu2, (up,), ((up.shape[1], up.dtype),), rows,
+                         name="moe_relu2", interpret=interpret)
+    return h
+
+
+def _activate_ungated_fwd(up, rows, interpret):
+    return _activate_ungated(up, rows, interpret), (up, rows)
+
+
+def _activate_ungated_bwd(interpret, res, d_h):
+    up, rows = res
+    d_up, = row_map.row_map(
+        _relu2_t, (d_h, up), ((up.shape[1], up.dtype),), rows,
+        name="moe_relu2_t", interpret=interpret)
+    return d_up, None
+
+
+_activate_ungated.defvjp(_activate_ungated_fwd, _activate_ungated_bwd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _twice(xs, rows, interpret):
     """``xs`` for each of its two readers (the gate's product and the up's),
@@ -285,21 +362,25 @@ def _row_passes(r: int, d: int, f: int, held_here: int,
     return False if row_map.mappable(r, d, f) else None
 
 
-def _share(x, router_in, router_w, w_gate, w_up, w_down, first_expert, *,
-           experts_per_token: int, compute_dtype, matmul_precision: str):
+def _share(x, router_in, w, first_expert, *, experts_per_token: int,
+           compute_dtype, matmul_precision: str, router_scale: float):
     """One member's part: x [N, D] -> (y [N, D], this share's counts [G],
     assignments it could not give a row, rows its row-order passes visit).
-    ``router_in`` is what the router reads (float32), ``first_expert`` may
-    be traced (a mesh member's)."""
+    ``w`` holds the router's and the held experts' arrays by name (``bias``
+    and ``w_gate`` where the layer has them), ``router_in`` is what the
+    router reads (float32), ``first_expert`` may be traced (a mesh
+    member's)."""
     n, d = x.shape
-    held_here = w_gate.shape[0]
+    w_gate, w_up, w_down = w.get("w_gate"), w["w_up"], w["w_down"]
+    held_here = w_up.shape[0]
     k = experts_per_token
     dt = compute_dtype
-    interpret = _row_passes(n * k, d, w_gate.shape[2], held_here,
-                            router_w.shape[1])
+    interpret = _row_passes(n * k, d, w_up.shape[2], held_here,
+                            w["router"].shape[1])
 
     with jax.named_scope("moe_router"):
-        weights, experts = route(router_in, router_w, k)
+        weights, experts = route(router_in, w["router"], k,
+                                 bias=w.get("bias"), scale=router_scale)
         local = experts - first_expert
         held = jnp.logical_and(local >= 0, local < held_here)  # [N, k]
         # absent experts' assignments share one key past the held groups
@@ -324,11 +405,16 @@ def _share(x, router_in, router_w, w_gate, w_up, w_down, first_expert, *,
             jnp.minimum(counts, jnp.maximum(xs.shape[0] - starts, 0)))
 
     with jax.named_scope("moe_experts"):
-        xs_gate, xs_up = _twice(xs, rows, interpret)
-        gate = _grouped(xs_gate, w_gate.astype(dt), counts, matmul_precision)
-        up = _grouped(xs_up, w_up.astype(dt), counts, matmul_precision)
-        ys = _grouped(_activate(gate, up, rows, interpret), w_down.astype(dt),
-                      counts, matmul_precision)
+        if w_gate is None:
+            up = _grouped(xs, w_up.astype(dt), counts, matmul_precision)
+            h = _activate_ungated(up, rows, interpret)
+        else:
+            xs_gate, xs_up = _twice(xs, rows, interpret)
+            gate = _grouped(xs_gate, w_gate.astype(dt), counts,
+                            matmul_precision)
+            up = _grouped(xs_up, w_up.astype(dt), counts, matmul_precision)
+            h = _activate(gate, up, rows, interpret)
+        ys = _grouped(h, w_down.astype(dt), counts, matmul_precision)
 
     with jax.named_scope("moe_combine"):
         y = _combine(ys, weights, row_token, row_slot, pos, held, rows,
@@ -357,53 +443,71 @@ def _mesh_axes(mesh) -> Tuple[Tuple[str, ...], bool]:
     return batch, size(AXIS_EXPERT) > 1
 
 
+def _shared(params: Params, x, dt):
+    """The shared expert: ``relu(x . up) ** 2 . down`` of every token."""
+    with jax.named_scope("moe_shared"):
+        up = x.astype(dt) @ params["shared_up"]["w"].astype(dt)
+        h = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(dt)
+        return h @ params["shared_down"]["w"].astype(dt)
+
+
 def apply(params: Params, x, *, experts_per_token: int, first_expert: int = 0,
-          router_in=None, compute_dtype=jnp.bfloat16,
-          matmul_precision: str = "bf16", mesh: Mesh = None):
+          router_in=None, router_scale: float = 1.0,
+          compute_dtype=jnp.bfloat16, matmul_precision: str = "bf16",
+          mesh: Mesh = None):
     """x [B, T, D] -> (y [B, T, D], counters). ``params`` as :func:`init`
     gives them: the router over all published experts and the matrices of
-    the experts held, ``first_expert`` on. ``router_in`` [B, T, D] is what
-    the router scores where that differs from ``x`` (a float32 copy of a
-    bf16 activation); ``matmul_precision`` other than ``bf16`` sends the
-    three expert products through ``kernels.quant_matmul``."""
+    the experts held, ``first_expert`` on. What the tree holds says what the
+    layer is: a router ``bias`` the sigmoid score (else softmax), no
+    ``w_gate`` ungated ``relu ** 2`` experts, ``shared_up`` a shared expert.
+    ``router_in`` [B, T, D] is what the router scores where that differs
+    from ``x`` (a float32 copy of a bf16 activation); ``router_scale``
+    multiplies the weights; ``matmul_precision`` other than ``bf16`` sends
+    the routed experts' products through ``kernels.quant_matmul``."""
     b, t, d = x.shape
     router_in = x if router_in is None else router_in
-    w = (params["router"]["w"], params["w_gate"]["w"], params["w_up"]["w"],
-         params["w_down"]["w"])
+    w = {"router": params["router"]["w"], **{
+        name: params[name]["w"] for name in ("w_gate", "w_up", "w_down")
+        if name in params}}
+    if "bias" in params["router"]:
+        w["bias"] = params["router"]["bias"]
     share = functools.partial(
         _share, experts_per_token=experts_per_token,
-        compute_dtype=compute_dtype, matmul_precision=matmul_precision)
+        compute_dtype=compute_dtype, matmul_precision=matmul_precision,
+        router_scale=router_scale)
     batch_axes, experts_spread = _mesh_axes(mesh)
 
     if not batch_axes and not experts_spread:
         y, *counted = share(
-            x.reshape(b * t, d), router_in.reshape(b * t, d), *w,
-            first_expert)
-        return y.reshape(b, t, d), _counters(*counted)
+            x.reshape(b * t, d), router_in.reshape(b * t, d), w, first_expert)
+        y = y.reshape(b, t, d)
+    else:
+        def member(x_, r_, w_):
+            held_here = w_["w_up"].shape[0]
+            first = first_expert
+            if experts_spread:
+                first = first + lax.axis_index(AXIS_EXPERT) * held_here
+            y, counts, dropped, worked = share(
+                x_.reshape(-1, d), r_.reshape(-1, d), w_, first)
+            worked = jnp.asarray(worked, jnp.int32)
+            if batch_axes:  # every token's assignments, wherever its rows are
+                counts = lax.psum(counts, batch_axes)
+                dropped, worked = lax.psum((dropped, worked), batch_axes)
+            if experts_spread:  # the shares' partial results add up
+                y = lax.psum(y, AXIS_EXPERT)
+                counts = lax.all_gather(counts, AXIS_EXPERT, tiled=True)
+                dropped, worked = lax.psum((dropped, worked), AXIS_EXPERT)
+            return y.reshape(x_.shape), counts, dropped, worked
 
-    def member(x_, r_, router_w, w_gate, w_up, w_down):
-        held_here = w_gate.shape[0]
-        first = first_expert
-        if experts_spread:
-            first = first + lax.axis_index(AXIS_EXPERT) * held_here
-        y, counts, dropped, worked = share(
-            x_.reshape(-1, d), r_.reshape(-1, d), router_w, w_gate, w_up,
-            w_down, first)
-        worked = jnp.asarray(worked, jnp.int32)
-        if batch_axes:  # every token's assignments, wherever its rows are
-            counts = lax.psum(counts, batch_axes)
-            dropped, worked = lax.psum((dropped, worked), batch_axes)
-        if experts_spread:  # the shares' partial results add up
-            y = lax.psum(y, AXIS_EXPERT)
-            counts = lax.all_gather(counts, AXIS_EXPERT, tiled=True)
-            dropped, worked = lax.psum((dropped, worked), AXIS_EXPERT)
-        return y.reshape(x_.shape), counts, dropped, worked
-
-    tokens = P(batch_axes or None, None, None)
-    held = P(AXIS_EXPERT if experts_spread else None, None, None)
-    y, *counted = jax.shard_map(
-        member, mesh=mesh,
-        in_specs=(tokens, tokens, P(), held, held, held),
-        out_specs=(tokens, P(), P(), P()), check_vma=False,
-    )(x, router_in, *w)
+        tokens = P(batch_axes or None, None, None)
+        held = P(AXIS_EXPERT if experts_spread else None, None, None)
+        y, *counted = jax.shard_map(
+            member, mesh=mesh,
+            in_specs=(tokens, tokens,
+                      {name: held if name.startswith("w_") else P()
+                       for name in w}),
+            out_specs=(tokens, P(), P(), P()), check_vma=False,
+        )(x, router_in, w)
+    if "shared_up" in params:  # every chip's alike: once, after the sum
+        y = y + _shared(params, x, compute_dtype)
     return y, _counters(*counted)

@@ -78,6 +78,19 @@ def test_each_product_is_ragged_dots(product, layout, k, n):
         _near(d_w, want_d_w, "d_w")
 
 
+@pytest.mark.parametrize("k,n", [(384, 208), (208, 384)])
+@pytest.mark.parametrize("product", ["forward", "d_xs", "d_w"])
+def test_a_width_that_is_no_multiple_of_128_is_one_whole_block(product, k, n):
+    """Experts 1856 = 14.5 x 128 wide, in small: 208 = 1.625 x 128 as what
+    is contracted and as what comes out, a boundary inside a tile and rows
+    past the last group, against ``lax.ragged_dot``."""
+    assert gm._lane_tiles(208) == [208] and gm.tileable(R, k, n)
+    for footprint in (gm._gmm_bytes, gm._dw_bytes):
+        assert gm._tiles(R, k, n, 2, footprint) == (512, k, n)
+    for layout in ("a_boundary_inside_a_tile", "rows_past_the_last_group"):
+        test_each_product_is_ragged_dots(product, layout, k, n)
+
+
 @pytest.mark.parametrize("product", ["forward", "d_xs", "d_w"])
 def test_widths_the_budget_splits_are_accumulated_in_float32(
         monkeypatch, product):
@@ -102,6 +115,13 @@ def test_tiles_come_from_the_shapes():
             assert footprint(tm, tk, tn, 2) <= gm._VMEM_BUDGET
     assert gm.tileable(4096, 256, 128)
     assert not gm.tileable(96, 32, 48) and not gm.tileable(100, 128, 128)
+    # experts 1856 wide: the whole width or none of it, so the transpose
+    # that would hold 2688 x 1856 twice over splits the other width
+    assert gm.tileable(98304, 2688, 1856) and gm.tileable(98304, 1856, 2688)
+    assert not gm.tileable(98304, 2688, 1860)  # half a packed sublane over
+    assert gm._tiles(98304, 2688, 1856, 2, gm._gmm_bytes) == (512, 2688, 1856)
+    assert gm._tiles(98304, 2688, 1856, 2, gm._dw_bytes) == (512, 896, 1856)
+    assert gm._tiles(98304, 1856, 2688, 2, gm._dw_bytes) == (512, 1856, 896)
 
 
 def _dots(jaxpr):
@@ -195,6 +215,42 @@ def test_the_cells_products_compile_for_a_v5e_at_whole_width(one_chip, k, n):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     for name in (r"moe_gmm_*\.\d", r"moe_gmm_dx_*\.\d", r"moe_gmm_dw_*\.\d"):
         assert re.search(name, text), name
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)])
+def test_products_1856_wide_compile_for_a_v5e(one_chip, k, n):
+    """``nemotron3nano.steady-8k``'s 98,304 rows in 8 groups, experts 1856
+    = 14.5 x 128 wide: Mosaic takes the whole-width block in all three
+    kernels (it would refuse here what it refuses on the chip)."""
+    rows, groups = 98304, 8
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def all_three(xs, w, ct, sizes):
+        y, vjp = jax.vjp(lambda a, b: gm._grouped(a, b, sizes, False), xs, w)
+        return y, vjp(ct)
+
+    text = jax.jit(all_three).lower(
+        spec((rows, k)), spec((groups, k, n)), spec((rows, n)),
+        spec((groups,), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("name", ["moe_relu2", "moe_relu2_t"])
+def test_the_ungated_row_passes_compile_for_a_v5e_at_1856(one_chip, name):
+    rows, width = 98304, 1856
+    assert row_map.row_tile(rows) == 512 and row_map.mappable(rows, width)
+    assert not row_map.mappable(rows, 100)
+    wide = jax.ShapeDtypeStruct((rows, width), jnp.bfloat16,
+                                sharding=one_chip)
+    held = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    body, operands = {"moe_relu2": (moe._relu2, (wide,)),
+                      "moe_relu2_t": (moe._relu2_t, (wide, wide))}[name]
+    text = jax.jit(lambda rows, *operands: row_map.row_map(
+        body, operands, ((width, jnp.bfloat16),), rows, name=name,
+        interpret=False)).lower(held, *operands).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(name + r"_*\.\d", text), name
 
 
 @pytest.mark.parametrize("name,width", [

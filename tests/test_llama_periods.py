@@ -292,3 +292,102 @@ def test_a_closing_recorder_waits_for_the_last_step():
     assert rec.snapshot()["counters"] == {moe.ASSIGNMENTS_HELD: 7.0}
     rec.close()
     assert rec.snapshot()["counters"] == {moe.ASSIGNMENTS_HELD: 11.0}
+
+
+# -- layers that are one mixer each ------------------------------------------
+
+@pytest.fixture(scope="module")
+def hybrid():
+    cfg = dataclasses.replace(llama.tiny_hybrid(),
+                              compute_dtype=jnp.float32)
+    return cfg, llama.init(cfg, jax.random.PRNGKey(0))
+
+
+def test_single_mixers_stack_by_kind(hybrid):
+    cfg, params = hybrid
+    layers = params["layers"]
+    assert set(layers) == {"mamba", "experts", "attention"}
+    lead = {kind: jax.tree.leaves(tree)[0].shape[0]
+            for kind, tree in layers.items()}
+    assert lead == {"mamba": 2, "experts": 2, "attention": 1}
+    assert "w_gate" not in layers["experts"]
+    assert {"shared_up", "shared_down"} <= set(layers["experts"])
+    assert layers["experts"]["router"]["bias"].shape == (2, 8)
+    assert llama.param_count(cfg) == sum(
+        a.size for a in jax.tree.leaves(params))
+    axes = llama.logical_axes(cfg)
+    jax.tree.map(lambda a, ax: None if a.ndim == len(ax) else 1 / 0,
+                 params, axes, is_leaf=lambda x: isinstance(x, tuple))
+
+
+def test_two_periods_of_single_mixers_are_the_layers_in_pattern_order(hybrid):
+    """Ten layers as two periods of five: the scan's second step takes each
+    kind's later layers. Against the same layers walked one by one."""
+    cfg, _ = hybrid
+    cfg = dataclasses.replace(cfg, n_layers=10)
+    params = llama.init(cfg, jax.random.PRNGKey(2))
+    want = llama.apply(cfg, params, TOKENS)
+    # the same model as one period of ten: kind i-th layers in order
+    flat = dataclasses.replace(cfg, layer_kinds=cfg.layer_kinds * 2)
+    np.testing.assert_allclose(llama.apply(flat, params, TOKENS), want,
+                               atol=1e-5, rtol=1e-5)
+    # and the order matters: the first period's layers swapped for the
+    # second's give another model
+    swapped = jax.tree.map(lambda a: a[::-1], params["layers"])
+    other = llama.apply(cfg, dict(params, layers=swapped), TOKENS)
+    assert float(jnp.max(jnp.abs(other - want))) > 1e-3
+
+
+def test_the_single_attention_layer_has_no_positions():
+    """Causal attention that rotates nothing: the last position's result
+    does not change when the tokens before it change places (one layer:
+    a second would read the first's results, which follow their prefixes)."""
+    cfg = dataclasses.replace(
+        llama.tiny(), layer_kinds=("attention",), n_layers=1,
+        compute_dtype=jnp.float32)
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    assert set(params["layers"]) == {"attention"}
+    moved = TOKENS.at[:, :-1].set(TOKENS[:, :-1][:, ::-1])
+    a = llama.apply(cfg, params, TOKENS)[:, -1]
+    b = llama.apply(cfg, params, moved)[:, -1]
+    np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+    # a pair's attention rotates: there the order shows
+    pair = llama.tiny()
+    pair = dataclasses.replace(pair, compute_dtype=jnp.float32)
+    pp = llama.init(pair, jax.random.PRNGKey(0))
+    assert float(jnp.max(jnp.abs(
+        llama.apply(pair, pp, TOKENS)[:, -1]
+        - llama.apply(pair, pp, moved)[:, -1]))) > 1e-3
+
+
+def test_the_hybrid_steps_scalars_reach_its_metrics(hybrid):
+    cfg, params = hybrid
+    mesh = build_mesh(MeshPlan.data_parallel(1), jax.devices()[:1])
+    trainer = _routed_trainer(cfg, mesh)
+    _, metrics = trainer.train_step(
+        trainer.init_state(params), {"tokens": TOKENS})
+    assert {"loss", "grad_norm", moe.ASSIGNMENTS_HELD,
+            moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED,
+            moe.ROWS_WORKED, "ssm.carry_share"} == set(metrics)
+    assert 0.0 <= float(metrics["ssm.carry_share"]) <= 1.0
+    assert "ssm.carry_share" in stepstats.TRAIN_COUNTERS
+
+
+@pytest.mark.parametrize("change", [
+    dict(layer_kinds=("mamba", "full")),  # a pair among single mixers
+    dict(layer_kinds=("mamba", "linear")),  # a kind it does not know
+    dict(experts_gated=True),  # a shared expert beside gated experts
+    dict(router_score="tanh"),
+    dict(n_experts=0, n_experts_held=0),  # an 'experts' layer, no experts
+    dict(ssm_heads=0), dict(ssm_groups=3), dict(ssm_chunk=0),
+    dict(n_layers=7),  # no whole number of periods
+])
+def test_config_refuses_what_the_decoder_does_not_compute(change):
+    with pytest.raises(ValueError):
+        dataclasses.replace(llama.tiny_hybrid(), **change)
+
+
+def test_a_sequence_that_is_no_whole_number_of_chunks_is_refused(hybrid):
+    cfg, params = hybrid
+    with pytest.raises(ValueError, match="whole number of chunks"):
+        llama.apply(cfg, params, TOKENS[:, :20])

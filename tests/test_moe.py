@@ -310,3 +310,173 @@ def test_the_router_is_float32_whatever_the_activations(whole):
                      p["router"]["w"], K)
     assert w.dtype == jnp.float32 and e.shape == (48, K)
     np.testing.assert_allclose(jnp.sum(w, -1), 1.0, atol=1e-6)
+
+
+# -- the other form: sigmoid scores, ungated experts, a shared expert --------
+
+E2, K2, SCALE = 128, 6, 2.5
+
+
+@pytest.fixture(scope="module")
+def whole_sigmoid():
+    """All 128 published experts held, ungated, with a shared expert; the
+    correction bias drawn at the spacing of the top scores."""
+    p = moe.init(jax.random.PRNGKey(4), d_model=D, d_expert=F, n_experts=E2,
+                 n_held=E2, gated=False, d_shared=40, score_bias=True)
+    p["router"]["bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(5), (E2,))
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 24, D), jnp.float32)
+    return p, x
+
+
+def _share2(p, first, count, shared=True):
+    out = {"router": p["router"], **{
+        n: {"w": p[n]["w"][first:first + count]} for n in ("w_up", "w_down")}}
+    if shared:
+        out.update(shared_up=p["shared_up"], shared_down=p["shared_down"])
+    return out
+
+
+def _plain_route(xf, p):
+    """The published rule, written out: sigmoid scores, the choice by score
+    plus bias, the weights from the unbiased scores."""
+    s = jax.nn.sigmoid(jnp.matmul(xf, p["router"]["w"], precision="highest"))
+    chosen = jnp.argsort(-(s + p["router"]["bias"]), axis=-1)[:, :K2]
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    return w / (jnp.sum(w, -1, keepdims=True) + 1e-20) * SCALE, chosen
+
+
+def _loop2(p, x, first=0, shared=True):
+    xf = x.reshape(-1, D)
+    w, e = _plain_route(xf, p)
+    relu2 = lambda a: jnp.square(jax.nn.relu(a))
+    out = jnp.zeros_like(xf)
+    for i in range(p["w_up"]["w"].shape[0]):
+        y = relu2(xf @ p["w_up"]["w"][i]) @ p["w_down"]["w"][i]
+        out = out + y * jnp.sum(
+            jnp.where(e == first + i, w, 0.0), -1, keepdims=True)
+    if shared:
+        out = out + relu2(xf @ p["shared_up"]["w"]) @ p["shared_down"]["w"]
+    return out.reshape(x.shape)
+
+
+def test_the_sigmoid_route_is_the_published_rule(whole_sigmoid):
+    p, x = whole_sigmoid
+    xf = x.reshape(-1, D)
+    w, e = moe.route(xf, p["router"]["w"], K2, bias=p["router"]["bias"],
+                     scale=SCALE)
+    want_w, want_e = _plain_route(xf, p)
+    np.testing.assert_array_equal(e, want_e)
+    np.testing.assert_allclose(w, want_w, rtol=1e-6)
+    np.testing.assert_allclose(jnp.sum(w, -1), SCALE, rtol=1e-6)
+    # the bias changes the choice and not the weight: without it other
+    # experts are chosen for many tokens, and an expert chosen either way
+    # has the weight its unbiased score gives among its companions
+    _, unbiased = moe.route(xf, p["router"]["w"], K2,
+                            bias=jnp.zeros((E2,)), scale=SCALE)
+    changed = jnp.any(jnp.sort(e, -1) != jnp.sort(unbiased, -1), axis=-1)
+    assert 0.2 < float(jnp.mean(changed)) < 1.0
+    s = jax.nn.sigmoid(jnp.matmul(xf, p["router"]["w"], precision="highest"))
+    picked = jnp.take_along_axis(s, e, axis=-1)
+    np.testing.assert_allclose(
+        w, picked / jnp.sum(picked, -1, keepdims=True) * SCALE, rtol=1e-6)
+    # a buffer: nothing flows back to it
+    grad = jax.grad(lambda b: jnp.sum(moe.route(
+        xf, p["router"]["w"], K2, bias=b, scale=SCALE)[0] ** 2))(
+            p["router"]["bias"])
+    assert not np.any(np.asarray(grad))
+
+
+def test_the_ungated_layer_with_its_shared_expert_is_the_plain_loop(
+        whole_sigmoid):
+    p, x = whole_sigmoid
+    apply = lambda p, x: moe.apply(p, x, experts_per_token=K2,
+                                   router_scale=SCALE, **F32)[0]
+    np.testing.assert_allclose(apply(p, x), _loop2(p, x), atol=2e-5,
+                               rtol=2e-5)
+    cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    got = jax.grad(lambda p, x: jnp.sum(apply(p, x) * cot), (0, 1))(p, x)
+    want = jax.grad(lambda p, x: jnp.sum(_loop2(p, x) * cot), (0, 1))(p, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=2e-4)
+    assert "w_gate" not in p and not np.any(np.asarray(got[0]["router"]["bias"]))
+
+
+def test_sixteen_shares_and_the_shared_expert_once_add_up(whole_sigmoid):
+    """The guide's test of the cut: sixteen shares of 8 experts, each
+    computed by the layer as a one-chip share, with what every chip
+    computes alike, the shared expert, counted once, sum to the uncut
+    layer; so do their counts."""
+    p, x = whole_sigmoid
+    each = E2 // 16
+    total, held = 0.0, 0.0
+    for first in range(0, E2, each):
+        y, counters = moe.apply(
+            _share2(p, first, each, shared=False), x, first_expert=first,
+            experts_per_token=K2, router_scale=SCALE, **F32)
+        np.testing.assert_allclose(
+            y, _loop2(_share2(p, first, each), x, first, shared=False),
+            atol=1e-5, rtol=1e-5)
+        total, held = total + y, held + float(counters[moe.ASSIGNMENTS_HELD])
+    xf = x.reshape(-1, D)
+    relu2 = lambda a: jnp.square(jax.nn.relu(a))
+    total = total + (relu2(xf @ p["shared_up"]["w"])
+                     @ p["shared_down"]["w"]).reshape(x.shape)
+    whole, _ = moe.apply(p, x, experts_per_token=K2, router_scale=SCALE, **F32)
+    np.testing.assert_allclose(total, whole, atol=3e-5, rtol=3e-5)
+    np.testing.assert_allclose(total, _loop2(p, x), atol=3e-5, rtol=3e-5)
+    assert held == 2 * 24 * K2
+    # one share with the shared expert is that share plus the expert
+    one, _ = moe.apply(_share2(p, 8, each), x, first_expert=8,
+                       experts_per_token=K2, router_scale=SCALE, **F32)
+    np.testing.assert_allclose(one, _loop2(_share2(p, 8, each), x, 8),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("axes", [{AXIS_EXPERT: 4}, {AXIS_DATA: 2,
+                                                     AXIS_EXPERT: 2}])
+def test_over_a_mesh_the_shared_expert_is_added_once(whole_sigmoid, axes):
+    p, x = whole_sigmoid
+    n = int(np.prod(list(axes.values())))
+    mesh = build_mesh(MeshPlan(axes=axes), jax.devices()[:n])
+    y, _ = jax.jit(lambda p, x: moe.apply(
+        p, x, experts_per_token=K2, router_scale=SCALE, mesh=mesh, **F32))(p, x)
+    np.testing.assert_allclose(y, _loop2(p, x), atol=3e-5, rtol=3e-5)
+
+
+@pytest.fixture(scope="module")
+def bounded_ungated():
+    """The ungated layer over 1152 rows of which about half are held, its
+    experts 144 wide (no multiple of 128: a whole-width block), by the
+    CPU's path and on the bounded one, every kernel interpreted and every
+    row past the held ones NaN around every pass."""
+    p = moe.init(jax.random.PRNGKey(7), d_model=256, d_expert=144,
+                 n_experts=8, n_held=8, gated=False, d_shared=128,
+                 score_bias=True)
+    p = {"router": p["router"], "shared_up": p["shared_up"],
+         "shared_down": p["shared_down"],
+         **{n: {"w": p[n]["w"][2:6]} for n in ("w_up", "w_down")}}
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 288, 256), jnp.float32)
+    cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def layer_and_gradients():
+        apply = lambda p, x: moe.apply(
+            p, x, first_expert=2, experts_per_token=K, router_scale=SCALE,
+            **F32)[0]
+        y, vjp = jax.vjp(apply, p, x)
+        d_p, d_x = vjp(cot)
+        return {"y": y, "x": d_x, "router": d_p["router"]["w"],
+                **{n: d_p[n]["w"] for n in ("w_up", "w_down", "shared_up")}}
+
+    want = layer_and_gradients()
+    with poisoned_rows.patched(interpret=True):
+        return layer_and_gradients(), want
+
+
+@pytest.mark.parametrize(
+    "leaf", ["y", "x", "router", "w_up", "w_down", "shared_up"])
+def test_bounded_and_poisoned_the_ungated_layer_is_the_same(
+        bounded_ungated, leaf):
+    got, want = bounded_ungated
+    assert float(jnp.max(jnp.abs(want[leaf]))) > 0
+    assert bool(jnp.all(jnp.isfinite(got[leaf])))
+    np.testing.assert_allclose(got[leaf], want[leaf], atol=5e-5, rtol=2e-4)

@@ -8,9 +8,11 @@ the routed feed-forward's grouped products (whole-width tiles over the
 row tiles that hold real rows) and its elementwise passes over the same
 buffers (``row_map``: a map that stops at the last held row's tile, where
 the compiler's fusion runs over every row the static shape has).
-``ssd`` is the state-space layers' chunked scan and short convolution: not
-a Pallas kernel yet but ``jax.numpy`` products the compiler lowers, kept
-here so that the kernel which replaces them has its place and its tests.
+``ssd`` is the state-space layers' chunked scan and short convolution: the
+scan as two kernels with a custom backward (a chunk's decays, ``C B^T`` and
+the states never leave VMEM) on a TPU where the shapes tile, ``jax.numpy``
+products the compiler lowers elsewhere; the convolution shifted
+multiply-adds.
 Written per /opt/skills/guides/pallas_guide.md; every kernel has an
 interpret-mode path so the CPU test suite checks numerics.
 """

@@ -617,11 +617,20 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
             return layer
         # save the flash kernel's (o, lse) residuals across the remat
         # boundary: recomputing them in the backward costs a full kernel
-        # pass (~4% of the llama step on v5e) for ~70MB/layer of HBM
+        # pass (~4% of the llama step on v5e) for ~70MB/layer of HBM.
+        # Likewise the scan kernel's y and entering states (kernels/ssd.py;
+        # a Mamba layer's only: no other program holds these names): 24 KB
+        # a token a layer at Nemotron's widths (16 the float32 states, 8
+        # y), 403 MB a layer at 2 x 8192 tokens a chip, nine times the
+        # layer's carry, for 1.5 ms a layer of replay: +1.0 % tokens/s in
+        # nemotron3nano.steady-8k's four layers (PERF.md section 6, PR 34).
+        # It grows with depth and tokens a chip as the carry does: the
+        # source's 23 Mamba layers would keep 9.3 GB at those tokens on a
+        # 16 GB chip. A job that deep takes these two names out
         return jax.checkpoint(
             layer,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "flash_o", "flash_lse"
+                "flash_o", "flash_lse", "ssd_y", "ssd_states"
             ),
         )
 
@@ -654,7 +663,8 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
                     return routed(carry, lp)
             with jax.named_scope("mamba"):
                 out, counters = mamba2.apply(
-                    c, lp, _rmsnorm(carry, lp["norm"]["scale"], c.norm_eps))
+                    c, lp, _rmsnorm(carry, lp["norm"]["scale"], c.norm_eps),
+                    mesh=mesh)
                 return constrain_fwd(
                     carry + out, ["batch", "seq", "embed"]), counters
 

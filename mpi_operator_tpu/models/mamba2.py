@@ -6,7 +6,9 @@ residual itself).
     xBC = silu(causal_conv(xBC) + bias)            (kernels/ssd.py)
     x, B, C = split(xBC)               [T, H, P], [T, G, N], [T, G, N]
     dt = softplus(dt + dt_bias)   A = -exp(A_log)             float32
-    y  = scan(x, dt, A, B, C) + D x    the chunked scan (kernels/ssd.py)
+    y  = scan(x, dt, A, B, C) + D x    the chunked scan (kernels/ssd.py:
+         Pallas kernels ``ssd_fwd`` / ``ssd_bwd`` on a TPU at shapes that
+         tile, ``jax.numpy`` products elsewhere; the choice is the scan's)
     g  = y silu(z), RMS-normalised over each of the G groups of channels
          alone, times a weight                       (gate before the norm)
     out = g . W_out
@@ -85,8 +87,10 @@ def _gate_norm(y, z, scale, groups: int, eps: float):
     return (grouped.reshape(g.shape) * scale).astype(y.dtype)
 
 
-def apply(c, lp: Params, u):
-    """u [B, T, D], normed -> (the mixer's result [B, T, D], counters)."""
+def apply(c, lp: Params, u, *, mesh=None):
+    """u [B, T, D], normed -> (the mixer's result [B, T, D], counters).
+    ``mesh`` goes to the scan, whose kernels the compiler cannot partition
+    (kernels/ssd.py): every other product here is the compiler's."""
     dt_ = u.dtype
     bsz, t, _ = u.shape
     h, p, g, n = c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state
@@ -103,9 +107,8 @@ def apply(c, lp: Params, u):
         a = -jnp.exp(lp["A_log"].astype(jnp.float32))
         x = x.reshape(bsz, t, h, p)
         y = ssd.scan(x, dt, a, b.reshape(bsz, t, g, n),
-                     cm.reshape(bsz, t, g, n), chunk=c.ssm_chunk)
-        y = (y.astype(jnp.float32)
-             + lp["D"][:, None] * x.astype(jnp.float32)).astype(dt_)
+                     cm.reshape(bsz, t, g, n), skip=lp["D"],
+                     chunk=c.ssm_chunk, mesh=mesh)
         counters = {CARRY_SHARE: lax.stop_gradient(
             ssd.carry_share(dt, a, chunk=c.ssm_chunk))}
     with jax.named_scope("ssm_gate_norm"):

@@ -1,8 +1,9 @@
 """kernels/grouped_matmul.py: the three kernels' bodies under the Pallas
 interpreter, at sizes the MXU tiles, against ``lax.ragged_dot`` and its
 ``jax.vjp``. The chip's half is tests_tpu/test_moe_on_tpu.py. The compiles
-for a described chip at the end take in kernels/row_map.py's maps too: one
-file holds every test that loads the TPU's compiler."""
+for a described chip at the end take in kernels/row_map.py's maps and
+kernels/ssd.py's scan too: one file holds every test that loads the TPU's
+compiler."""
 
 import importlib
 import os
@@ -180,18 +181,27 @@ def test_the_bf16_layer_holds_no_narrower_type(monkeypatch):
 
 
 # -- the cell's shapes, compiled for the chip's compiler (no chip needed) -----
+# Every kernel's compile test is in THIS file, the scan's (kernels/ssd.py)
+# too: one process at a time may load the TPU's library, the tests run a
+# file a worker, and a second file that described the topology would find
+# it taken and skip in silence.
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described v5e chip: Mosaic refuses here what it would refuse there
-    (a tile the VMEM limit does not hold, a slice off the tiling)."""
+def topo():
+    """A described v5e host of four chips: Mosaic refuses here what it would
+    refuse there (a tile the VMEM limit does not hold, a slice off the
+    tiling), and the partitioner a kernel it cannot split."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -284,3 +294,76 @@ def test_the_cells_row_passes_compile_for_a_v5e(one_chip, name, width):
     # a number a row goes in and comes out as it lies in HBM: no copy into
     # a layout that pads it to a tile's width
     assert not re.search(r"f32\[131072,1\]", text)
+
+
+@pytest.mark.parametrize("pass_states", [True, False])
+def test_the_cells_scan_compiles_for_a_v5e(one_chip, pass_states):
+    """``nemotron3nano.steady-8k``'s state-space scan, 2 rows of 8192 in
+    chunks of 128, 64 heads of 64 in 8 groups, state 128 (kernels/ssd.py):
+    forward, replay and backward are three Mosaic calls under the names the
+    trace is read by, and what they need beside their operands is the saved
+    states, not a [Q, Q] matrix a head."""
+    from mpi_operator_tpu.kernels import ssd
+    bsz, t, h, p, g, n, chunk = 2, 8192, 64, 64, 8, 128, 128
+    assert ssd.tileable(chunk, n, h // g, p)
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def value_and_grads(*v):
+        loss = lambda x, dt, a, b, c, d: jnp.sum(jax.checkpoint(
+            lambda *v: ssd.scan(*v, skip=d, chunk=chunk,
+                                pass_states=pass_states, interpret=False))(
+            x, dt, a, b, c).astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=range(6))(*v)
+
+    compiled = jax.jit(value_and_grads).lower(
+        spec((bsz, t, h, p)), spec((bsz, t, h), jnp.float32),
+        spec((h,), jnp.float32), spec((bsz, t, g, n)),
+        spec((bsz, t, g, n)), spec((h,), jnp.float32)).compile()
+    text = compiled.as_text()
+    # with the fault planted the replay saves no states: it IS the forward,
+    # and the compiler keeps one of the two
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        3 if pass_states else 2)
+    assert re.search(r"ssd_fwd_*\.\d", text)
+    assert re.search(r"ssd_bwd_*\.\d", text)
+    # one head's decays over every (row, chunk) would be 537 MB in float32;
+    # the saved states are 268 MB and the operands' copies the rest
+    decays = bsz * (t // chunk) * h * chunk * chunk * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * decays
+
+
+def test_the_cells_scan_compiles_for_four_v5e_chips_under_shard_map(topo):
+    """The same scan on a 2 x 2 mesh (rows over ``data``, groups over
+    ``tensor``): the kernels are a device's own, one row of 8192 and four
+    groups each, and nothing is gathered around them: no collective but
+    the sums of A's and D's gradients over the rows' axis."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mpi_operator_tpu.kernels import ssd
+    bsz, t, h, p, g, n, chunk = 2, 8192, 64, 64, 8, 128, 128
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "tensor"))
+    spec = lambda shape, parts, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, P(*parts)))
+    wide = ("data", None, "tensor", None)
+
+    def value_and_grads(*v):
+        loss = lambda x, dt, a, b, c, d: jnp.sum(jax.checkpoint(
+            lambda *v: ssd.scan(*v, skip=d, chunk=chunk, interpret=False,
+                                mesh=mesh))(x, dt, a, b, c).astype(
+            jnp.float32))
+        return jax.value_and_grad(loss, argnums=range(6))(*v)
+
+    compiled = jax.jit(value_and_grads).lower(
+        spec((bsz, t, h, p), wide),
+        spec((bsz, t, h), wide[:3], jnp.float32),
+        spec((h,), ("tensor",), jnp.float32), spec((bsz, t, g, n), wide),
+        spec((bsz, t, g, n), wide),
+        spec((h,), ("tensor",), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # a device's share: x [1, 8192, 2048] in, and its saved states
+    assert re.search(r"bf16\[1,8192,2048\]", text)
+    assert "all-gather" not in text and "all-to-all" not in text
+    decays = bsz * (t // chunk) * h * chunk * chunk * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * decays // 4
+

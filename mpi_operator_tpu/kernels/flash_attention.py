@@ -9,7 +9,11 @@ T is bounded only by HBM. GQA-aware: the kv head for a q head is derived in
 the BlockSpec index maps (no K/V expansion in HBM).
 
 Layout: [B, H, T, D] (heads-major — the kernel-friendly transpose of the
-model's [B, T, H, D]; the wrapper handles it). bf16 operands on the MXU,
+model's [B, T, H, D]; the wrapper handles it). Queries and keys share one
+head size and values, and so the output and its cotangent, may have
+another (latent attention's 192 / 128): every block, scratch and output is
+sized by its own operand; a width that is no whole lane tile is one
+whole-width block. bf16 operands on the MXU,
 f32 accumulation in VMEM scratch that persists across the innermost grid
 dimension; outputs are written on that dimension's final step.
 
@@ -135,7 +139,8 @@ def _fwd_kernel(
     """One grid step folds one (q-tile, k-tile) pair. Grid (b, h, qi, ki),
     ki innermost: the f32 scratch (acc, m, l) carries the online softmax
     across a q-tile's k sweep; o/lse are written on the sweep's last step.
-    Refs: q/o [1,1,BQ,D], k/v [1,1,BK,D], lse [1,1,BQ,1]."""
+    Refs: q [1,1,BQ,D], k [1,1,BK,D], v [1,1,BK,Dv], o [1,1,BQ,Dv],
+    lse [1,1,BQ,1]."""
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -195,8 +200,10 @@ def _flash_fwd(
     q, k, v, *, causal: bool, scale: float, block_q: int, block_k: int,
     interpret: bool, window=None,
 ):
-    """q [B,H,T,D], k/v [B,Hkv,T,D] → (o [B,H,T,D], lse [B,H,Tq_pad,1])."""
+    """q [B,H,T,D], k [B,Hkv,T,D], v [B,Hkv,T,Dv] →
+    (o [B,H,T,Dv], lse [B,H,Tq_pad,1])."""
     b, h, t, d = q.shape
+    dv = v.shape[-1]
     h_kv = k.shape[1]
     g = h // h_kv
     bq = min(block_q, t)
@@ -233,18 +240,18 @@ def _flash_fwd(
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bk, d), kv_index),
-            pl.BlockSpec((1, 1, bk, d), kv_index),
+            pl.BlockSpec((1, 1, bk, dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bq, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, n_qb * bq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, n_qb * bq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, n_qb * bq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
@@ -260,8 +267,8 @@ def _bwd_dq_kernel(
     t_real: int, window=None,
 ):
     """dq: grid (b, h, qi, ki) streams K/V tiles past each q tile,
-    recomputing P on-chip from the saved LSE. Refs: q/do/dq [1,1,BQ,D],
-    k/v [1,1,BK,D], lse/delta [1,1,BQ,1]."""
+    recomputing P on-chip from the saved LSE. Refs: q/dq [1,1,BQ,D],
+    k [1,1,BK,D], v [1,1,BK,Dv], do [1,1,BQ,Dv], lse/delta [1,1,BQ,1]."""
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -316,7 +323,8 @@ def _bwd_dkv_kernel(
 ):
     """dk/dv: grid (b, h, ki, qi) streams Q/dO tiles past each k tile. GQA:
     outputs are per *q* head; the wrapper group-sums to kv heads. Refs:
-    k/v/dk/dv [1,1,BK,D], q/do [1,1,BQ,D], lse/delta [1,1,BQ,1]."""
+    k/dk [1,1,BK,D], v/dv [1,1,BK,Dv], q [1,1,BQ,D], do [1,1,BQ,Dv],
+    lse/delta [1,1,BQ,1]."""
     ki = pl.program_id(2)
     qi = pl.program_id(3)
 
@@ -372,9 +380,11 @@ def _flash_bwd(
     q, k, v, o, lse, do, *, causal: bool, scale: float, block_q: int,
     block_k: int, interpret: bool, window=None,
 ):
-    """Pallas flash backward. q/o/do [B,H,T,D], k/v [B,Hkv,T,D],
-    lse [B,H,Tq_pad,1] → (dq, dk, dv) in input shapes/dtypes."""
+    """Pallas flash backward. q [B,H,T,D], k [B,Hkv,T,D], v [B,Hkv,T,Dv],
+    o/do [B,H,T,Dv], lse [B,H,Tq_pad,1] → (dq, dk, dv) in input
+    shapes/dtypes."""
     b, h, t, d = q.shape
+    dv = v.shape[-1]
     h_kv = k.shape[1]
     g = h // h_kv
     bq = min(block_q, t)
@@ -414,8 +424,8 @@ def _flash_bwd(
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bk, d), kv_index),
-            pl.BlockSpec((1, 1, bk, d), kv_index),
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bk, dv), kv_index),
+            pl.BlockSpec((1, 1, bq, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
@@ -438,25 +448,25 @@ def _flash_bwd(
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), q_index_dkv),
             pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, qi: (bi, hi // g, ki, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, qi: (bi, hi // g, ki, 0)),
-            pl.BlockSpec((1, 1, bq, d), q_index_dkv),
+            pl.BlockSpec((1, 1, bk, dv), lambda bi, hi, ki, qi: (bi, hi // g, ki, 0)),
+            pl.BlockSpec((1, 1, bq, dv), q_index_dkv),
             pl.BlockSpec((1, 1, bq, 1), q_index_dkv),
             pl.BlockSpec((1, 1, bq, 1), q_index_dkv),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, bk, dv), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
         ],
         # partials in the input dtype (f32 accumulation stays in scratch):
         # the per-q-head [B,H,T,D] pair is the backward's largest transient,
         # and the group-sum result is cast to k.dtype regardless
         out_shape=[
             jax.ShapeDtypeStruct((b, h, n_kb * bk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, n_kb * bk, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, n_kb * bk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_dkv",
@@ -469,21 +479,21 @@ def _flash_bwd(
         .sum(axis=2)
         .astype(k.dtype)
     )
-    dv = (
+    d_v = (
         dv_h[:, :, :t]
-        .reshape(b, h_kv, g, t, d)
+        .reshape(b, h_kv, g, t, dv)
         .astype(jnp.float32)
         .sum(axis=2)
         .astype(v.dtype)
     )
-    return dq, dk, dv
+    return dq, dk, d_v
 
 
 def _block_reference(q_blk, k, v, q_offset, *, causal: bool, scale: float,
                      window=None):
     """Attention for one q block against full K/V (heads-major, GQA-aware).
-    q_blk [B,H,BQ,D], k/v [B,Hkv,T,D], q_offset scalar start index; with
-    ``window`` the band is a mask on positions."""
+    q_blk [B,H,BQ,D], k [B,Hkv,T,D], v [B,Hkv,T,Dv], q_offset scalar start
+    index; with ``window`` the band is a mask on positions."""
     b, h, bq, d = q_blk.shape
     h_kv = k.shape[1]
     g = h // h_kv
@@ -500,7 +510,7 @@ def _block_reference(q_blk, k, v, q_offset, *, causal: bool, scale: float,
     p = jax.nn.softmax(s, axis=-1)
     p5 = p.reshape(b, h_kv, g, bq, k.shape[2])
     o = jnp.einsum("bhgqk,bhkd->bhgqd", p5, v.astype(p.dtype))
-    return o.reshape(b, h, bq, d).astype(q_blk.dtype)
+    return o.reshape(b, h, bq, v.shape[-1]).astype(q_blk.dtype)
 
 
 def _chunked_reference(q, k, v, *, causal: bool, scale: float, block_q: int,
@@ -522,7 +532,7 @@ def _chunked_reference(q, k, v, *, causal: bool, scale: float, block_q: int,
             qb, k, v, off, causal=causal, scale=scale, window=window)
     )
     out = jax.lax.map(lambda args: blk(*args), (qr, offsets))  # [n,B,H,BQ,D]
-    out = out.transpose(1, 2, 0, 3, 4).reshape(b, h, t_pad, d)
+    out = out.transpose(1, 2, 0, 3, 4).reshape(b, h, t_pad, v.shape[-1])
     return out[:, :, :t]
 
 
@@ -606,7 +616,9 @@ def flash_attention(
     layout: str = "bthd",
     window: Optional[int] = None,
 ):
-    """Flash attention in model layout q [B,T,H,D], k/v [B,T,Hkv,D] — or,
+    """Flash attention in model layout q [B,T,H,D], k [B,T,Hkv,D],
+    v [B,T,Hkv,Dv] → [B,T,H,Dv] (Dv = D, or another head size for the
+    values alone) — or,
     with ``layout="bhtd"``, directly in the kernel's heads-major layout
     (a caller that PRODUCES q/k/v heads-major skips the [B,T,H,D]↔[B,H,T,D]
     copies the wrapper otherwise pays on every call, ~3% of the llama step).

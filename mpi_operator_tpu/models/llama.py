@@ -19,10 +19,17 @@ The reference has no LLM workload — its examples top out at CNN scale
   and the kinds inside a period (``Config.layer_kinds``) are unrolled in the
   scan's body, so a model of one kind compiles to the plain scan over
   layers. A layer is a *pair* or a *single mixer*. A pair (``full``,
-  ``window``) is attention then a feed-forward, each behind its own norm and
-  residual: full causal attention or a sliding window of keys, each with its
-  own RoPE; the feed-forward dense (SwiGLU) or routed (``Config.n_experts``
-  > 0: parallel/moe.py's share of the experts). A single mixer (``mamba``,
+  ``window``, ``latent``) is attention then a feed-forward, each behind its
+  own norm and residual: full causal attention or a sliding window of keys,
+  each with its own RoPE, or multi-head latent attention (keys and values
+  expanded from one normalised low-rank latent a token, a rotated part of
+  the key that all heads share, keys and queries wider than values; its own
+  parameter tree); the feed-forward dense (SwiGLU) or routed
+  (``Config.n_experts`` > 0: parallel/moe.py's share of the experts).
+  ``Config.n_dense_layers`` pairs in front of a routed model's periods keep
+  the dense feed-forward, ``d_ff`` wide: a stack of their own
+  (``params["lead"]``), scanned before the periods, so the program is two
+  small scans whatever the depth. A single mixer (``mamba``,
   ``experts``, ``attention``) is ``x + mixer(rmsnorm(x))`` and nothing else:
   a Mamba-2 state-space layer (models/mamba2.py), a routed layer alone, or
   causal attention with no positional embedding (position comes through the
@@ -58,7 +65,7 @@ from mpi_operator_tpu.runtime.topology import AXIS_SEQ
 Params = Dict[str, Any]
 
 
-PAIR_KINDS = ("full", "window")  # attention, then a feed-forward
+PAIR_KINDS = ("full", "window", "latent")  # attention, then a feed-forward
 MIXER_KINDS = ("mamba", "experts", "attention")  # one mixer a layer
 LAYER_KINDS = PAIR_KINDS + MIXER_KINDS
 
@@ -116,16 +123,31 @@ class Config:
     # all layer intermediates at once)
     remat_layers: bool = False
     # one period of the layer pattern. Pairs, by their attention: "full"
-    # (causal) or "window" (query i sees the ``window`` keys up to its
-    # own). Or single mixers: "mamba", "experts" (the routed layer alone),
+    # (causal), "window" (query i sees the ``window`` keys up to its own)
+    # or "latent" (causal, keys and values from a latent: below). Or single
+    # mixers: "mamba", "experts" (the routed layer alone),
     # "attention" (causal, no positional embedding). One or the other, not
-    # both in one pattern. The stack is n_layers / len(layer_kinds) periods.
+    # both in one pattern. The stack is ``n_dense_layers`` leading pairs,
+    # then (n_layers - n_dense_layers) / len(layer_kinds) periods.
     layer_kinds: Tuple[str, ...] = ("full",)
     window: Optional[int] = None
+    # leading pairs of a routed model whose feed-forward is the dense one
+    # (``d_ff`` wide), with the attention of the (one) pair kind
+    n_dense_layers: int = 0
+    # latent attention: a head's query and key are ``qk_nope_dim`` numbers
+    # without position and ``qk_rope_dim`` rotated ones (the key's rotated
+    # part is one for all heads), its value ``v_head_dim``; keys without
+    # position and values are expanded from a normalised latent of
+    # ``kv_lora_rank`` a token. ``head_dim`` and ``n_kv_heads`` are unused
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # RoPE by kind: window layers rotate at the plain ``rope_theta``, full
     # layers too unless there is a Yarn for them
     yarn_full: Optional[Yarn] = None
-    # routed feed-forward (n_experts > 0; ``d_ff`` is then unused): the
+    # routed feed-forward (n_experts > 0; ``d_ff`` is then the leading
+    # dense layers' width, unused without them): the
     # router scores ``n_experts`` published experts and keeps the
     # ``experts_per_token`` largest; this program holds ``n_experts_held``
     # of them (0: all), ``first_expert`` on, each ``d_expert`` wide
@@ -137,7 +159,7 @@ class Config:
     # the routed layer's form (parallel/moe.py): the score function
     # ("softmax", or "sigmoid" with a correction bias in the choice), a
     # factor on the weights, gated SwiGLU experts or ungated relu ** 2 ones,
-    # and the width of a shared expert (0: none; ungated experts only)
+    # and the width of a shared expert (0: none; of the experts' form)
     router_score: str = "softmax"
     router_scale: float = 1.0
     experts_gated: bool = True
@@ -173,9 +195,6 @@ class Config:
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score={self.router_score!r}; "
                              "expected softmax|sigmoid")
-        if self.d_shared and self.experts_gated:
-            raise ValueError("a shared expert is an ungated relu ** 2 "
-                             "feed-forward: experts_gated must be False")
         if "experts" in kinds and not self.n_experts:
             raise ValueError("an 'experts' layer needs n_experts")
         if "mamba" in kinds and not (
@@ -188,10 +207,29 @@ class Config:
                 f"{self.ssm_head_dim} in {self.ssm_groups} groups, state "
                 f"{self.ssm_state}, kernel {self.conv_kernel}, chunk "
                 f"{self.ssm_chunk}")
-        if self.n_layers % len(kinds):
+        if "latent" in kinds and not (
+                self.kv_lora_rank > 0 and self.qk_nope_dim > 0
+                and self.v_head_dim > 0 and self.qk_rope_dim > 0
+                and self.qk_rope_dim % 2 == 0):
+            raise ValueError(
+                f"latent attention: a latent of {self.kv_lora_rank}, keys "
+                f"of {self.qk_nope_dim} + {self.qk_rope_dim} (an even "
+                f"number rotated), values of {self.v_head_dim}")
+        if self.n_dense_layers and not (
+                self.routed and len(set(kinds)) == 1
+                and kinds[0] in PAIR_KINDS):
+            raise ValueError(
+                f"n_dense_layers={self.n_dense_layers}: leading dense "
+                "pairs stand in front of a routed model's periods of one "
+                f"pair kind (layer_kinds={kinds!r}, n_experts="
+                f"{self.n_experts})")
+        periods, rest = divmod(self.n_layers - self.n_dense_layers,
+                               len(kinds))
+        if periods < 1 or rest or self.n_dense_layers < 0:
             raise ValueError(
                 f"n_layers={self.n_layers} is no whole number of periods "
-                f"of {len(kinds)} layers")
+                f"of {len(kinds)} layers behind {self.n_dense_layers} "
+                "leading dense ones")
         if ("window" in kinds) != (self.window is not None):
             raise ValueError(
                 "window layers need a window, and a window needs them: "
@@ -217,6 +255,16 @@ class Config:
     @property
     def experts_held(self) -> int:
         return self.n_experts_held or self.n_experts
+
+    @property
+    def latent(self) -> bool:
+        return "latent" in self.layer_kinds
+
+    @property
+    def qk_head_dim(self) -> int:
+        """What a query meets a key over."""
+        return (self.qk_nope_dim + self.qk_rope_dim if self.latent
+                else self.head_dim)
 
     @property
     def q_dim(self) -> int:
@@ -279,6 +327,20 @@ def tiny_hybrid(vocab: int = 256) -> Config:
     )
 
 
+def tiny_latent(vocab: int = 256) -> Config:
+    """Test-scale config of the latent-attention shape: one leading dense
+    pair, then two routed ones; keys and queries 24 wide (16 + 8 rotated),
+    values 16, a latent of 32; sigmoid routing over 8 gated experts of
+    which 2 a token and the first 4 held, beside a gated shared expert."""
+    return Config(
+        vocab=vocab, d_model=48, n_layers=3, n_heads=4, d_ff=96,
+        rope_theta=10_000.0, norm_eps=1e-6, layer_kinds=("latent",),
+        n_dense_layers=1, kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, n_experts=8, n_experts_held=4, experts_per_token=2,
+        d_expert=24, router_score="sigmoid", router_scale=2.5, d_shared=48,
+    )
+
+
 def _normal(key, shape, scale):
     return jax.random.normal(key, shape, jnp.float32) * scale
 
@@ -297,6 +359,47 @@ def _init_attention(c: Config, keys, lead=()) -> Params:
         "wk": {"w": _normal(keys[1], (*lead, d, c.kv_dim), d**-0.5)},
         "wv": {"w": _normal(keys[2], (*lead, d, c.kv_dim), d**-0.5)},
         "wo": {"w": _normal(keys[3], (*lead, c.q_dim, d), c.q_dim**-0.5)},
+    }
+
+
+def _init_latent(c: Config, keys, lead=()) -> Params:
+    """Latent attention's tree: the query's projection, the projection down
+    to the latent and the shared rotated key, the latent's norm, the
+    expansion to every head's key without position and value, the output's
+    projection. Projections std fan_in**-0.5, as the others."""
+    d, h, r = c.d_model, c.n_heads, c.kv_lora_rank
+    up = h * (c.qk_nope_dim + c.v_head_dim)
+    return {
+        "attn_norm": {"scale": jnp.ones((*lead, d), jnp.float32)},
+        "wq": {"w": _normal(keys[0], (*lead, d, h * c.qk_head_dim), d**-0.5)},
+        "wkv_a": {"w": _normal(keys[1], (*lead, d, r + c.qk_rope_dim),
+                               d**-0.5)},
+        "kv_a_norm": {"scale": jnp.ones((*lead, r), jnp.float32)},
+        "wkv_b": {"w": _normal(keys[2], (*lead, r, up), r**-0.5)},
+        "wo": {"w": _normal(keys[3], (*lead, h * c.v_head_dim, d),
+                            (h * c.v_head_dim)**-0.5)},
+    }
+
+
+def _init_pairs(c: Config, lk, n: int, routed: bool) -> Params:
+    """``n`` pairs stacked on axis 0 (``lax.scan`` walks the leading axis):
+    the attention's leaves, the feed-forward's norm, and every layer's
+    router and held experts where ``routed``, else the dense SwiGLU."""
+    d = c.d_model
+    if routed:
+        feed_forward = jax.vmap(lambda k: _init_routed(c, k))(
+            jax.random.split(lk[4], n))
+    else:
+        feed_forward = {
+            "w_gate": {"w": _normal(lk[4], (n, d, c.d_ff), d**-0.5)},
+            "w_up": {"w": _normal(lk[5], (n, d, c.d_ff), d**-0.5)},
+            "w_down": {"w": _normal(lk[6], (n, c.d_ff, d), c.d_ff**-0.5)},
+        }
+    attention = _init_latent if c.latent else _init_attention
+    return {
+        **attention(c, lk, lead=(n,)),
+        "mlp_norm": {"scale": jnp.ones((n, d), jnp.float32)},
+        **feed_forward,
     }
 
 
@@ -327,9 +430,8 @@ def init(config: Config, key) -> Params:
     c = config
     ke, kl, kh = jax.random.split(key, 3)
     lk = jax.random.split(kl, 7)
-    n, d = c.n_layers, c.d_model
+    d = c.d_model
     s_d = d**-0.5
-    s_ff = c.d_ff**-0.5
     if c.single_mixers:
         # unlike trees: each kind's layers stacked on an axis of their own
         layers = {
@@ -337,28 +439,18 @@ def init(config: Config, key) -> Params:
                 jax.random.split(lk[i], count))
             for i, (kind, count) in enumerate(_per_kind(c).items())}
     else:
-        if c.routed:
-            # every layer's router and held experts, stacked like the rest
-            feed_forward = jax.vmap(lambda k: _init_routed(c, k))(
-                jax.random.split(lk[4], n))
-        else:
-            feed_forward = {
-                "w_gate": {"w": _normal(lk[4], (n, d, c.d_ff), s_d)},
-                "w_up": {"w": _normal(lk[5], (n, d, c.d_ff), s_d)},
-                "w_down": {"w": _normal(lk[6], (n, c.d_ff, d), s_ff)},
-            }
-        # all layers stacked on axis 0 → lax.scan over the leading axis
-        layers = {
-            **_init_attention(c, lk, lead=(n,)),
-            "mlp_norm": {"scale": jnp.ones((n, d), jnp.float32)},
-            **feed_forward,
-        }
-    return {
+        layers = _init_pairs(c, lk, c.n_layers - c.n_dense_layers, c.routed)
+    params = {
         "embed": {"w": _normal(ke, (c.vocab, d), 1.0)},
         "layers": layers,
         "final_norm": {"scale": jnp.ones((d,), jnp.float32)},
         "lm_head": {"w": _normal(kh, (d, c.vocab), s_d)},
     }
+    if c.n_dense_layers:  # a stack of their own, in front of the periods
+        params["lead"] = _init_pairs(
+            c, jax.random.split(jax.random.fold_in(kl, 7), 7),
+            c.n_dense_layers, routed=False)
+    return params
 
 
 _ATTENTION_AXES = {
@@ -370,34 +462,50 @@ _ATTENTION_AXES = {
 }
 
 
+# latent attention: the latent's own axis is spread like the model's
+_LATENT_AXES = {
+    "attn_norm": {"scale": ("stats",)},
+    "wq": {"w": ("embed", "heads")},
+    "wkv_a": {"w": ("embed", None)},
+    "kv_a_norm": {"scale": ("stats",)},
+    "wkv_b": {"w": ("latent", "heads")},
+    "wo": {"w": ("heads", "embed")},
+}
+_DENSE_AXES = {
+    "mlp_norm": {"scale": ("stats",)},
+    "w_gate": {"w": ("embed", "mlp")},
+    "w_up": {"w": ("embed", "mlp")},
+    "w_down": {"w": ("mlp", "embed")},
+}
+
+
 def logical_axes(config: Config) -> Params:
     c = config
     routed_axes = lambda: {
         "mlp_norm": {"scale": ("stats",)},
         **moe.logical_axes(shared=c.d_shared > 0, **_moe_form(c))}
+    attention_axes = _LATENT_AXES if c.latent else _ATTENTION_AXES
     if c.single_mixers:
         by_kind = {"mamba": mamba2.logical_axes,
                    "attention": lambda: _ATTENTION_AXES,
                    "experts": routed_axes}
         layers = {kind: by_kind[kind]() for kind in _per_kind(c)}
-    elif c.routed:
-        layers = {**_ATTENTION_AXES, **routed_axes()}
     else:
-        layers = {
-            **_ATTENTION_AXES,
-            "mlp_norm": {"scale": ("stats",)},
-            "w_gate": {"w": ("embed", "mlp")},
-            "w_up": {"w": ("embed", "mlp")},
-            "w_down": {"w": ("mlp", "embed")},
-        }
-    return {
+        layers = {**attention_axes,
+                  **(routed_axes() if c.routed else _DENSE_AXES)}
+    # the leading stack axis is always replicated (None)
+    stacked = lambda tree: jax.tree.map(
+        lambda axes: (None, *axes), tree,
+        is_leaf=lambda x: isinstance(x, tuple))
+    axes = {
         "embed": {"w": ("vocab", "embed")},
-        # the leading stack axis is always replicated (None)
-        "layers": jax.tree.map(lambda axes: (None, *axes), layers,
-                               is_leaf=lambda x: isinstance(x, tuple)),
+        "layers": stacked(layers),
         "final_norm": {"scale": ("stats",)},
         "lm_head": {"w": ("embed", "vocab")},
     }
+    if c.n_dense_layers:
+        axes["lead"] = stacked({**attention_axes, **_DENSE_AXES})
+    return axes
 
 
 def _rmsnorm32(x, scale, eps):
@@ -522,8 +630,63 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
         raise ValueError(
             "a window needs the whole sequence on one chip: the ring "
             "(mesh axis 'sequence') has no band")
+    if seq_sharded and c.latent:
+        raise ValueError(
+            "latent attention needs the whole sequence on one chip: the "
+            "ring (mesh axis 'sequence') carries keys and values of one "
+            "head size")
+
+    def latent_attention(h, lp):
+        """Multi-head latent attention: ``[c | k_rot] = y W_kva``, keys
+        without position and values expanded from ``rmsnorm(c)``, the
+        key's rotated part one for all heads, RoPE over the rotated
+        parts alone (half-split pairs, as :func:`_rotate`), scores over
+        the concatenated ``qk_head_dim`` scaled by its root."""
+        y = _rmsnorm(h, lp["attn_norm"]["scale"], c.norm_eps)
+        b, t, _ = y.shape
+        nope, rot, r = c.qk_nope_dim, c.qk_rope_dim, c.kv_lora_rank
+        # heads-major end to end, as the other kinds: the transposes fold
+        # into the products
+        with jax.named_scope("latent_q"):
+            q = jnp.einsum("btd,dhx->bhtx", y, lp["wq"]["w"].astype(dt)
+                           .reshape(-1, c.n_heads, nope + rot))
+        with jax.named_scope("latent_kv_down"):
+            down = y @ lp["wkv_a"]["w"].astype(dt)  # [B, T, r + rot]
+            latent = _rmsnorm(down[..., :r], lp["kv_a_norm"]["scale"],
+                              c.norm_eps)
+        with jax.named_scope("latent_kv_up"):
+            # two products, so that the values are an array of their own
+            # and not a slice the kernel would need copied out
+            wkv3 = lp["wkv_b"]["w"].astype(dt).reshape(
+                r, c.n_heads, nope + c.v_head_dim)
+            k_nope = jnp.einsum("btr,rhx->bhtx", latent, wkv3[..., :nope])
+            v = jnp.einsum("btr,rhx->bhtx", latent, wkv3[..., nope:])
+        with jax.named_scope("latent_rope"):
+            cos, sin = _rope_tables(t, rot, c.rope_theta, dt)
+            q = jnp.concatenate(
+                [q[..., :nope], _rotate(q[..., nope:], cos, sin)], axis=-1)
+            k_rot = _rotate(down[..., r:], cos, sin)  # [B, T, rot]
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_rot[:, None], (b, c.n_heads, t, rot))], axis=-1)
+        scale = c.qk_head_dim**-0.5
+        if c.attention_impl != "dense":
+            from mpi_operator_tpu.kernels import flash_attention
+
+            attn = flash_attention(q, k, v, causal=True, scale=scale,
+                                   mesh=mesh, layout="bhtd")
+        else:
+            attn = dense_attention(
+                *(a.transpose(0, 2, 1, 3) for a in (q, k, v)), causal=True,
+                scale=scale).transpose(0, 2, 1, 3)
+        with jax.named_scope("latent_out"):
+            h = h + jnp.einsum(
+                "bhtx,hxd->btd", attn, lp["wo"]["w"].astype(dt).reshape(
+                    c.n_heads, c.v_head_dim, -1))
+        return constrain_fwd(h, ["batch", "seq", "embed"])
 
     def attention(h, lp, kind):
+        if kind == "latent":
+            return latent_attention(h, lp)
         y = _rmsnorm(h, lp["attn_norm"]["scale"], c.norm_eps)
         b, t, _ = y.shape
         # K/V stay at n_kv_heads: every attention path is GQA-aware, so the
@@ -653,6 +816,13 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
 
         return remat(layer)
 
+    def lead_layer(carry, lp):
+        """A leading pair of a routed model: the dense feed-forward."""
+        with jax.named_scope("lead"):
+            h = attend(carry, lp, c.layer_kinds[0])
+            with jax.named_scope("mlp"):
+                return mlp(h, lp), None
+
     def mixer_of(kind):
         """A single mixer: ``x + mixer(rmsnorm(x))`` and nothing else."""
         def layer(carry, lp):
@@ -669,6 +839,9 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
                     carry + out, ["batch", "seq", "embed"]), counters
 
         return remat(layer)
+
+    if c.n_dense_layers:  # a scan of their own, before the periods'
+        x, _ = lax.scan(remat(lead_layer), x, params["lead"])
 
     # the scan walks periods; the kinds inside one are unrolled in its
     # body. A model of one kind is the plain scan over its layers.
@@ -804,11 +977,18 @@ def param_count(config: Config) -> int:
     """The parameters held: of a routed model's experts, this share's."""
     c = config
     d = c.d_model
-    attention = d * (c.q_dim + 2 * c.kv_dim) + c.q_dim * d + d
+    if c.latent:
+        r, h = c.kv_lora_rank, c.n_heads
+        attention = (d * h * c.qk_head_dim + d * (r + c.qk_rope_dim) + r
+                     + r * h * (c.qk_nope_dim + c.v_head_dim)
+                     + h * c.v_head_dim * d + d)
+    else:
+        attention = d * (c.q_dim + 2 * c.kv_dim) + c.q_dim * d + d
+    matrices = 3 if c.experts_gated else 2  # of an expert, routed or shared
     routed = (d * c.n_experts
               + (c.n_experts if c.router_score == "sigmoid" else 0)
-              + c.experts_held * (3 if c.experts_gated else 2) * d * c.d_expert
-              + 2 * d * c.d_shared + d)
+              + matrices * d * (c.experts_held * c.d_expert + c.d_shared)
+              + d)
     if c.single_mixers:
         inner, conv, proj = mamba2.widths(c)
         mamba = (d + d * proj + conv * (c.conv_kernel + 1) + 3 * c.ssm_heads
@@ -817,6 +997,8 @@ def param_count(config: Config) -> int:
         layers = sum(per_kind[kind] * count
                      for kind, count in _per_kind(c).items())
     else:
-        feed_forward = routed if c.routed else 3 * d * c.d_ff + d
-        layers = c.n_layers * (attention + feed_forward)
+        dense = 3 * d * c.d_ff + d
+        layers = (c.n_dense_layers * (attention + dense)
+                  + (c.n_layers - c.n_dense_layers)
+                  * (attention + (routed if c.routed else dense)))
     return c.vocab * d + layers + d + d * c.vocab

@@ -14,9 +14,11 @@ experts' part of the result:
     h = relu(W_up[e] . x[t]) ** 2          no ``w_gate`` in the tree: two
 
 A *shared expert* (``shared_up`` / ``shared_down`` in the tree) is a dense
-``relu ** 2`` feed-forward of every token, added to the routed result once:
-what every chip computes alike, so over an ``expert`` mesh axis it is added
-after the members' sum and not once a member.
+feed-forward of every token in the routed experts' form, added to the
+routed result once: ``relu ** 2`` beside ungated experts, SwiGLU (with
+``shared_gate`` in the tree) beside gated ones. It is what every chip
+computes alike, so over an ``expert`` mesh axis it is added after the
+members' sum and not once a member.
 
 What the absent experts would add is left out; the partial results of all
 shares (the shared expert counted once) add up to the whole layer
@@ -96,10 +98,11 @@ def init(key, *, d_model: int, d_expert: int, n_experts: int,
     """One layer's weights: the router over all published experts (with
     ``score_bias`` its correction bias, nought), the matrices of the
     ``n_held`` experts held here (three, or without ``gated`` two), and with
-    ``d_shared`` the shared expert's two. Projections std fan_in**-0.5, as
-    the dense feed-forward's."""
+    ``d_shared`` the shared expert's, as many. Projections std
+    fan_in**-0.5, as the dense feed-forward's."""
     kr, kg, ku, kd = jax.random.split(key, 4)
     ksu, ksd = jax.random.split(jax.random.fold_in(key, 4))
+    ksg = jax.random.fold_in(key, 5)
     normal = lambda k, shape, fan_in: (
         jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5)
     p = {
@@ -114,6 +117,9 @@ def init(key, *, d_model: int, d_expert: int, n_experts: int,
     if d_shared:
         p["shared_up"] = {"w": normal(ksu, (d_model, d_shared), d_model)}
         p["shared_down"] = {"w": normal(ksd, (d_shared, d_model), d_shared)}
+        if gated:
+            p["shared_gate"] = {
+                "w": normal(ksg, (d_model, d_shared), d_model)}
     return p
 
 
@@ -131,6 +137,8 @@ def logical_axes(gated: bool = True, shared: bool = False,
     if shared:
         axes["shared_up"] = {"w": ("embed", "mlp")}
         axes["shared_down"] = {"w": ("mlp", "embed")}
+        if gated:
+            axes["shared_gate"] = {"w": ("embed", "mlp")}
     return axes
 
 
@@ -444,11 +452,17 @@ def _mesh_axes(mesh) -> Tuple[Tuple[str, ...], bool]:
 
 
 def _shared(params: Params, x, dt):
-    """The shared expert: ``relu(x . up) ** 2 . down`` of every token."""
+    """The shared expert of every token: ``relu(x . up) ** 2 . down``, or
+    with ``shared_gate`` in the tree ``(silu(x . gate) * (x . up)) . down``."""
     with jax.named_scope("moe_shared"):
-        up = x.astype(dt) @ params["shared_up"]["w"].astype(dt)
-        h = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(dt)
-        return h @ params["shared_down"]["w"].astype(dt)
+        up = (x.astype(dt) @ params["shared_up"]["w"].astype(dt)).astype(
+            jnp.float32)
+        if "shared_gate" in params:
+            gate = x.astype(dt) @ params["shared_gate"]["w"].astype(dt)
+            h = jax.nn.silu(gate.astype(jnp.float32)) * up
+        else:
+            h = jnp.square(jax.nn.relu(up))
+        return h.astype(dt) @ params["shared_down"]["w"].astype(dt)
 
 
 def apply(params: Params, x, *, experts_per_token: int, first_expert: int = 0,
@@ -459,7 +473,8 @@ def apply(params: Params, x, *, experts_per_token: int, first_expert: int = 0,
     gives them: the router over all published experts and the matrices of
     the experts held, ``first_expert`` on. What the tree holds says what the
     layer is: a router ``bias`` the sigmoid score (else softmax), no
-    ``w_gate`` ungated ``relu ** 2`` experts, ``shared_up`` a shared expert.
+    ``w_gate`` ungated ``relu ** 2`` experts, ``shared_up`` a shared expert
+    (gated where ``shared_gate`` is there too).
     ``router_in`` [B, T, D] is what the router scores where that differs
     from ``x`` (a float32 copy of a bf16 activation); ``router_scale``
     multiplies the weights; ``matmul_precision`` other than ``bf16`` sends

@@ -37,6 +37,7 @@ DEFAULT_RULES: Rules = {
     "batch": (AXIS_DATA, AXIS_FSDP),
     "seq": AXIS_SEQ,
     "embed": AXIS_FSDP,
+    "latent": AXIS_FSDP,
     "mlp": AXIS_TENSOR,
     "heads": AXIS_TENSOR,
     "kv_heads": AXIS_TENSOR,

@@ -7,6 +7,7 @@ from benchmark.tests.test_named_trace import *  # noqa: F401,F403
 from benchmark.tests.test_ckpt_import_s import *  # noqa: F401,F403
 from benchmark.tests.test_mellum import *  # noqa: F401,F403
 from benchmark.tests.test_nemotron_h import *  # noqa: F401,F403
+from benchmark.tests.test_deepseek_v3 import *  # noqa: F401,F403
 
 
 def test_its_entry_is_the_last_and_sits_beside_ckpt_open_s():  # noqa: F811

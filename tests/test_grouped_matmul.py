@@ -246,6 +246,55 @@ def test_products_1856_wide_compile_for_a_v5e(one_chip, k, n):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)])
+def test_products_768_wide_compile_for_a_v5e(one_chip, k, n):
+    """``kanana2.steady-8k``'s 98,304 rows in 16 groups, experts 768 = six
+    lane tiles wide, the three kernels under their names."""
+    rows, groups = 98304, 16
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def all_three(xs, w, ct, sizes):
+        y, vjp = jax.vjp(lambda a, b: gm._grouped(a, b, sizes, False), xs, w)
+        return y, vjp(ct)
+
+    text = jax.jit(all_three).lower(
+        spec((rows, k)), spec((groups, k, n)), spec((rows, n)),
+        spec((groups,), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("d,dv", [(192, 128), (128, 128)])
+def test_the_flash_kernels_compile_for_a_v5e_at_unlike_head_sizes(
+        one_chip, d, dv):
+    """Latent attention's shapes in ``kanana2.steady-8k``: 2 rows x 32 heads
+    x 8192 positions, keys and queries 192 wide (one and a half lane tiles:
+    a whole-width block), values, the output and its cotangent 128; forward,
+    ``dq`` and ``dk`` / ``dv``, each one Mosaic call under its name. Beside
+    it the equal sizes every other cell runs."""
+    fa = importlib.import_module("mpi_operator_tpu.kernels.flash_attention")
+    spec = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=one_chip)
+
+    def all_three(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: fa._flash(
+            q, k, v, True, d ** -0.5, 1024, 1024, False, None), q, k, v)
+        return o, vjp(do)
+
+    compiled = jax.jit(all_three).lower(
+        spec(2, 32, 8192, d), spec(2, 32, 8192, d), spec(2, 32, 8192, dv),
+        spec(2, 32, 8192, dv)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert re.search(name + r"_*\.\d", text), name
+    o, (dq, dk, d_v) = jax.eval_shape(
+        all_three, spec(2, 32, 8192, d), spec(2, 32, 8192, d),
+        spec(2, 32, 8192, dv), spec(2, 32, 8192, dv))
+    assert (o.shape[-1], dq.shape[-1], dk.shape[-1], d_v.shape[-1]) == (
+        dv, d, d, dv)
+
+
 @pytest.mark.parametrize("name", ["moe_relu2", "moe_relu2_t"])
 def test_the_ungated_row_passes_compile_for_a_v5e_at_1856(one_chip, name):
     rows, width = 98304, 1856

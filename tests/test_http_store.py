@@ -218,6 +218,10 @@ def test_ring_resume_boundaries_through_the_wire():
     try:
         for i in range(10):
             c.create(Pod(metadata=ObjectMeta(name=f"p{i}")))  # rvs 1..10
+        # the server's drain thread appends to the ring after create returns
+        deadline = time.monotonic() + 5.0
+        while srv._log._dropped_rv < 6 and time.monotonic() < deadline:
+            time.sleep(0.01)
         dropped = srv._log._dropped_rv
         assert dropped == 6
 

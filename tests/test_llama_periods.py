@@ -18,7 +18,8 @@ from mpi_operator_tpu.ops import (
 from mpi_operator_tpu.ops.data import make_global_batch
 from mpi_operator_tpu.parallel import moe
 from mpi_operator_tpu.runtime import MeshPlan, build_mesh, stepstats
-from mpi_operator_tpu.runtime.topology import AXIS_DATA, AXIS_SEQ
+from mpi_operator_tpu.runtime.topology import (
+    AXIS_DATA, AXIS_FSDP, AXIS_SEQ, AXIS_TENSOR)
 
 TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0, 256)
 
@@ -376,7 +377,7 @@ def test_the_hybrid_steps_scalars_reach_its_metrics(hybrid):
 @pytest.mark.parametrize("change", [
     dict(layer_kinds=("mamba", "full")),  # a pair among single mixers
     dict(layer_kinds=("mamba", "linear")),  # a kind it does not know
-    dict(experts_gated=True),  # a shared expert beside gated experts
+    dict(n_dense_layers=1),  # leading dense pairs before single mixers
     dict(router_score="tanh"),
     dict(n_experts=0, n_experts_held=0),  # an 'experts' layer, no experts
     dict(ssm_heads=0), dict(ssm_groups=3), dict(ssm_chunk=0),
@@ -391,3 +392,224 @@ def test_a_sequence_that_is_no_whole_number_of_chunks_is_refused(hybrid):
     cfg, params = hybrid
     with pytest.raises(ValueError, match="whole number of chunks"):
         llama.apply(cfg, params, TOKENS[:, :20])
+
+
+# -- latent attention, and leading dense pairs before the routed ones --------
+
+import importlib  # noqa: E402
+import os  # noqa: E402
+import sys  # noqa: E402
+
+_BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if _BENCH not in sys.path:  # the plain reference and the adapter live there
+    sys.path.insert(0, _BENCH)
+
+
+@pytest.fixture(scope="module")
+def latent():
+    cfg = dataclasses.replace(llama.tiny_latent(),
+                              compute_dtype=jnp.float32)
+    return cfg, llama.init(cfg, jax.random.PRNGKey(0))
+
+
+def test_latent_pairs_have_their_tree_and_the_dense_ones_a_stack(latent):
+    cfg, params = latent
+    attention = {"attn_norm", "wq", "wkv_a", "kv_a_norm", "wkv_b", "wo"}
+    assert set(params["lead"]) == attention | {
+        "mlp_norm", "w_gate", "w_up", "w_down"}
+    assert set(params["layers"]) == attention | {
+        "mlp_norm", "router", "w_gate", "w_up", "w_down", "shared_gate",
+        "shared_up", "shared_down"}
+    shape = lambda tree, name: tree[name]["w"].shape
+    # keys and queries 24 = 16 + 8 wide, values 16, a latent of 32
+    assert shape(params["layers"], "wq") == (2, 48, 4 * 24)
+    assert shape(params["layers"], "wkv_a") == (2, 48, 32 + 8)
+    assert shape(params["layers"], "wkv_b") == (2, 32, 4 * (16 + 16))
+    assert shape(params["layers"], "wo") == (2, 4 * 16, 48)
+    assert shape(params["lead"], "wq") == (1, 48, 4 * 24)
+    assert shape(params["lead"], "w_gate") == (1, 48, 96)
+    assert shape(params["layers"], "w_gate") == (2, 4, 48, 24)
+    assert shape(params["layers"], "shared_gate") == (2, 48, 48)
+    assert llama.param_count(cfg) == sum(
+        a.size for a in jax.tree.leaves(params))
+    axes = llama.logical_axes(cfg)
+    jax.tree.map(lambda a, ax: None if a.ndim == len(ax) else 1 / 0,
+                 params, axes, is_leaf=lambda x: isinstance(x, tuple))
+
+
+def test_the_latent_flash_and_dense_paths_agree(latent):
+    cfg, params = latent
+    dense = dataclasses.replace(cfg, attention_impl="dense")
+    np.testing.assert_allclose(
+        llama.apply(cfg, params, TOKENS), llama.apply(dense, params, TOKENS),
+        atol=1e-5, rtol=1e-5)
+
+
+def test_the_leading_pairs_and_the_periods_are_two_scans(latent):
+    """Whatever the depth: the leading dense pairs are scanned, not
+    unrolled, and so are the routed ones; the router's counters are the
+    routed layers' alone."""
+    cfg, _ = latent
+    deep = dataclasses.replace(cfg, n_layers=7, n_dense_layers=3)
+    params = llama.init(deep, jax.random.PRNGKey(3))
+    jaxpr = jax.make_jaxpr(lambda p: llama.loss_fn(
+        deep, p, {"tokens": TOKENS})[0])(params)
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in scans] == [3, 4]
+    _, counters = llama._forward(deep, params, TOKENS, mesh=None, rules=None,
+                                 return_features=False)
+    assert {v.shape for v in counters.values()} == {(4,)}
+    _, scalars = llama.loss_fn(deep, params, {"tokens": TOKENS})
+    np.testing.assert_allclose(
+        scalars[moe.ASSIGNMENTS_HELD],
+        jnp.mean(counters[moe.ASSIGNMENTS_HELD]))
+
+
+def test_the_leading_pairs_feed_forward_is_the_dense_one(latent):
+    """Nought in the leading stack's ``w_down`` leaves the layer its
+    attention alone, and the routed layers' experts are untouched by it."""
+    cfg, params = latent
+    want = llama.apply(cfg, params, TOKENS)
+    lead = dict(params["lead"], w_down={"w": jnp.zeros_like(
+        params["lead"]["w_down"]["w"])})
+    got = llama.apply(cfg, dict(params, lead=lead), TOKENS)
+    assert float(jnp.max(jnp.abs(got - want))) > 1e-3
+    assert cfg.d_ff == 96 and cfg.routed
+
+
+def test_one_rotated_key_serves_every_head_and_values_do_not_rotate(latent):
+    """The last position's result follows the order of the tokens before it
+    (the rotated parts carry position); with the rotated columns of ``wq``
+    nought it does not: what is left of a score is the unrotated part, and
+    values and the latent carry no position."""
+    cfg, _ = latent
+    # one layer: a second would read the first's results, which follow
+    # their prefixes
+    cfg = dataclasses.replace(cfg, n_layers=1, n_dense_layers=0)
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    assert "lead" not in params
+    moved = TOKENS.at[:, :-1].set(TOKENS[:, :-1][:, ::-1])
+    last = lambda p, t: llama.apply(cfg, p, t)[:, -1]
+    assert float(jnp.max(jnp.abs(
+        last(params, TOKENS) - last(params, moved)))) > 1e-3
+    wq = params["layers"]["wq"]["w"]
+    blind = dict(params, layers=dict(params["layers"], wq={
+        "w": wq.reshape(*wq.shape[:-1], 4, 24).at[..., 16:].set(0.0)
+        .reshape(wq.shape)}))
+    np.testing.assert_allclose(last(blind, TOKENS), last(blind, moved),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("change,match", [
+    (dict(kv_lora_rank=0), "latent attention"),
+    (dict(qk_rope_dim=7), "latent attention"),
+    (dict(v_head_dim=0), "latent attention"),
+    (dict(n_experts=0, n_experts_held=0, d_shared=0), "n_dense_layers"),
+    (dict(n_layers=4, layer_kinds=("latent", "full")), "n_dense_layers"),
+    (dict(n_dense_layers=3), "periods"),
+    (dict(n_layers=4, n_dense_layers=0, layer_kinds=("latent",) * 3),
+     "periods"),
+])
+def test_a_latent_configuration_that_cannot_be_is_refused(change, match):
+    with pytest.raises(ValueError, match=match):
+        dataclasses.replace(llama.tiny_latent(), **change)
+
+
+def test_latent_attention_over_a_sequence_mesh_raises(latent):
+    cfg, params = latent
+    mesh = build_mesh(MeshPlan(axes={AXIS_DATA: 1, AXIS_SEQ: 2}),
+                      jax.devices()[:2])
+    with pytest.raises(ValueError, match="ring"):
+        llama.apply(cfg, params, TOKENS, mesh=mesh)
+
+
+# tiny_latent through Trainer against the plain reference
+# (benchmark/reference/deepseek_v3.py, float32 at ``highest``): the loss and
+# EVERY element of every leaf's gradient, read from Adam's first moment
+# after one step without a clip (mu = (1 - beta1) g exactly), on one device
+# and on four virtual ones.
+
+LATENT_ROWS = jax.random.randint(jax.random.PRNGKey(11), (4, 32), 0, 256)
+
+
+def _latent_conf():
+    with open(os.path.join(_BENCH, "tests", "tiny-kanana-cpu.json")) as f:
+        return json.load(f)
+
+
+def _latent_step(axes, dtype):
+    """(loss, {flat leaf: gradient}) of the program's first step on a mesh
+    of ``axes``, its compute dtype ``dtype``."""
+    import weights
+
+    adapter = importlib.import_module("adapters.deepseek_v3")
+    reference = importlib.import_module("reference.deepseek_v3")
+    conf = _latent_conf()
+    cfg = adapter.config(dict(conf, assumed=dict(
+        conf["assumed"], compute_dtype=dtype)))
+    n = int(np.prod(list(axes.values())))
+    mesh = build_mesh(MeshPlan(axes=axes), jax.devices()[:n])
+    trainer = Trainer(
+        adapter.loss_fn(cfg, mesh), adapter.logical_axes(cfg), mesh,
+        TrainerConfig(learning_rate=1e-3, beta1=0.9, grad_clip_norm=0.0))
+    flat = weights.draw(reference.param_shapes(conf), weights.seed_key(7))
+    state = trainer.init_state(adapter.to_tree(flat))
+    state, metrics = trainer.train_step(
+        state, make_global_batch(mesh, {"tokens": np.asarray(LATENT_ROWS)}))
+    moments = [n.mu for n in jax.tree.leaves(
+        state.opt_state, is_leaf=lambda n: hasattr(n, "mu"))
+        if hasattr(n, "mu")]
+    mu = adapter.to_flat(moments[0])
+    return float(metrics["loss"]), {k: np.asarray(v) / (1 - 0.9)
+                                    for k, v in mu.items()}
+
+
+@pytest.fixture(scope="module")
+def latent_reference():
+    import weights
+
+    reference = importlib.import_module("reference.deepseek_v3")
+    conf = _latent_conf()
+    flat = weights.draw(reference.param_shapes(conf), weights.seed_key(7))
+    loss, grads = jax.value_and_grad(
+        lambda p: reference.loss(conf, p, {"tokens": LATENT_ROWS}))(flat)
+    return float(loss), {k: np.asarray(v) for k, v in grads.items()}
+
+
+def _worst_leaf(got, want):
+    """The widest gap of any element of any leaf, against the leaf's
+    largest element."""
+    return max(float(np.max(np.abs(got[k] - want[k])) / np.max(np.abs(want[k])))
+               for k in want)
+
+
+def test_the_preset_is_the_tiny_configurations_shape():
+    adapter = importlib.import_module("adapters.deepseek_v3")
+    assert adapter.config(_latent_conf()) == dataclasses.replace(
+        llama.tiny_latent(), remat_layers=True)
+
+
+@pytest.mark.parametrize("axes", [
+    {AXIS_DATA: 1}, {AXIS_FSDP: 4}, {AXIS_FSDP: 2, AXIS_TENSOR: 2}],
+    ids=["one", "fsdp4", "fsdp2-tensor2"])
+def test_the_latent_step_is_the_plain_references(latent_reference, axes):
+    """Float32 on both sides: half-split rotation over permuted columns
+    against interleaved pairs, the flash path's chunked form against blocks
+    of queries, the sort and the grouped products against a loop over the
+    experts; over four devices the kernels' and the experts' ``shard_map``
+    and the partitioner's collectives besides. 2e-5 of a leaf's largest
+    element is some twenty times what they read (1e-6) and a five-hundredth
+    of what bf16 reads below."""
+    want_loss, want = latent_reference
+    loss, got = _latent_step(axes, "float32")
+    assert set(got) == set(want)
+    assert abs(loss - want_loss) / want_loss < 2e-6
+    assert _worst_leaf(got, want) < 2e-5
+
+
+def test_bf16_in_the_latent_programs_place_fails_it(latent_reference):
+    want_loss, want = latent_reference
+    loss, got = _latent_step({AXIS_DATA: 1}, "bfloat16")
+    assert abs(loss - want_loss) / want_loss > 2e-6
+    assert _worst_leaf(got, want) > 1e-2

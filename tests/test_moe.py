@@ -480,3 +480,142 @@ def test_bounded_and_poisoned_the_ungated_layer_is_the_same(
     assert float(jnp.max(jnp.abs(want[leaf]))) > 0
     assert bool(jnp.all(jnp.isfinite(got[leaf])))
     np.testing.assert_allclose(got[leaf], want[leaf], atol=5e-5, rtol=2e-4)
+
+
+# -- gated experts beside a gated shared expert: the latent-attention model's --
+
+E3, K3, SCALE3, F3 = 128, 6, 2.448, 24
+
+
+@pytest.fixture(scope="module")
+def whole_gated_sigmoid():
+    """All 128 published experts held, gated, sigmoid scores with a
+    correction bias, beside a gated shared expert twice an expert's width."""
+    p = moe.init(jax.random.PRNGKey(14), d_model=D, d_expert=F3,
+                 n_experts=E3, n_held=E3, gated=True, d_shared=2 * F3,
+                 score_bias=True)
+    p["router"]["bias"] = 0.05 * jax.random.normal(
+        jax.random.PRNGKey(15), (E3,))
+    x = jax.random.normal(jax.random.PRNGKey(16), (2, 24, D), jnp.float32)
+    return p, x
+
+
+def _reference_layer(p, x, first=0, count=E3, shared=True):
+    """The uncut layer by the benchmark's plain reference
+    (benchmark/reference/deepseek_v3.py: float32, a loop over the experts):
+    experts ``first`` .. ``first + count``, with the shared expert or
+    without."""
+    import importlib
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    reference = importlib.import_module("reference.deepseek_v3")
+    conf = {"n_group": 1, "topk_group": 1, "num_experts_per_tok": K3,
+            "norm_topk_prob": True, "routed_scaling_factor": SCALE3,
+            "stands_for": {"experts_held": {"first": 0}}}
+    w = {"router": p["router"]["w"], **{
+        n: p[n]["w"][first:first + count]
+        for n in ("w_gate", "w_up", "w_down")}}
+    cast = lambda a: a
+    out = reference.routed_experts(conf, x, w, p["router"]["bias"], cast,
+                                   first=first)
+    if shared:
+        out = out + reference.swiglu(
+            x, p["shared_gate"]["w"], p["shared_up"]["w"],
+            p["shared_down"]["w"], cast)
+    return out
+
+
+def _share3(p, first, count, shared=True):
+    out = {"router": p["router"], **{
+        n: {"w": p[n]["w"][first:first + count]}
+        for n in ("w_gate", "w_up", "w_down")}}
+    if shared:
+        out.update({n: p[n] for n in
+                    ("shared_gate", "shared_up", "shared_down")})
+    return out
+
+
+def test_a_shared_expert_takes_the_routed_experts_form():
+    gated = moe.init(jax.random.PRNGKey(0), d_model=D, d_expert=F3,
+                     n_experts=8, n_held=4, gated=True, d_shared=40)
+    ungated = moe.init(jax.random.PRNGKey(0), d_model=D, d_expert=F3,
+                       n_experts=8, n_held=4, gated=False, d_shared=40)
+    assert gated["shared_gate"]["w"].shape == (D, 40)
+    assert "shared_gate" not in ungated and "w_gate" not in ungated
+    # the leaves both forms have are the same draw
+    for name in ("shared_up", "shared_down", "router"):
+        np.testing.assert_array_equal(gated[name]["w"], ungated[name]["w"])
+    assert set(moe.logical_axes(gated=True, shared=True)) - set(
+        moe.logical_axes(gated=False, shared=True)) == {
+            "w_gate", "shared_gate"}
+    assert "shared_gate" not in moe.logical_axes(gated=True, shared=False)
+
+
+def test_the_gated_layer_with_its_gated_shared_expert_is_the_plain_reference(
+        whole_gated_sigmoid):
+    p, x = whole_gated_sigmoid
+    apply = lambda p, x: moe.apply(p, x, experts_per_token=K3,
+                                   router_scale=SCALE3, **F32)[0]
+    np.testing.assert_allclose(apply(p, x), _reference_layer(p, x),
+                               atol=2e-5, rtol=2e-5)
+    cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    got = jax.grad(lambda p, x: jnp.sum(apply(p, x) * cot), (0, 1))(p, x)
+    want = jax.grad(
+        lambda p, x: jnp.sum(_reference_layer(p, x) * cot), (0, 1))(p, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=2e-4)
+    assert float(jnp.max(jnp.abs(got[0]["shared_gate"]["w"]))) > 1e-3
+    assert not np.any(np.asarray(got[0]["router"]["bias"]))
+
+
+def test_eight_shares_and_the_gated_shared_expert_once_add_up(
+        whole_gated_sigmoid):
+    """The guide's test of the cut, for the eight-way deployment: eight
+    shares of 16 experts, each computed by the layer as a one-chip share,
+    with what every chip computes alike, the gated shared expert, counted
+    once, sum to what the uncut plain reference gives for the whole layer;
+    so do their counts."""
+    p, x = whole_gated_sigmoid
+    each = E3 // 8
+    total, held = 0.0, 0.0
+    for first in range(0, E3, each):
+        y, counters = moe.apply(
+            _share3(p, first, each, shared=False), x, first_expert=first,
+            experts_per_token=K3, router_scale=SCALE3, **F32)
+        np.testing.assert_allclose(
+            y, _reference_layer(p, x, first, each, shared=False),
+            atol=1e-5, rtol=1e-5)
+        total, held = total + y, held + float(counters[moe.ASSIGNMENTS_HELD])
+    shared = _reference_layer(p, x, 0, 0) - _reference_layer(
+        p, x, 0, 0, shared=False)
+    total = total + shared
+    np.testing.assert_allclose(total, _reference_layer(p, x), atol=3e-5,
+                               rtol=3e-5)
+    whole, _ = moe.apply(p, x, experts_per_token=K3, router_scale=SCALE3,
+                         **F32)
+    np.testing.assert_allclose(total, whole, atol=3e-5, rtol=3e-5)
+    assert held == 2 * 24 * K3
+    # one share with the shared expert is that share plus the expert, once
+    one, _ = moe.apply(_share3(p, 16, each), x, first_expert=16,
+                       experts_per_token=K3, router_scale=SCALE3, **F32)
+    np.testing.assert_allclose(
+        one, _reference_layer(p, x, 16, each), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("axes", [{AXIS_EXPERT: 4}, {AXIS_DATA: 2,
+                                                     AXIS_EXPERT: 2}])
+def test_over_a_mesh_the_gated_shared_expert_is_added_once(
+        whole_gated_sigmoid, axes):
+    p, x = whole_gated_sigmoid
+    n = int(np.prod(list(axes.values())))
+    mesh = build_mesh(MeshPlan(axes=axes), jax.devices()[:n])
+    y, _ = jax.jit(lambda p, x: moe.apply(
+        p, x, experts_per_token=K3, router_scale=SCALE3, mesh=mesh,
+        **F32))(p, x)
+    np.testing.assert_allclose(y, _reference_layer(p, x), atol=3e-5,
+                               rtol=3e-5)

@@ -147,3 +147,51 @@ def test_banded_kernels_at_the_mellum_shape_match_reference():
             np.asarray(b, np.float32) / scale,
             atol=5e-2, rtol=5e-2, err_msg=name,
         )
+
+
+def test_latent_kernels_at_the_kanana_shape_match_reference():
+    """The shape ``kanana2.steady-8k`` runs on a chip: b 2, 32 heads each
+    with keys of its own, T 8192, keys and queries 192 wide (one and a half
+    lane tiles: a whole-width block), values, the output and its cotangent
+    128 — the forward and the three gradients of the compiled kernels at
+    their 1024 x 1024 tiles against the chunked form in float32 at
+    ``highest`` on the same bf16 numbers."""
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(kq, (2, 8192, 32, 192), jnp.bfloat16)
+    k = jax.random.normal(kk, (2, 8192, 32, 192), jnp.bfloat16)
+    v = jax.random.normal(kv, (2, 8192, 32, 128), jnp.bfloat16)
+    f32 = lambda *a: [x.astype(jnp.float32) for x in a]
+
+    def ref(q_, k_, v_):
+        with jax.default_matmul_precision("highest"):
+            return chunked_reference(*f32(q_, k_, v_), causal=True)
+
+    got = jax.jit(lambda *a: flash_attention(*a, causal=True))(q, k, v)
+    assert got.shape == (2, 8192, 32, 128) and got.dtype == jnp.bfloat16
+    want = jax.jit(ref)(q, k, v)
+    # the output is rounded to bf16 (2 ** -9 of values up to ~4) and the
+    # probabilities are rounded to bf16 before they meet the values: 3e-2,
+    # the equal-size tests' tolerance, is ten times that
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               atol=3e-2, rtol=3e-2)
+
+    def sq(fn):
+        return lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_).astype(jnp.float32) ** 2)
+
+    g1 = jax.jit(jax.grad(sq(lambda *a: flash_attention(*a, causal=True)),
+                          argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(sq(ref), argnums=(0, 1, 2)))(q, k, v)
+    for name, a, b, width in zip(("dq", "dk", "dv"), g1, g2,
+                                 (192, 192, 128)):
+        assert a.shape[-1] == width and a.dtype == jnp.bfloat16, name
+        # against the leaf's largest element, as the other shapes' tests:
+        # ds and p are rounded to bf16 before dq, dk and dv's products sum
+        # them over up to 8192 positions, and the result is rounded again
+        scale = max(1.0, float(jnp.max(jnp.abs(b))))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32) / scale, np.asarray(b) / scale,
+            atol=5e-2, rtol=5e-2, err_msg=name)
+        # and not by luck of a loose tolerance: the two agree in the mean
+        # to a hundredth of the leaf's root mean square
+        gap = float(jnp.sqrt(jnp.mean((a.astype(jnp.float32) - b) ** 2)))
+        assert gap < 2e-2 * float(jnp.sqrt(jnp.mean(b ** 2))), name

@@ -225,3 +225,31 @@ def test_the_cells_row_passes_are_their_expressions_and_faster(name, width):
                                    err_msg=name)
     assert quarter_ms < 0.5 * fused_ms, name
     assert all_ms < 1.05 * fused_ms, name
+
+
+def test_the_gated_shared_expert_at_the_kanana_shape():
+    """``kanana2.steady-8k``'s shared expert, 2048 -> 1536 -> 2048 over
+    16,384 tokens, gated (``shared_gate`` in the tree), compiled in bf16
+    against the same expression in float32 at ``highest``; and added once to
+    a share's routed result."""
+    d, f = 2048, 1536
+    p = moe.init(jax.random.PRNGKey(21), d_model=d, d_expert=768,
+                 n_experts=128, n_held=16, gated=True, d_shared=f,
+                 score_bias=True)
+    x = jax.random.normal(jax.random.PRNGKey(22), (2, 8192, d), jnp.float32)
+    got = jax.jit(lambda p, x: moe._shared(p, x, jnp.bfloat16))(p, x)
+    assert got.shape == x.shape and got.dtype == jnp.bfloat16
+    with jax.default_matmul_precision("highest"):
+        want = (jax.nn.silu(x @ p["shared_gate"]["w"])
+                * (x @ p["shared_up"]["w"])) @ p["shared_down"]["w"]
+    # three products on bf16 operands (2 ** -9 each) and two roundings of
+    # the result: the gap is a few thousandths of the result's size
+    gap = float(jnp.sqrt(jnp.mean((got.astype(jnp.float32) - want) ** 2)))
+    size = float(jnp.sqrt(jnp.mean(want ** 2)))
+    assert gap < 1e-2 * size, (gap, size)
+    both = lambda p: jax.jit(lambda p, x: moe.apply(
+        p, x, experts_per_token=6, router_scale=2.448)[0])(p, x)
+    routed = {n: v for n, v in p.items() if not n.startswith("shared_")}
+    added = both(p).astype(jnp.float32) - both(routed).astype(jnp.float32)
+    gap = float(jnp.sqrt(jnp.mean((added - want) ** 2)))
+    assert gap < 2e-2 * size, (gap, size)

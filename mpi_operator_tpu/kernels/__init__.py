@@ -12,15 +12,19 @@ the compiler's fusion runs over every row the static shape has).
 scan as two kernels with a custom backward (a chunk's decays, ``C B^T`` and
 the states never leave VMEM) on a TPU where the shapes tile, ``jax.numpy``
 products the compiler lowers elsewhere; the convolution shifted
-multiply-adds.
+multiply-adds. ``ssm_conv_gate`` is those layers' two memory-bound passes
+(the convolution with its silu, the gate with its grouped RMS norm) as
+row-tiled kernels with custom backward passes, one read of each operand in
+its own dtype, on a TPU where the widths tile; the ``jax.numpy`` passes
+elsewhere.
 Written per /opt/skills/guides/pallas_guide.md; every kernel has an
 interpret-mode path so the CPU test suite checks numerics.
 """
 
-from mpi_operator_tpu.kernels import ssd
+from mpi_operator_tpu.kernels import ssd, ssm_conv_gate
 from mpi_operator_tpu.kernels.flash_attention import flash_attention
 from mpi_operator_tpu.kernels.grouped_matmul import grouped_matmul
 from mpi_operator_tpu.kernels.quant_matmul import quant_matmul, quant_ragged_dot
 
 __all__ = ["flash_attention", "grouped_matmul", "quant_matmul",
-           "quant_ragged_dot", "ssd"]
+           "quant_ragged_dot", "ssd", "ssm_conv_gate"]

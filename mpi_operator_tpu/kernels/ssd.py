@@ -1,5 +1,7 @@
 """Mamba-2's state-space scan in its chunked form (SSD, arXiv:2405.21060),
-and the short causal convolution in front of it.
+and the short causal convolution in front of it as ``jax.numpy`` passes
+(``causal_conv``: what runs off a TPU and the yardstick of the kernel that
+runs on one, kernels/ssm_conv_gate.py).
 
 The function, per head with a state ``S`` [P, N] that starts at nought:
 
@@ -633,7 +635,10 @@ def causal_conv(x, w, bias):
     ``y[t, c] = bias[c] + sum_k w[c, k] x[t - (K - 1) + k, c]`` with nought
     before the sequence's start. x [B, T, C], w [C, K], bias [C]. K shifted
     multiply-adds (K is 4: one fused pass over ``x``), in float32 and
-    returned so: what follows it rounds."""
+    returned so: what follows it rounds. The ``jax.numpy`` form of
+    ``ssm_conv_gate.conv_silu``, which calls it wherever its kernels do not
+    run: ``x`` padded and widened in HBM, autodiff's backward a pass a
+    tap."""
     k = w.shape[1]
     t = x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), [(0, 0), (k - 1, 0), (0, 0)])

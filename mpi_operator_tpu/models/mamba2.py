@@ -3,15 +3,27 @@ kind (models/llama.py calls it on the normed activations and adds the
 residual itself).
 
     [z | xBC | dt] = u . W_in          widths  H P | H P + 2 G N | H
-    xBC = silu(causal_conv(xBC) + bias)            (kernels/ssd.py)
+    xBC = silu(causal_conv(xBC) + bias)    the short convolution, each
+         channel alone over K positions (kernels/ssm_conv_gate.py:
+         ``conv_silu``)
     x, B, C = split(xBC)               [T, H, P], [T, G, N], [T, G, N]
     dt = softplus(dt + dt_bias)   A = -exp(A_log)             float32
-    y  = scan(x, dt, A, B, C) + D x    the chunked scan (kernels/ssd.py:
-         Pallas kernels ``ssd_fwd`` / ``ssd_bwd`` on a TPU at shapes that
-         tile, ``jax.numpy`` products elsewhere; the choice is the scan's)
+    y  = scan(x, dt, A, B, C) + D x    the chunked scan (kernels/ssd.py)
     g  = y silu(z), RMS-normalised over each of the G groups of channels
-         alone, times a weight                       (gate before the norm)
+         alone, times a weight          (gate before the norm;
+         kernels/ssm_conv_gate.py: ``gate_norm``)
     out = g . W_out
+
+The two projections are the compiler's products. The three passes between
+them each have two forms and choose between them themselves, from what the
+call observes (no flag): on a TPU at shapes that tile, Pallas kernels with
+hand-written backward passes (``ssm_conv_fwd`` / ``ssm_conv_bwd``,
+``ssd_fwd`` / ``ssd_bwd``, ``ssm_gate_fwd`` / ``ssm_gate_bwd`` in the
+trace: the convolution and the gated norm are memory-bound and read each
+operand once, in bf16, where the compiler's passes widen, pad and reshape
+in HBM; the scan keeps a chunk's decays in VMEM), under ``shard_map`` on a
+mesh of more than one device; ``jax.numpy`` forms anywhere else, the CPU
+suite included.
 
 No bias but the convolution's. Scopes in the device trace, inside the
 decoder's ``mamba``: ``ssm_in_proj``, ``ssm_conv``, ``ssm_scan``,
@@ -28,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mpi_operator_tpu.kernels import ssd
+from mpi_operator_tpu.kernels import ssd, ssm_conv_gate
 
 Params = Dict[str, Any]
 
@@ -78,30 +90,29 @@ def logical_axes() -> Params:
     }
 
 
-def _gate_norm(y, z, scale, groups: int, eps: float):
-    """``y silu(z)``, then RMS-normalised over each group of channels."""
-    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    grouped = g.reshape(*g.shape[:-1], groups, -1)
-    grouped = grouped * lax.rsqrt(
-        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + eps)
-    return (grouped.reshape(g.shape) * scale).astype(y.dtype)
-
-
 def apply(c, lp: Params, u, *, mesh=None):
     """u [B, T, D], normed -> (the mixer's result [B, T, D], counters).
-    ``mesh`` goes to the scan, whose kernels the compiler cannot partition
-    (kernels/ssd.py): every other product here is the compiler's."""
+    ``mesh`` goes to the convolution, the scan and the gated norm, whose
+    kernels the compiler cannot partition (kernels/ssd.py,
+    kernels/ssm_conv_gate.py): the projections are the compiler's."""
     dt_ = u.dtype
     bsz, t, _ = u.shape
     h, p, g, n = c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state
     inner, conv, _ = widths(c)
     with jax.named_scope("ssm_in_proj"):
         zxbcdt = u @ lp["in_proj"]["w"].astype(dt_)
-        z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv], axis=-1)
+        dt = zxbcdt[..., inner + conv:]
     with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(ssd.causal_conv(
-            xbc, lp["conv"]["w"], lp["conv"]["b"])).astype(dt_)
-        x, b, cm = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+        # x, B and C each convolved where they lie in the projection's
+        # result, to an array of its own: a slice in front of a kernel and
+        # a split behind it would each be a copy (8 and 4 ms a step in
+        # nemotron3nano.steady-8k: PERF.md section 6, PR 37)
+        x, b, cm = (
+            ssm_conv_gate.conv_silu(
+                zxbcdt, lp["conv"]["w"][at:at + width],
+                lp["conv"]["b"][at:at + width], first=inner + at, mesh=mesh)
+            for at, width in ((0, inner), (inner, g * n),
+                              (inner + g * n, g * n)))
     with jax.named_scope("ssm_scan"):
         dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
         a = -jnp.exp(lp["A_log"].astype(jnp.float32))
@@ -112,7 +123,9 @@ def apply(c, lp: Params, u, *, mesh=None):
         counters = {CARRY_SHARE: lax.stop_gradient(
             ssd.carry_share(dt, a, chunk=c.ssm_chunk))}
     with jax.named_scope("ssm_gate_norm"):
-        gated = _gate_norm(y.reshape(bsz, t, inner), z,
-                           lp["gate_norm"]["scale"], g, c.norm_eps)
+        # z where it lies: the projection's first columns
+        gated = ssm_conv_gate.gate_norm(
+            y.reshape(bsz, t, inner), zxbcdt, lp["gate_norm"]["scale"],
+            groups=g, eps=c.norm_eps, mesh=mesh)
     with jax.named_scope("ssm_out_proj"):
         return gated @ lp["out_proj"]["w"].astype(dt_), counters

@@ -1,9 +1,9 @@
 """kernels/grouped_matmul.py: the three kernels' bodies under the Pallas
 interpreter, at sizes the MXU tiles, against ``lax.ragged_dot`` and its
 ``jax.vjp``. The chip's half is tests_tpu/test_moe_on_tpu.py. The compiles
-for a described chip at the end take in kernels/row_map.py's maps and
-kernels/ssd.py's scan too: one file holds every test that loads the TPU's
-compiler."""
+for a described chip at the end take in kernels/row_map.py's maps,
+kernels/ssd.py's scan and kernels/ssm_conv_gate.py's two passes too: one
+file holds every test that loads the TPU's compiler."""
 
 import importlib
 import os
@@ -416,3 +416,84 @@ def test_the_cells_scan_compiles_for_four_v5e_chips_under_shard_map(topo):
     decays = bsz * (t // chunk) * h * chunk * chunk * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * decays // 4
 
+
+
+def _the_cells_two_passes(mesh, bsz=2):
+    """Value and gradients of ``nemotron3nano.steady-8k``'s convolution
+    (rows of 8192; ``x`` 4096, ``B`` and ``C`` 1024 channels each, K = 4)
+    and gated norm (4096 channels in 8 groups) through their kernels
+    (kernels/ssm_conv_gate.py), each operand read where it lies in the
+    input projection's result, 10,304 wide (models/mamba2.py), under a
+    layer's checkpoint; and the shapes they take."""
+    from mpi_operator_tpu.kernels import ssm_conv_gate as scg
+    t, inner, state, k, groups = 8192, 4096, 1024, 4, 8
+    conv, proj = inner + 2 * state, 2 * inner + 2 * state + 64
+    parts = ((0, inner), (inner, state), (inner + state, state))
+    assert all(scg.conv_tileable(t, width, k, inner + at)
+               for at, width in parts)
+    assert scg.gate_tileable(bsz * t, inner, groups)
+
+    def value_and_grads(zxbcdt, w, bias, y, scale):
+        def layer(zxbcdt, w, bias, y, scale):
+            x, b, c = (scg.conv_silu(
+                zxbcdt, w[at:at + width], bias[at:at + width],
+                first=inner + at, interpret=False, mesh=mesh)
+                for at, width in parts)
+            gated = scg.gate_norm(y, zxbcdt, scale, groups=groups, eps=1e-5,
+                                  interpret=False, mesh=mesh)
+            return sum(jnp.sum(v.astype(jnp.float32) ** 2)
+                       for v in (x, b, c, gated))
+        return jax.value_and_grad(jax.checkpoint(layer), argnums=range(5))(
+            zxbcdt, w, bias, y, scale)
+
+    f32 = jnp.float32
+    return value_and_grads, (
+        ((bsz, t, proj), jnp.bfloat16), ((conv, k), f32), ((conv,), f32),
+        ((bsz, t, inner), jnp.bfloat16), ((inner,), f32))
+
+
+def test_the_cells_convolution_and_gated_norm_compile_for_a_v5e(one_chip):
+    """Twelve Mosaic calls under the names the trace is read by (forward,
+    replay and backward; the convolution's once each for ``x``, ``B`` and
+    ``C``), no copy of a
+    slice of the projection's result in front of them, and beside the
+    operands and results nothing but the four results kept for the loss's
+    cotangents and those, in bf16: nothing padded, widened to float32 or
+    reshaped to groups in HBM (one float32 copy of the convolved channels
+    alone is 403 MB)."""
+    value_and_grads, shapes = _the_cells_two_passes(None)
+    compiled = jax.jit(value_and_grads).lower(*(
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+        for s, d in shapes)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 12
+    for name in ("ssm_conv_fwd", "ssm_conv_bwd", "ssm_gate_fwd",
+                 "ssm_gate_bwd"):
+        assert re.search(name + r"_*\.\d", text), name
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(
+        r"bf16\[2,8192,(4096|6144|1024)\]\S* (slice|copy)\(", entry)
+    wide = lambda shape: 2 * int(np.prod(shape))  # bytes in bf16
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < 1.05 * 2 * (wide((2, 8192, 6144)) + wide(shapes[3][0]))
+
+
+def test_the_cells_two_passes_compile_for_four_v5e_chips_under_shard_map(
+        topo):
+    """On a mesh of four (rows over ``fsdp`` and ``data``) the kernels are
+    a device's own, one row of 8192 with every channel, and nothing is
+    gathered around them: no collective but the sums of the weights',
+    bias's and scale's gradients over the rows' axes."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "fsdp"))
+    value_and_grads, shapes = _the_cells_two_passes(mesh, bsz=4)
+    rows = P(("data", "fsdp"))
+    compiled = jax.jit(value_and_grads).lower(*(
+        jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(
+            mesh, rows if len(s) == 3 else P()))
+        for s, d in shapes)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 12
+    assert re.search(r"bf16\[1,8192,10304\]", text)  # a device's share
+    assert not re.search(r"bf16\[4,8192,(10304|4096)\]", text)
+    assert "all-gather" not in text and "all-to-all" not in text

@@ -13,7 +13,17 @@ heads that carry state over all eight; with the fault planted (states not
 passed) failing; both forms timed at the cell's 2 x 8192 with the layer's
 skip, forward and forward with backward; and, where the host has four
 chips, the kernels under ``shard_map`` on a 2 x 2 mesh against one chip's,
-alone and inside a Mamba layer's loss."""
+alone and inside a Mamba layer's loss.
+
+And the layer's two memory-bound passes (kernels/ssm_conv_gate.py) at the
+cell's own shapes, 2 x 8192 by 6144 channels with K = 4 and by 4096
+channels in 8 groups: the compiled kernels against the float32
+``jax.numpy`` forms, values and all six cotangents, in bf16 (beside the
+bf16 passes' own error) and in float32, each operand read where it lies in
+the input projection's 10,304-wide result (their milliseconds are the
+step's trace's to read: a call of under a millisecond is not the host
+clock's); and on four chips under ``shard_map`` with the rows over
+``fsdp``."""
 
 import dataclasses
 import time
@@ -24,6 +34,7 @@ import numpy as np
 import pytest
 
 from mpi_operator_tpu.kernels import ssd
+from mpi_operator_tpu.kernels import ssm_conv_gate as scg
 from tests.test_ssd import recurrence
 
 B, T, H, P, G, N, CHUNK = 2, 1024, 64, 64, 8, 128, 128
@@ -247,10 +258,14 @@ def test_on_four_chips_the_kernels_run_under_shard_map_to_one_chips_answer():
           f"2 x 2 chips {timed(sharded, *spread):.2f} ms")
 
 
-def test_on_four_chips_a_mamba_layers_loss_is_one_chips():
-    """The decoder on a mesh (``fsdp`` 2, ``tensor`` 2): a Mamba layer's
-    projections partitioned by the compiler, its scan's kernels under
-    ``shard_map``; loss and gradient norm against the same model without a
+@pytest.mark.parametrize("axes,kernels_of", [
+    ({"fsdp": 2, "tensor": 2}, ("ssd",)),
+    ({"data": 2, "fsdp": 2}, ("ssd", "ssm_conv", "ssm_gate"))])
+def test_on_four_chips_a_mamba_layers_loss_is_one_chips(axes, kernels_of):
+    """The decoder on a mesh: a Mamba layer's projections partitioned by
+    the compiler, its scan's kernels under ``shard_map`` and, where no
+    ``tensor`` axis splits the channels, the convolution's and the gated
+    norm's too; loss and gradient norm against the same model without a
     mesh on one chip."""
     from mpi_operator_tpu.models import llama
     from mpi_operator_tpu.parallel.sharding import named_sharding
@@ -259,7 +274,7 @@ def test_on_four_chips_a_mamba_layers_loss_is_one_chips():
         llama.tiny_hybrid(), d_model=256, n_layers=2,
         layer_kinds=("mamba", "mamba"), ssm_heads=16, ssm_head_dim=64,
         ssm_groups=4, ssm_state=128, ssm_chunk=128)
-    mesh = build_mesh(MeshPlan(axes={"fsdp": 2, "tensor": 2}), _four_chips())
+    mesh = build_mesh(MeshPlan(axes=axes), _four_chips())
     params = llama.init(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 1024), 0, cfg.vocab)
 
@@ -279,10 +294,136 @@ def test_on_four_chips_a_mamba_layers_loss_is_one_chips():
         is_leaf=lambda v: isinstance(v, tuple))
     sharded = value_and_norm(mesh)
     text = sharded.lower(spread, tokens).compile().as_text()
-    assert "ssd_fwd" in text and "ssd_bwd" in text
+    for name in ("ssd", "ssm_conv", "ssm_gate"):
+        assert (name + "_fwd" in text) == (name in kernels_of), name
+        assert (name + "_bwd" in text) == (name in kernels_of), name
     got = sharded(spread, tokens)
     print(f"a Mamba layer's loss and gradient norm, one chip {want}, "
-          f"2 x 2 chips {got}")
+          f"{axes} {got}")
     np.testing.assert_allclose(got[0], want[0], rtol=2e-3)
     np.testing.assert_allclose(got[1], want[1], rtol=2e-2)
 
+
+
+# ---- the convolution with its silu, the gate with its grouped norm ----
+
+CONV_SHAPE, K = (2, 8192, 6144), 4
+GATE_SHAPE, GROUPS, EPS = (2, 8192, 4096), 8, 1e-5
+# the input projection's result as the layer has it: z, the convolved
+# channels, dt. The kernels read their columns where they lie in it
+PROJ_SHAPE, CONV_FIRST = (2, 8192, 4096 + 6144 + 64), 4096
+CONV_NAMES = ("y", "dx", "dw", "dbias")
+GATE_NAMES = ("out", "dy", "dz", "dscale")
+
+
+def _conv_kernels(proj, w, bias, **how):
+    return scg.conv_silu(proj, w, bias, first=CONV_FIRST, **how)
+
+
+def _conv_passes(proj, w, bias):
+    x = proj[..., CONV_FIRST:CONV_FIRST + CONV_SHAPE[2]]
+    return jax.nn.silu(ssd.causal_conv(x, w, bias)).astype(x.dtype)
+
+
+def _gate_kernels(y, proj, scale, **how):
+    return scg.gate_norm(y, proj, scale, groups=GROUPS, eps=EPS, **how)
+
+
+def _gate_passes(y, proj, scale):
+    return scg._gate_norm_passes(y, proj[..., :GATE_SHAPE[2]], scale, GROUPS,
+                                 EPS)
+
+
+def _two_passes(rows=2, seed=21):
+    """{pass: (kernels, passes, wide operands bf16-exact in float32, the
+    weights, the cotangent, the results' names)} at the cell's widths."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 9)
+    exact = lambda key, shape: jax.random.normal(
+        key, (rows, *shape[1:])).astype(jnp.bfloat16).astype(jnp.float32)
+    c = CONV_SHAPE[2]
+    return {
+        "conv": (_conv_kernels, _conv_passes, (exact(ks[0], PROJ_SHAPE),),
+                 (jax.random.normal(ks[1], (c, K)) * K ** -0.5,
+                  0.3 * jax.random.normal(ks[2], (c,))),
+                 exact(ks[3], CONV_SHAPE), CONV_NAMES),
+        "gate": (_gate_kernels, _gate_passes,
+                 (exact(ks[4], GATE_SHAPE), exact(ks[5], PROJ_SHAPE)),
+                 (1.0 + 0.2 * jax.random.normal(ks[6], (GATE_SHAPE[2],)),),
+                 exact(ks[7], GATE_SHAPE), GATE_NAMES)}
+
+
+def _value_and_cotangents(f, dtype=None):
+    """jitted: (wide operands, weights, cotangent) -> (value, cotangents),
+    the wide ones cast to ``dtype`` first."""
+    def run(wide, weights, ct):
+        if dtype is not None:
+            wide = tuple(v.astype(dtype) for v in wide)
+        value, pull = jax.vjp(f, *wide, *weights)
+        return (value, *pull(ct.astype(value.dtype)))
+    return jax.jit(run)
+
+
+def _gaps(got, want):
+    return [_rms(g.astype(jnp.float32) - w) / _rms(w)
+            for g, w in zip(got, want)]
+
+
+@pytest.mark.parametrize("pass_", ("conv", "gate"))
+def test_the_two_passes_kernels_are_the_float32_passes_at_the_cells_shapes(
+        pass_):
+    """Compiled, at 2 x 8192: in bf16 as the model gives the operands, each
+    result within a rounding of the float32 ``jax.numpy`` passes on the same
+    numbers and no further than the bf16 passes' own (``TIE``); in float32
+    to float32's own level. All eight results, all six cotangents."""
+    kernels, passes, wide, weights, ct, names = _two_passes()[pass_]
+    text = _value_and_cotangents(kernels, jnp.bfloat16).lower(
+        wide, weights, ct).as_text()
+    assert f"ssm_{pass_}_fwd" in text and f"ssm_{pass_}_bwd" in text
+    want = _value_and_cotangents(passes)(wide, weights, ct)
+    half = {name: _gaps(_value_and_cotangents(f, jnp.bfloat16)(
+        wide, weights, ct), want)
+        for name, f in (("kernels", kernels), ("passes", passes))}
+    full = _gaps(_value_and_cotangents(kernels)(wide, weights, ct), want)
+    for i, name in enumerate(names):
+        print(f"{pass_} {name}: bf16 kernels {half['kernels'][i]:.3e}, "
+              f"bf16 passes {half['passes'][i]:.3e}, float32 kernels "
+              f"{full[i]:.3e}")
+        assert np.isfinite(half["kernels"][i]), name
+        assert half["kernels"][i] <= TIE * half["passes"][i] + 1e-6, name
+        assert half["kernels"][i] < 4e-3, name  # bf16's rounding
+        assert full[i] < 2e-5, name
+    # the fault: every tile of positions from nought
+    if pass_ == "conv":
+        bad = _gaps(_value_and_cotangents(
+            lambda *v: kernels(*v, halo=False), jnp.bfloat16)(
+            wide, weights, ct), want)
+        print("conv, the halo dropped: " + ", ".join(
+            f"{n} {e:.3e}" for n, e in zip(names, bad)))
+        assert all(b > 5 * e for b, e in zip(bad, half["kernels"])), bad
+
+
+def test_on_four_chips_the_two_passes_run_under_shard_map_over_fsdp():
+    """Four rows of 8192 over ``fsdp`` = 4, a row a chip with every
+    channel: values and cotangents against the same calls without a mesh on
+    one chip. The wide ones are the same kernel on the same numbers; the
+    weights', bias's and scale's are sums made across chips."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(_four_chips()), ("fsdp",))
+    put = lambda v, spec: jax.device_put(v, NamedSharding(mesh, spec))
+    for pass_, (kernels, _p, wide, weights, ct, names) in (
+            _two_passes(rows=4).items()):
+        wide = tuple(v.astype(jnp.bfloat16) for v in wide)
+        sharded = _value_and_cotangents(
+            lambda *v: kernels(*v, mesh=mesh))
+        spread = (tuple(put(v, P("fsdp")) for v in wide),
+                  tuple(put(v, P()) for v in weights), put(ct, P("fsdp")))
+        text = sharded.lower(*spread).compile().as_text()
+        assert f"ssm_{pass_}_fwd" in text and f"ssm_{pass_}_bwd" in text
+        assert f"bf16[1,8192,{wide[0].shape[2]}]" in text  # a chip's share
+        assert "all-gather" not in text
+        want = _value_and_cotangents(kernels)(wide, weights, ct)
+        got = sharded(*spread)
+        for name, gap in zip(names, _gaps(got, [
+                w.astype(jnp.float32) for w in want])):
+            print(f"four chips against one, {pass_} {name}: {gap:.3e}")
+            assert gap < 1e-5, (name, gap)

@@ -432,12 +432,14 @@ SETUP_OVERLAPPED = ("ckpt_import",)
 # and the blob carries under `counters`: the routed feed-forward's
 # (parallel/moe.py) — how many of the step's assignments fell to experts
 # held here (mean a layer), the fullest held expert over the mean (worst
-# layer), assignments that found no row (0: the layer is dropless); and the
+# layer), assignments that found no row (0: the layer is dropless), the
+# rows its two gathers in token order read (mean a layer); and the
 # state-space layers' (models/mamba2.py) — the share of the scan's (row,
 # chunk, head) whose decay over the whole chunk exceeds 0.1, so that state
 # is handed from chunk to chunk (mean a layer).
 TRAIN_COUNTERS = ("moe.assignments_held", "moe.load_max_over_mean",
-                  "moe.assignments_dropped", "ssm.carry_share")
+                  "moe.assignments_dropped", "moe.token_rows_read",
+                  "ssm.carry_share")
 # the blob's `compiles` (runtime/compile_cache.compile_records, ISSUE 39):
 # the programs with the most compile seconds, the rest summed under one
 # key; the newest compile spans; the incarnation's first step times

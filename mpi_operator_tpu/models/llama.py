@@ -894,10 +894,10 @@ def _step_counters(counters):
     """One scalar a counter for the step, from each layer's: how many
     assignments fell to held experts (mean a layer), the fullest held
     expert over the mean (worst layer), assignments without a row (sum),
-    the share of the scan's chunks that hand state on (mean a layer). Its
-    lines keep their count: every line below is in a kernel's cache key."""
+    rows read in token order, the share of the scan's chunks that hand state
+    on (both mean a layer). Keep its lines: those below are in cache keys."""
     fold = {moe.ASSIGNMENTS_HELD: jnp.mean, moe.LOAD_MAX_OVER_MEAN: jnp.max,
-            moe.ASSIGNMENTS_DROPPED: jnp.sum,
+            moe.ASSIGNMENTS_DROPPED: jnp.sum, moe.TOKEN_ROWS_READ: jnp.mean,
             mamba2.CARRY_SHARE: jnp.mean}
     return {name: fold[name](values) for name, values in counters.items()}
 

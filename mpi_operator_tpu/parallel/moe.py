@@ -51,9 +51,17 @@ whose grid's extent is ``cdiv(H, tile)``, read on the device. Rows past
 that tile are left as the buffer held them, and every reader masks them
 with ``held``. That path is taken where the grouped kernels are (a TPU,
 shapes that tile) and the share leaves some published expert out; anywhere
-else the same expressions run over all R rows in ``jax.numpy``. The other
-three gathers still move all R rows: the dispatch's, and the two in token
-order (the combine's and the dispatch's transpose).
+else the same expressions run over all R rows in ``jax.numpy``.
+
+The two gathers in token order, the combine's weighted sum and the
+dispatch's transpose, read the held rows only on that same path, where the
+rows are bf16 a whole number of lane tiles wide (``kernels.token_sum``:
+``moe_token_sum``). The sort is stable, so a tile of consecutive tokens
+owns one contiguous range of each held group's rows; the kernel copies
+those ranges' 16-row lines by DMA and sums each token's rows in VMEM, the
+weights in float32. Elsewhere each gathers every assignment's row and masks
+the absent ones. One gather still moves all R rows: the dispatch's, in row
+order.
 
 With a mesh that has an ``expert`` axis each member holds its share of
 ``w_gate`` / ``w_up`` / ``w_down`` and the partial results are summed over
@@ -61,9 +69,11 @@ that axis; tokens stay where their batch axes put them. On one chip the
 layer runs without that exchange, and nothing stands in for absent chips.
 
 Scopes in the device trace: ``moe_router``, ``moe_dispatch``,
-``moe_experts``, ``moe_combine``, ``moe_shared``. Counters, as scalars of the step (no
-sync): ``moe.assignments_held``, ``moe.load_max_over_mean``,
-``moe.assignments_dropped`` (0, computed and not assumed).
+``moe_experts``, ``moe_combine``, ``moe_shared``. Counters, as scalars of
+the step (no sync): ``moe.assignments_held``, ``moe.load_max_over_mean``,
+``moe.assignments_dropped`` (0, computed and not assumed),
+``moe.token_rows_read`` (the rows a gather in token order reads: the copied
+lines on the kernel, R elsewhere).
 """
 
 from __future__ import annotations
@@ -76,7 +86,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from mpi_operator_tpu.kernels import row_map
+from mpi_operator_tpu.kernels import row_map, token_sum
 from mpi_operator_tpu.kernels.grouped_matmul import grouped_matmul
 from mpi_operator_tpu.kernels.quant_matmul import quant_ragged_dot
 from mpi_operator_tpu.runtime.topology import AXIS_DATA, AXIS_EXPERT, AXIS_FSDP
@@ -86,6 +96,7 @@ Params = Dict[str, Any]
 ASSIGNMENTS_HELD = "moe.assignments_held"
 LOAD_MAX_OVER_MEAN = "moe.load_max_over_mean"
 ASSIGNMENTS_DROPPED = "moe.assignments_dropped"
+TOKEN_ROWS_READ = "moe.token_rows_read"
 
 
 def init(key, *, d_model: int, d_expert: int, n_experts: int,
@@ -187,22 +198,26 @@ def _take(a, idx):
     return jnp.take(a, idx, axis=0, mode="clip")
 
 
-@jax.custom_vjp
-def _dispatch(x, row_token, pos, held):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _dispatch(x, row_token, pos, held, plan, interpret):
     """xs[r] = x[row_token[r]]."""
     return _take(x, row_token)
 
 
-def _dispatch_fwd(x, row_token, pos, held):
-    return _dispatch(x, row_token, pos, held), (pos, held)
+def _dispatch_fwd(x, row_token, pos, held, plan, interpret):
+    return _dispatch(x, row_token, pos, held, plan, interpret), (pos, held,
+                                                                 plan)
 
 
-def _dispatch_bwd(res, d_xs):
-    pos, held = res
+def _dispatch_bwd(interpret, res, d_xs):
+    pos, held, plan = res
+    if plan is not None:  # each token's held rows, and no others
+        return (token_sum.token_sum(d_xs, pos, plan, interpret=interpret),
+                None, None, None, None)
     rows = _take(d_xs, pos)  # [N, k, D]
     d_x = jnp.sum(jnp.where(held[..., None], rows, 0).astype(jnp.float32),
                   axis=1)
-    return d_x.astype(d_xs.dtype), None, None, None
+    return d_x.astype(d_xs.dtype), None, None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -213,17 +228,21 @@ def _combine_t(d_rows, ys, w_rows):
     return d_rows * w_rows, jnp.sum(d_rows * ys, axis=-1, keepdims=True)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _combine(ys, weights, row_token, row_slot, pos, held, rows, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _combine(ys, weights, row_token, row_slot, pos, held, rows, plan,
+             interpret):
     """y[t] = sum_j weights[t, j] * ys[pos[t, j]] over the held j."""
+    if plan is not None:
+        return token_sum.token_sum(ys, pos, plan, weights,
+                                   interpret=interpret)
     gathered = _take(ys, pos)  # [N, k, D]
     gathered = jnp.where(held[..., None], gathered, 0).astype(jnp.float32)
     return jnp.sum(gathered * weights[..., None], axis=1).astype(ys.dtype)
 
 
-def _combine_fwd(ys, weights, row_token, row_slot, pos, held, rows,
+def _combine_fwd(ys, weights, row_token, row_slot, pos, held, rows, plan,
                  interpret):
-    return (_combine(ys, weights, row_token, row_slot, pos, held, rows,
+    return (_combine(ys, weights, row_token, row_slot, pos, held, rows, plan,
                      interpret),
             (ys, weights, row_token, row_slot, pos, held, rows))
 
@@ -245,7 +264,8 @@ def _combine_bwd(interpret, res, d_y):
         ((ys.shape[1], ys.dtype), (None, jnp.float32)), rows,
         name="moe_combine_t", interpret=interpret)
     d_w = jnp.where(held, _moved(d_w_rows, row_slot).reshape(pos.shape), 0.0)
-    return d_ys, d_w.astype(weights.dtype), None, None, None, None, None
+    return (d_ys, d_w.astype(weights.dtype), None, None, None, None, None,
+            None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -369,7 +389,8 @@ def _row_passes(r: int, d: int, f: int, held_here: int,
 def _share(x, router_in, w, first_expert, *, experts_per_token: int,
            compute_dtype, matmul_precision: str, router_scale: float):
     """One member's part: x [N, D] -> (y [N, D], this share's counts [G],
-    assignments it could not give a row).
+    assignments it could not give a row, rows the gathers in token order
+    read).
     ``w`` holds the router's and the held experts' arrays by name (``bias``
     and ``w_gate`` where the layer has them), ``router_in`` is what the
     router reads (float32), ``first_expert`` may be traced (a mesh
@@ -398,7 +419,13 @@ def _share(x, router_in, w, first_expert, *, experts_per_token: int,
         row_slot = jnp.argsort(key, stable=True).astype(jnp.int32)  # [R]
         pos = jnp.argsort(row_slot).astype(jnp.int32).reshape(n, k)
         row_token = row_slot // k
-        xs = _dispatch(x.astype(dt), row_token, pos, held)  # [R, D]
+        # the gathers in token order read the held rows only where the
+        # passes in row order are bounded and the kernel takes the shapes
+        plan = None
+        if interpret is not None and token_sum.takes(n, d, dt):
+            plan = token_sum.plan(key.reshape(n, k), held_here)
+        xs = _dispatch(x.astype(dt), row_token, pos, held, plan,
+                       interpret)  # [R, D]
         # held assignments past the buffer's last row. The buffer has a row
         # for every assignment (R = N * k), so this reads 0; it is counted
         # from the buffer as built, for the day one is built smaller
@@ -420,18 +447,21 @@ def _share(x, router_in, w, first_expert, *, experts_per_token: int,
 
     with jax.named_scope("moe_combine"):
         y = _combine(ys, weights, row_token, row_slot, pos, held, rows,
-                     interpret)
-    return y, counts, dropped
+                     plan, interpret)
+    read = n * k if plan is None else plan.rows_read
+    return y, counts, dropped, read
 
 
-def _counters(counts, dropped) -> Dict[str, jnp.ndarray]:
-    """From every held expert's count (all shares together)."""
+def _counters(counts, dropped, read) -> Dict[str, jnp.ndarray]:
+    """From every held expert's count (all shares together), and the rows
+    the gathers in token order read (a member's, all tokens together)."""
     total = jnp.sum(counts).astype(jnp.float32)
     mean = jnp.maximum(total / counts.shape[0], 1e-9)
     return {
         ASSIGNMENTS_HELD: total,
         LOAD_MAX_OVER_MEAN: jnp.max(counts).astype(jnp.float32) / mean,
         ASSIGNMENTS_DROPPED: jnp.asarray(dropped, jnp.float32),
+        TOKEN_ROWS_READ: jnp.asarray(read, jnp.float32),
     }
 
 
@@ -495,16 +525,17 @@ def apply(params: Params, x, *, experts_per_token: int, first_expert: int = 0,
             first = first_expert
             if experts_spread:
                 first = first + lax.axis_index(AXIS_EXPERT) * held_here
-            y, counts, dropped = share(
+            y, counts, dropped, read = share(
                 x_.reshape(-1, d), r_.reshape(-1, d), w_, first)
             if batch_axes:  # every token's assignments, wherever its rows are
                 counts = lax.psum(counts, batch_axes)
-                dropped = lax.psum(dropped, batch_axes)
+                dropped, read = lax.psum((dropped, read), batch_axes)
             if experts_spread:  # the shares' partial results add up
                 y = lax.psum(y, AXIS_EXPERT)
                 counts = lax.all_gather(counts, AXIS_EXPERT, tiled=True)
                 dropped = lax.psum(dropped, AXIS_EXPERT)
-            return y.reshape(x_.shape), counts, dropped
+                read = lax.pmean(read, AXIS_EXPERT)  # each reads its own
+            return y.reshape(x_.shape), counts, dropped, read
 
         tokens = P(batch_axes or None, None, None)
         held = P(AXIS_EXPERT if experts_spread else None, None, None)
@@ -513,7 +544,7 @@ def apply(params: Params, x, *, experts_per_token: int, first_expert: int = 0,
             in_specs=(tokens, tokens,
                       {name: held if name.startswith("w_") else P()
                        for name in w}),
-            out_specs=(tokens, P(), P()), check_vma=False,
+            out_specs=(tokens, P(), P(), P()), check_vma=False,
         )(x, router_in, w)
     if "shared_up" in params:  # every chip's alike: once, after the sum
         y = y + _shared(params, x, compute_dtype)

@@ -2,8 +2,9 @@
 interpreter, at sizes the MXU tiles, against ``lax.ragged_dot`` and its
 ``jax.vjp``. The chip's half is tests_tpu/test_moe_on_tpu.py. The compiles
 for a described chip at the end take in kernels/row_map.py's maps,
-kernels/ssd.py's scan and kernels/ssm_conv_gate.py's two passes too: one
-file holds every test that loads the TPU's compiler."""
+kernels/token_sum.py's sums, kernels/ssd.py's scan and
+kernels/ssm_conv_gate.py's two passes too: one file holds every test that
+loads the TPU's compiler."""
 
 import importlib
 import os
@@ -16,7 +17,7 @@ import pytest
 from jax import lax
 from jax.sharding import SingleDeviceSharding
 
-from mpi_operator_tpu.kernels import row_map
+from mpi_operator_tpu.kernels import row_map, token_sum
 from mpi_operator_tpu.parallel import moe
 from tests import poisoned_rows
 
@@ -343,6 +344,89 @@ def test_the_cells_row_passes_compile_for_a_v5e(one_chip, name, width):
     # a number a row goes in and comes out as it lies in HBM: no copy into
     # a layout that pads it to a tile's width
     assert not re.search(r"f32\[131072,1\]", text)
+
+
+# the routed cells' layers a chip: tokens, k, held of published experts,
+# model width, expert width, gated
+ROUTED_CELLS = {
+    "mellum2.steady-8k": (16384, 8, 16, 64, 2304, 896, True),
+    "nemotron3nano.steady-8k": (16384, 6, 8, 128, 2688, 1856, False),
+    "kanana2.steady-8k": (16384, 6, 16, 128, 2048, 768, True),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROUTED_CELLS))
+def test_the_cells_token_sums_compile_for_a_v5e(one_chip, cell):
+    """Both call sites of the token sum (kernels/token_sum.py), weighted and
+    not, at the cell's buffer: one Mosaic call each under the name the trace
+    is read by, inside the VMEM limit its launcher states."""
+    n, k, held, _, d, _, _ = ROUTED_CELLS[cell]
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    plan = token_sum.Plan(spec((n // 128 * held,), jnp.int32),
+                          spec((), jnp.int32))
+    assert token_sum.takes(n, d, jnp.bfloat16)
+    text = jax.jit(lambda src, pos, plan, c: (
+        token_sum.token_sum(src, pos, plan, c),
+        token_sum.token_sum(src, pos, plan))
+    ).lower(spec((n * k, d), jnp.bfloat16), spec((n, k), jnp.int32), plan,
+            spec((n, k), jnp.float32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert re.search(r"moe_token_sum_*\.\d", text)
+
+
+@pytest.mark.parametrize("cell", sorted(ROUTED_CELLS))
+def test_on_the_kernel_path_the_layer_gathers_no_row_of_every_assignment(
+        one_chip, monkeypatch, cell):
+    """The cell's routed layer, value and gradients, compiled for a v5e on
+    the path the chip takes (the grouped products, the passes in row order
+    and the token sums as kernels): the two gathers in token order are the
+    token sum, and nothing gathers an ``[N, k, D]`` array of every
+    assignment's row; the dispatch's gather in row order is still there."""
+    n, k, held, experts, d, f, gated = ROUTED_CELLS[cell]
+    monkeypatch.setattr(moe, "_row_passes", lambda *shapes: False)
+    monkeypatch.setattr(moe, "_grouped", lambda xs, w, sizes, precision:
+                        gm.grouped_matmul(xs, w, sizes, interpret=False))
+    p = jax.eval_shape(lambda: moe.init(
+        jax.random.PRNGKey(0), d_model=d, d_expert=f, n_experts=experts,
+        n_held=held, gated=gated, score_bias=not gated))
+    put = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((2, n // 2, d), jnp.bfloat16, sharding=one_chip)
+    loss = lambda p, x: jnp.sum(moe.apply(
+        p, x, experts_per_token=k)[0].astype(jnp.float32) ** 2)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        jax.tree.map(put, p), x).compile().as_text()
+    assert re.search(r"moe_token_sum_*\.\d", text)
+    assert not re.search(rf"bf16\[{n},{k},{d}\]\S* gather\(", text)
+    assert not re.search(rf"\[{n},{k},{d}\]", text)
+    assert re.search(rf"bf16\[{n * k},{d}\]\S* gather\(", text)
+
+
+def test_the_token_sums_compile_for_four_v5e_chips_under_shard_map(topo):
+    """``mellum2.steady-8k``'s buffer a chip on a mesh of four, the tokens
+    over ``data``: each chip plans and sums its own tokens' rows of its own
+    buffer, as the layer's members do; nothing is gathered around the
+    kernel."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    n, k, held, _, d, _, _ = ROUTED_CELLS["mellum2.steady-8k"]
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, P("data")))
+
+    def member(src, pos, group, c):
+        plan = token_sum.plan(group, held)
+        return token_sum.token_sum(src, pos, plan, c), token_sum.token_sum(
+            src, pos, plan)
+
+    text = jax.jit(jax.shard_map(
+        member, mesh=mesh, in_specs=(P("data"),) * 4,
+        out_specs=(P("data"), P("data")), check_vma=False)).lower(
+        spec((4 * n * k, d), jnp.bfloat16), spec((4 * n, k), jnp.int32),
+        spec((4 * n, k), jnp.int32), spec((4 * n, k), jnp.float32)
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert re.search(rf"bf16\[{n * k},{d}\]", text)  # a chip's own buffer
+    assert "all-gather" not in text and "all-to-all" not in text
 
 
 @pytest.mark.parametrize("pass_states", [True, False])

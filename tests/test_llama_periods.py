@@ -77,10 +77,12 @@ def test_a_routed_loss_returns_its_counters(routed):
     cfg, params = routed
     loss, counters = llama.loss_fn(cfg, params, {"tokens": TOKENS})
     assert set(counters) == {moe.ASSIGNMENTS_HELD, moe.LOAD_MAX_OVER_MEAN,
-                             moe.ASSIGNMENTS_DROPPED}
+                             moe.ASSIGNMENTS_DROPPED, moe.TOKEN_ROWS_READ}
     # half the experts held: about half of the 2 x 24 x 2 assignments
     assert 30 < float(counters[moe.ASSIGNMENTS_HELD]) < 66
     assert float(counters[moe.ASSIGNMENTS_DROPPED]) == 0
+    # on the CPU the gathers in token order read every assignment's row
+    assert float(counters[moe.TOKEN_ROWS_READ]) == 2 * 24 * 2
     assert np.isfinite(float(loss))
     # a dense model's loss is its value alone
     dense = llama.tiny()
@@ -173,11 +175,13 @@ DENSE_STEP_DIGESTS = {
 # say their indices are in bounds, the activation and the rows read twice
 # behind hand-written transposes, two gathers of numbers as sorts, one
 # counter more; PR 39 took that counter, `moe.rows_worked`, out again)
+# and one counter more since, `moe.token_rows_read`: on the CPU a constant a
+# layer, the gathers in token order staying ``jax.numpy`` there
 ROUTED_STEP_DIGESTS = {
     (False, 32):
-        "9dce5fb2896dd7d2e037282a37d034213fdcbfaa695611d664e1031c84acc31a",
+        "31ee2af673e458ed114502c7873b7db0a0ed0a373c4090c490a15129ce70da1e",
     (True, 4096):
-        "6e898b5fcbd8b590c3cc6cce102b4ec8f830061657529d78725ae5f9b1572147",
+        "8b4bdce639592921cfab9b25f7b5935b147871bbee64bd2e7a126eb02c058547",
 }
 
 
@@ -223,7 +227,8 @@ def test_the_step_puts_the_losss_scalars_into_its_metrics(routed):
     state, metrics = trainer.train_step(
         trainer.init_state(params), {"tokens": TOKENS})
     assert {"loss", "grad_norm", moe.ASSIGNMENTS_HELD,
-            moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED} == set(metrics)
+            moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED,
+            moe.TOKEN_ROWS_READ} == set(metrics)
     _, counters = llama.loss_fn(cfg, params, {"tokens": TOKENS})
     assert float(metrics[moe.ASSIGNMENTS_HELD]) == float(
         counters[moe.ASSIGNMENTS_HELD])
@@ -253,7 +258,8 @@ def test_the_counters_reach_the_blob(routed, tmp_path, monkeypatch):
     assert result.outcome == "done"
     blob = json.loads(stats_file.read_text())
     assert set(blob["counters"]) == {
-        moe.ASSIGNMENTS_HELD, moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED}
+        moe.ASSIGNMENTS_HELD, moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED,
+        moe.TOKEN_ROWS_READ}
     assert blob["counters"][moe.ASSIGNMENTS_DROPPED] == 0
     assert blob["counters"][moe.ASSIGNMENTS_HELD] == pytest.approx(
         result.metrics[moe.ASSIGNMENTS_HELD], abs=1e-3)
@@ -364,7 +370,7 @@ def test_the_hybrid_steps_scalars_reach_its_metrics(hybrid):
         trainer.init_state(params), {"tokens": TOKENS})
     assert {"loss", "grad_norm", moe.ASSIGNMENTS_HELD,
             moe.LOAD_MAX_OVER_MEAN, moe.ASSIGNMENTS_DROPPED,
-            "ssm.carry_share"} == set(metrics)
+            moe.TOKEN_ROWS_READ, "ssm.carry_share"} == set(metrics)
     assert 0.0 <= float(metrics["ssm.carry_share"]) <= 1.0
     assert "ssm.carry_share" in stepstats.TRAIN_COUNTERS
 

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mpi_operator_tpu.kernels import grouped_matmul, row_map
+from mpi_operator_tpu.kernels import grouped_matmul, row_map, token_sum
 from mpi_operator_tpu.kernels.quant_matmul import quant_ragged_dot
 from mpi_operator_tpu.parallel import moe
 from mpi_operator_tpu.runtime import MeshPlan, build_mesh
@@ -164,12 +164,12 @@ def test_with_the_kernels_the_layer_and_its_gradients_are_the_same(
 
 # -- the passes in row order, bounded by the held rows -----------------------
 
-ROWS, TILE = 1152, 128  # 2 x 288 tokens, two assignments each: nine tiles
+ROWS, TILE = 1280, 256  # 2 x 320 tokens, two assignments each: five tiles
 
 
 @pytest.fixture(scope="module", params=["float32", "bfloat16"])
 def bounded(request):
-    """The same layer over 1152 rows (tiles of 128) of which about half are
+    """The same layer over 1280 rows (tiles of 256) of which about half are
     held, by the CPU's path and on the bounded one: every kernel under the
     Pallas interpreter, every row past the held ones NaN before and after
     every pass (tests/poisoned_rows.py). In bfloat16 the combine's
@@ -177,7 +177,7 @@ def bounded(request):
     dtype = jnp.dtype(request.param)
     p = _share(moe.init(jax.random.PRNGKey(7), d_model=256, d_expert=128,
                         n_experts=8, n_held=8), 2, 4)
-    x = jax.random.normal(jax.random.PRNGKey(8), (2, 288, 256), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 320, 256), jnp.float32)
     cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
 
     def layer_and_gradients():
@@ -219,8 +219,10 @@ def test_bounded_and_poisoned_the_layer_and_its_gradients_are_the_same(
 def test_rows_worked_is_the_visited_tiles_or_the_whole_buffer(bounded):
     """The bounded passes visit the held rows' tiles, the layer's own bound
     (its held count); with every row held, the whole buffer. The layer's
-    counters are the same on both paths."""
-    (_, got), (_, want), _ = bounded
+    counters are the same on both paths, but the rows read in token order:
+    all of them on the CPU's path, and in bf16, where the kernel takes
+    them, the aligned ranges, as counted by hand from the routing."""
+    (_, got), (_, want), dtype = bounded
     held = want[moe.ASSIGNMENTS_HELD]
     assert 0 < held < ROWS - TILE and held % TILE  # a tile is cut
     assert row_map.row_tile(ROWS) == TILE
@@ -228,9 +230,179 @@ def test_rows_worked_is_the_visited_tiles_or_the_whole_buffer(bounded):
     assert int(row_map.rows_worked(ROWS, ROWS)) == ROWS
     assert set(got) == set(want) == {
         moe.ASSIGNMENTS_HELD, moe.LOAD_MAX_OVER_MEAN,
-        moe.ASSIGNMENTS_DROPPED}
-    for name in want:
+        moe.ASSIGNMENTS_DROPPED, moe.TOKEN_ROWS_READ}
+    for name in set(want) - {moe.TOKEN_ROWS_READ}:
         assert got[name] == want[name]
+    assert want[moe.TOKEN_ROWS_READ] == ROWS
+    if dtype == jnp.float32:  # the kernel takes bf16 rows only
+        assert got[moe.TOKEN_ROWS_READ] == ROWS
+        return
+    p = _share(moe.init(jax.random.PRNGKey(7), d_model=256, d_expert=128,
+                        n_experts=8, n_held=8), 2, 4)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 320, 256), jnp.float32)
+    _, experts = moe.route(x.reshape(-1, 256), p["router"]["w"], K)
+    local = np.asarray(experts) - 2
+    group = np.where((local >= 0) & (local < 4), local, 4)
+    read = _rows_read_by_hand(group, 4, token_sum.TILE)
+    assert held < read < ROWS
+    assert got[moe.TOKEN_ROWS_READ] == read
+
+
+# -- the two gathers in token order, reading the held rows only -------------
+
+def routed_buffer(n, k, held, experts, width, seed, *, never=()):
+    """An assignment buffer as the layer builds it: ``n`` tokens each routed
+    to ``k`` distinct experts of ``experts`` drawn evenly (never one of
+    ``never``), the first ``held`` of them held here; rows sorted stably by
+    held group, random bf16 where held and NaN from the last held row on.
+    (rows [n k, width], pos [n, k], group [n, k] (``held`` where absent),
+    weights [n, k] float32, the held rows' count)."""
+    rng = np.random.default_rng(seed)
+    draw = rng.random((n, experts))
+    draw[:, list(never)] = 2.0
+    chosen = np.argsort(draw, axis=1)[:, :k]
+    group = np.where(chosen < held, chosen, held).astype(np.int32)
+    row_slot = np.argsort(group.reshape(-1), kind="stable")
+    pos = np.argsort(row_slot).reshape(n, k).astype(np.int32)
+    rows = int(np.sum(chosen < held))
+    src = jax.random.normal(jax.random.PRNGKey(seed), (n * k, width),
+                            jnp.bfloat16)
+    src = jnp.where((jnp.arange(n * k) < rows)[:, None], src, jnp.nan)
+    weights = rng.random((n, k)).astype(np.float32)
+    return (src, jnp.asarray(pos), jnp.asarray(group), jnp.asarray(weights),
+            rows)
+
+
+def _rows_read_by_hand(group, held, tile):
+    """The rows a token tile's copies move, tile by tile and group by group:
+    a group's rows of the tile, from the 16-row line at or before the first
+    to the end of the line that holds the last."""
+    group = np.asarray(group)
+    read, start = 0, 0
+    for g in range(held):
+        tokens = np.nonzero(group == g)[0]  # in token order, as sorted
+        for i in np.unique(tokens // tile):
+            rows = start + np.nonzero(tokens // tile == i)[0]
+            read += -(-(rows[-1] + 1) // 16) * 16 - rows[0] // 16 * 16
+        start += len(tokens)
+    return read
+
+
+def _both_sites(src, pos, group, weights, held, plan, interpret):
+    """The combine's forward (weighted) and the dispatch's transpose (no
+    weight) of ``src`` as the layer calls them: on the kernel with
+    ``plan``, in ``jax.numpy`` without."""
+    n, k = pos.shape
+    is_held = group < held
+    row_slot = jnp.argsort(group.reshape(-1), stable=True).astype(jnp.int32)
+    combine = moe._combine(src, weights, row_slot // k, row_slot, pos,
+                           is_held, jnp.sum(is_held), plan, interpret)
+    _, vjp = jax.vjp(lambda x: moe._dispatch(
+        x, row_slot // k, pos, is_held, plan, interpret),
+        jnp.zeros((n, src.shape[1]), src.dtype))
+    return {"combine": combine, "dispatch_t": vjp(src)[0]}
+
+
+TOKEN_SUM_CASES = {  # n, k, held, published, width, never drawn
+    "mellum2": (256, 8, 16, 64, 256, ()),
+    "nemotron3nano": (256, 6, 8, 128, 128, ()),
+    "kanana2": (256, 6, 16, 128, 128, ()),
+    "every assignment held": (256, 2, 4, 4, 128, ()),
+    "none held": (256, 2, 4, 64, 128, range(4)),
+    "an empty group": (256, 6, 16, 64, 128, (5,)),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(TOKEN_SUM_CASES))
+def token_sums(request):
+    n, k, held, experts, width, never = TOKEN_SUM_CASES[request.param]
+    src, pos, group, weights, rows = routed_buffer(
+        n, k, held, experts, width, seed=len(request.param), never=never)
+    plan = token_sum.plan(group, held)
+    got = _both_sites(src, pos, group, weights, held, plan, True)
+    want = _both_sites(src, pos, group, weights, held, None, None)
+    return request.param, (group, held, rows, plan), got, want
+
+
+@pytest.mark.parametrize("site", ["combine", "dispatch_t"])
+def test_the_token_sum_is_the_masked_gathers_sum(token_sums, site):
+    """Both call sites at each routed cell's k and held share and at the
+    edges, two token tiles of 128, the rows past the held ones NaN: the
+    kernel (interpreted) gives what the gather of every assignment, masked
+    and summed in float32, gives, to the rounding of the sum's order."""
+    case, (_, held, rows, _), got, want = token_sums
+    a = np.asarray(got[site], np.float32)
+    b = np.asarray(want[site], np.float32)
+    assert np.all(np.isfinite(a)) and np.all(np.isfinite(b))
+    if case == "none held":
+        assert rows == 0 and not np.any(a) and not np.any(b)
+        return
+    assert rows > 0 and np.any(b)
+    np.testing.assert_allclose(a, b, rtol=2 ** -7,
+                               atol=2 ** -16 * np.abs(b).max())
+
+
+def test_the_plans_ranges_are_the_held_rows_lines(token_sums):
+    """The rows the copies move are the lines of the held rows, counted by
+    hand: no fewer than the held rows, and at most two lines more a range
+    (a line two ranges share is copied for each). Every assignment held:
+    some tile's range starts inside a line that the group before it ends
+    in, and the last range ends at the buffer's end."""
+    case, (group, held, rows, plan), _, _ = token_sums
+    n, k = group.shape
+    read = int(plan.rows_read)
+    assert read == _rows_read_by_hand(group, held, token_sum.TILE)
+    assert rows <= read <= rows + 32 * int(jnp.sum(plan.ranges % 256 > 0))
+    assert int(jnp.sum(plan.ranges % 256)) == rows
+    if case == "every assignment held":
+        lo, count = plan.ranges // 256, plan.ranges % 256
+        sizes = jnp.sum(count.reshape(-1, held), axis=0)
+        starts = jnp.repeat((jnp.cumsum(sizes) - sizes)[None], n // 128, 0)
+        assert bool(jnp.any((lo // 16 * 16 < starts.reshape(-1))
+                            & (count > 0)))
+        assert int(jnp.max(-(-(lo + count) // 16) * 16)) == rows == n * k
+
+
+@pytest.mark.parametrize("site", ["combine", "dispatch_t"])
+def test_a_range_started_a_row_late_loses_that_row_and_nothing_else(site):
+    """Every range of the plan one row late, the rows past the held ones
+    finite: the kernel (interpreted) sums each token's rows but the first
+    of each range, which no token then finds, and is far from the sound
+    sum."""
+    n, k, held, experts, width, _ = TOKEN_SUM_CASES["mellum2"]
+    src, pos, group, weights, rows = routed_buffer(n, k, held, experts,
+                                                   width, seed=40)
+    src = jnp.where(jnp.isnan(src), jnp.ones_like(src), src)
+    plan = token_sum.plan(group, held)
+    late = plan._replace(ranges=jnp.where(plan.ranges % 256 > 0,
+                                          plan.ranges + 256, plan.ranges))
+    c = weights if site == "combine" else None
+    got = np.asarray(token_sum.token_sum(src, pos, late, c, interpret=True),
+                     np.float32)
+    tile = token_sum.TILE
+    lo = np.asarray(plan.ranges // 256).reshape(n // tile, held)
+    g = np.asarray(group)
+    first = (g < held) & (np.asarray(pos) == lo[
+        np.arange(n)[:, None] // tile, np.minimum(g, held - 1)])
+    assert int(first.sum()) == int(jnp.sum(plan.ranges % 256 > 0)) > 0
+
+    def summed(keep):
+        rows_ = np.take(np.asarray(src, np.float32), np.asarray(pos), axis=0)
+        scale = np.ones((n, k), np.float32) if c is None else np.asarray(c)
+        return np.sum(np.where(keep[..., None], rows_ * scale[..., None], 0),
+                      axis=1)
+
+    want, sound = summed((g < held) & ~first), summed(g < held)
+    np.testing.assert_allclose(got, want, rtol=2 ** -7,
+                               atol=2 ** -16 * np.abs(want).max())
+    assert np.sqrt(np.mean((got - sound) ** 2) / np.mean(sound ** 2)) > 0.1
+
+
+def test_the_kernel_is_taken_for_bf16_rows_of_whole_lane_tiles():
+    assert token_sum.takes(16384, 2304, jnp.bfloat16)
+    assert not token_sum.takes(16384, 2304, jnp.float32)
+    assert not token_sum.takes(16384, 2300, jnp.bfloat16)
+    assert not token_sum.takes(576, 256, jnp.bfloat16)  # tiles of 64 tokens
 
 
 @pytest.mark.parametrize("shapes,path", [
@@ -445,25 +617,28 @@ def test_over_a_mesh_the_shared_expert_is_added_once(whole_sigmoid, axes):
     np.testing.assert_allclose(y, _loop2(p, x), atol=3e-5, rtol=3e-5)
 
 
-@pytest.fixture(scope="module")
-def bounded_ungated():
-    """The ungated layer over 1152 rows of which about half are held, its
+@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+def bounded_ungated(request):
+    """The ungated layer over 1280 rows of which about half are held, its
     experts 144 wide (no multiple of 128: a whole-width block), by the
     CPU's path and on the bounded one, every kernel interpreted and every
-    row past the held ones NaN around every pass."""
+    row past the held ones NaN around every pass. In bfloat16 the two
+    gathers in token order are the token sum's kernel."""
+    dtype = jnp.dtype(request.param)
     p = moe.init(jax.random.PRNGKey(7), d_model=256, d_expert=144,
                  n_experts=8, n_held=8, gated=False, d_shared=128,
                  score_bias=True)
     p = {"router": p["router"], "shared_up": p["shared_up"],
          "shared_down": p["shared_down"],
          **{n: {"w": p[n]["w"][2:6]} for n in ("w_up", "w_down")}}
-    x = jax.random.normal(jax.random.PRNGKey(8), (2, 288, 256), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 320, 256), jnp.float32)
     cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
 
     def layer_and_gradients():
         apply = lambda p, x: moe.apply(
-            p, x, first_expert=2, experts_per_token=K, router_scale=SCALE,
-            **F32)[0]
+            p, x.astype(dtype), first_expert=2, experts_per_token=K,
+            router_scale=SCALE, compute_dtype=dtype,
+            router_in=x)[0].astype(jnp.float32)
         y, vjp = jax.vjp(apply, p, x)
         d_p, d_x = vjp(cot)
         return {"y": y, "x": d_x, "router": d_p["router"]["w"],
@@ -471,17 +646,23 @@ def bounded_ungated():
 
     want = layer_and_gradients()
     with poisoned_rows.patched(interpret=True):
-        return layer_and_gradients(), want
+        return layer_and_gradients(), want, dtype
 
 
 @pytest.mark.parametrize(
     "leaf", ["y", "x", "router", "w_up", "w_down", "shared_up"])
 def test_bounded_and_poisoned_the_ungated_layer_is_the_same(
         bounded_ungated, leaf):
-    got, want = bounded_ungated
-    assert float(jnp.max(jnp.abs(want[leaf]))) > 0
+    got, want, dtype = bounded_ungated
+    scale = float(jnp.max(jnp.abs(want[leaf])))
+    assert scale > 0
     assert bool(jnp.all(jnp.isfinite(got[leaf])))
-    np.testing.assert_allclose(got[leaf], want[leaf], atol=5e-5, rtol=2e-4)
+    if dtype == jnp.float32:  # float32's rounding, in another order of sums
+        np.testing.assert_allclose(got[leaf], want[leaf],
+                                   atol=max(5e-5, 2e-6 * scale), rtol=2e-4)
+    else:  # both round float32 results to bf16, in another order of sums
+        np.testing.assert_allclose(got[leaf] / scale, want[leaf] / scale,
+                                   atol=2 ** -6)
 
 
 # -- gated experts beside a gated shared expert: the latent-attention model's --

@@ -9,9 +9,13 @@ width the MXU tiles (d 256, experts 128 wide, 16 published of which 4 held,
 2 a token), against that plain loop in float32 at ``highest`` precision,
 once more with every row past the held ones NaN around every pass
 (tests/poisoned_rows.py); one product at the benchmark cell's shapes against
-the compiler's own ``lax.ragged_dot``, both timed; and the passes in row
+the compiler's own ``lax.ragged_dot``, both timed; the passes in row
 order (kernels/row_map.py) at those shapes, bounded by a quarter of the rows
-and by all of them, against the compiler's own fusions over all rows."""
+and by all of them, against the compiler's own fusions over all rows; and
+the two gathers in token order (kernels/token_sum.py) at the three routed
+cells' shapes against the float32 ``jax.numpy`` form, with every range
+started a row late, both timed, on one chip and on four under
+``shard_map``."""
 
 import time
 
@@ -21,10 +25,11 @@ import numpy as np
 import pytest
 from jax import lax
 
-from mpi_operator_tpu.kernels import row_map
+from mpi_operator_tpu.kernels import row_map, token_sum
 from mpi_operator_tpu.kernels.grouped_matmul import grouped_matmul
 from mpi_operator_tpu.parallel import moe
 from tests import poisoned_rows
+from tests.test_moe import _rows_read_by_hand, routed_buffer
 
 D, F, E, HELD, FIRST, K = 256, 128, 16, 4, 4, 2
 
@@ -254,3 +259,147 @@ def test_the_gated_shared_expert_at_the_kanana_shape():
     added = both(p).astype(jnp.float32) - both(routed).astype(jnp.float32)
     gap = float(jnp.sqrt(jnp.mean((added - want) ** 2)))
     assert gap < 2e-2 * size, (gap, size)
+
+
+# -- the two gathers in token order, at the routed cells' shapes --------------
+
+CELLS = {  # tokens a chip, k, held, published, width
+    "mellum2.steady-8k": (16384, 8, 16, 64, 2304),
+    "nemotron3nano.steady-8k": (16384, 6, 8, 128, 2688),
+    "kanana2.steady-8k": (16384, 6, 16, 128, 2048),
+}
+
+
+def _rms(v):
+    return float(jnp.sqrt(jnp.mean(jnp.square(v.astype(jnp.float32)))))
+
+
+def _in_float32(src, pos, group, c, held):
+    """The token sums as the layer's ``jax.numpy`` form makes them, but in
+    float32 from the gather on and not rounded at the end."""
+    rows = jnp.take(src, pos, axis=0, mode="clip").astype(jnp.float32)
+    rows = jnp.where((group < held)[..., None], rows, 0.0)
+    return jnp.sum(rows * (1.0 if c is None else c[..., None]), axis=1)
+
+
+def _in_bf16(src, pos, group, c, held):
+    """What the layer ran before the kernel: the gather of every
+    assignment, masked, weighed and summed in float32, rounded once."""
+    return _in_float32(src, pos, group, c, held).astype(src.dtype)
+
+
+def _late(plan):
+    """The plan with every range starting one row late: each range's first
+    row is then summed by no token."""
+    return plan._replace(ranges=jnp.where(plan.ranges % 256 > 0,
+                                          plan.ranges + 256, plan.ranges))
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_cells_token_sums_are_the_float32_forms_and_faster(cell):
+    """At the cell's shapes (uniform routing at its k and held share, rows
+    past the held ones NaN) both call sites on the kernel against the
+    float32 form, rms error over rms; every range started a row late, with
+    the rows past the held ones finite, far off; the kernel and the
+    ``jax.numpy`` form it replaces timed (``-s``)."""
+    n, k, held, experts, width = CELLS[cell]
+    src, pos, group, weights, rows = routed_buffer(
+        n, k, held, experts, width, seed=n + k + held)
+    finite = jnp.where(jnp.isnan(src), jnp.ones_like(src), src)
+    plan = jax.jit(token_sum.plan, static_argnums=1)(group, held)
+    kernel = jax.jit(token_sum.token_sum)
+    for site, c in (("combine", weights), ("dispatch_t", None)):
+        want = jax.jit(_in_float32, static_argnums=4)(src, pos, group, c,
+                                                      held)
+        kernel_ms, got = _ms(kernel, src, pos, plan, c)
+        gather_ms, _ = _ms(jax.jit(_in_bf16, static_argnums=4), src, pos,
+                           group, c, held)
+        err = _rms(got.astype(jnp.float32) - want) / _rms(want)
+        late = kernel(finite, pos, _late(plan), c).astype(jnp.float32)
+        bad = _rms(late - want) / _rms(want)
+        print(f"{cell} {site}: kernel {kernel_ms:.3f} ms, gather of every "
+              f"assignment {gather_ms:.3f} ms; rms error {err:.2e}, ranges "
+              f"a row late {bad:.2e}; rows read {int(plan.rows_read)} of "
+              f"{n * k}, {rows} held")
+        assert bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))
+        assert err < 3e-3, (site, err)  # bf16's rounding of the result
+        # a range's first row lost: a sixteenth to a sixth of the held rows
+        assert bool(jnp.all(jnp.isfinite(late))) and bad > 0.1, (site, bad)
+        assert kernel_ms < 0.5 * gather_ms, site
+    assert rows < int(plan.rows_read) < 0.7 * n * k
+
+
+def test_on_four_chips_the_token_sums_run_under_shard_map():
+    """A Pallas call has no partitioning rule: with the tokens over a mesh
+    of four, each chip plans and sums its own tokens' rows of its own
+    buffer (``mellum2.steady-8k``'s shapes a chip), as the layer's members
+    do, against the float32 form on the same chip."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    if jax.device_count() < 4:
+        pytest.skip("a mesh of four needs four chips")
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    n, k, held, experts, width = CELLS["mellum2.steady-8k"]
+    parts = [routed_buffer(n, k, held, experts, width, seed=40 + i)
+             for i in range(4)]
+    put = lambda i: jax.device_put(jnp.concatenate([p[i] for p in parts]),
+                                   NamedSharding(mesh, P("data")))
+    src, pos, group, weights = (put(i) for i in range(4))
+
+    def member(src, pos, group, c):
+        plan = token_sum.plan(group, held)
+        return (token_sum.token_sum(src, pos, plan, c),
+                _in_float32(src, pos, group, c, held))
+
+    both = jax.jit(jax.shard_map(
+        member, mesh=mesh, in_specs=(P("data"),) * 4,
+        out_specs=(P("data"), P("data")), check_vma=False))
+    text = both.lower(src, pos, group, weights).compile().as_text()
+    assert "moe_token_sum" in text and "all-gather" not in text
+    assert f"bf16[{n * k},{width}]" in text  # a chip's own buffer
+    got, want = both(src, pos, group, weights)
+    for i in range(4):
+        rows = slice(i * n, (i + 1) * n)
+        err = _rms(got[rows].astype(jnp.float32) - want[rows]) / _rms(
+            want[rows])
+        print(f"chip {i}: rms error {err:.2e}")
+        assert err < 3e-3
+
+
+def test_on_four_chips_of_experts_the_layer_and_its_counter_hold():
+    """The layer on a mesh of four ``expert`` members, each holding four of
+    the sixteen experts and every token: each member's token sums run on
+    its own buffer, the partial results add up to the plain loop over all
+    sixteen, and ``moe.token_rows_read`` is the members' mean of the lines
+    each copies, as counted by hand from the routing."""
+    from mpi_operator_tpu.runtime import MeshPlan, build_mesh
+    from mpi_operator_tpu.runtime.topology import AXIS_EXPERT
+    if jax.device_count() < 4:
+        pytest.skip("a mesh of four needs four chips")
+    mesh = build_mesh(MeshPlan(axes={AXIS_EXPERT: 4}), jax.devices()[:4])
+    key = jax.random.PRNGKey(5)
+    p = moe.init(key, d_model=D, d_expert=F, n_experts=E, n_held=E)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (4, 512, D),
+                          jnp.float32)
+    y, counters = jax.jit(lambda p, x: moe.apply(
+        p, x, experts_per_token=K, mesh=mesh))(p, x)
+    with jax.default_matmul_precision("highest"):
+        xf = x.reshape(-1, D)
+        w, e = jax.jit(moe.route, static_argnums=2)(xf, p["router"]["w"], K)
+        gate = jax.nn.silu(jnp.einsum("td,edf->etf", xf, p["w_gate"]["w"]))
+        up = jnp.einsum("td,edf->etf", xf, p["w_up"]["w"])
+        ys = jnp.einsum("etf,efd->etd", gate * up, p["w_down"]["w"])
+        mine = (e[None] == jnp.arange(E)[:, None, None])  # [E, T, K]
+        want = jnp.einsum("etd,et->td", ys, jnp.sum(
+            jnp.where(mine, w[None], 0.0), axis=-1)).reshape(x.shape)
+    scale = float(jnp.max(jnp.abs(want)))
+    gap = float(jnp.max(jnp.abs(y.astype(jnp.float32) - want))) / scale
+    e = np.asarray(e)
+    read = np.mean([_rows_read_by_hand(
+        np.where((e >= m * 4) & (e < m * 4 + 4), e - m * 4, 4), 4,
+        token_sum.TILE) for m in range(4)])
+    got = float(counters[moe.TOKEN_ROWS_READ])
+    print(f"four expert members: largest gap {gap:.2e} of the largest "
+          f"output; rows read {got} a member, {read} by hand, of {e.size}")
+    assert gap < 3e-2
+    assert float(counters[moe.ASSIGNMENTS_HELD]) == e.size
+    assert abs(got - read) <= 0.01 * read and got < 0.7 * e.size

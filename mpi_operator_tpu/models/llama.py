@@ -26,6 +26,9 @@ The reference has no LLM workload — its examples top out at CNN scale
   the key that all heads share, keys and queries wider than values; its own
   parameter tree); the feed-forward dense (SwiGLU) or routed
   (``Config.n_experts`` > 0: parallel/moe.py's share of the experts).
+  Full and window attention may differ by kind in their query heads, their
+  RoPE's theta and the share of a head they rotate, and may gate each
+  head's output by a sigmoid of the layer's input.
   ``Config.n_dense_layers`` pairs in front of a routed model's periods keep
   the dense feed-forward, ``d_ff`` wide: a stack of their own
   (``params["lead"]``), scanned before the periods, so the program is two
@@ -34,17 +37,18 @@ The reference has no LLM workload — its examples top out at CNN scale
   a Mamba-2 state-space layer (models/mamba2.py), a routed layer alone, or
   causal attention with no positional embedding (position comes through the
   state-space layers). Pairs share one parameter tree, stacked over layers;
-  single mixers have unlike trees, so theirs stack by kind
-  (``params["layers"][kind]``) and a period's body takes the next layer of
-  each kind as it walks the pattern. What a model is follows from its
-  configuration's shape; there is no switch.
+  single mixers have unlike trees, and so do pairs whose kinds have unlike
+  query heads, so theirs stack by kind (``params["layers"][kind]``) and a
+  period's body takes the next layer of each kind as it walks the pattern.
+  What a model is follows from its configuration's shape; there is no
+  switch.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -77,17 +81,32 @@ class Yarn:
     wavelength exceeds the original context are divided by ``factor``, those
     well inside it are kept, with a linear ramp between the dimensions at
     which ``beta_fast`` and ``beta_slow`` rotations fit the original
-    context; cos and sin are multiplied by ``attention_factor``. Here the
-    factor's square is folded into the scores' scale instead, in float32:
-    the same scores, and no rounding of the factor into a bf16 table (1.277
-    is no bf16 number: the table's cos and sin would come out 0.3 % low at
-    every position of the slow dimensions, all to one side)."""
+    context; cos and sin are multiplied by ``attention_factor``. Where the
+    whole head rotates, the factor's square is folded into the scores'
+    scale instead, in float32: the same scores, and no rounding of the
+    factor into a bf16 table (1.277 is no bf16 number: the table's cos and
+    sin would come out 0.3 % low at every position of the slow dimensions,
+    all to one side). Where only part of a head rotates, only that part of
+    a score carries the square, so the factor goes into the rotated part's
+    tables, each entry rounded on its own."""
 
     factor: float
     original_len: int
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+
+
+class KindAttention(NamedTuple):
+    """Full or window attention's own shape: its query heads (over the
+    plain ``n_kv_heads``), its RoPE's theta, and how many of each head's
+    first dimensions its RoPE turns (the rest pass unrotated). In
+    ``Config.attention_by_kind`` a 0 means the plain value: ``n_heads``,
+    ``rope_theta``, the whole head; ``Config.attention`` fills them in."""
+
+    heads: int = 0
+    theta: float = 0.0
+    rotated: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,6 +165,16 @@ class Config:
     # RoPE by kind: window layers rotate at the plain ``rope_theta``, full
     # layers too unless there is a Yarn for them
     yarn_full: Optional[Yarn] = None
+    # full and window attention where a kind differs from the plain
+    # ``n_heads``, ``rope_theta`` and whole-head rotation: pairs (kind,
+    # KindAttention)
+    attention_by_kind: Tuple[Tuple[str, KindAttention], ...] = ()
+    # a sigmoid gate on each query head's output, from the attention's
+    # normed input (``wg``: d_model x heads), before ``wo``
+    head_gate: bool = False
+    # the leading dense pairs' attention kind ("": the one pair kind of the
+    # periods, which must then have one)
+    lead_kind: str = ""
     # routed feed-forward (n_experts > 0; ``d_ff`` is then the leading
     # dense layers' width, unused without them): the
     # router scores ``n_experts`` published experts and keeps the
@@ -157,10 +186,12 @@ class Config:
     experts_per_token: int = 0
     d_expert: int = 0
     # the routed layer's form (parallel/moe.py): the score function
-    # ("softmax", or "sigmoid" with a correction bias in the choice), a
-    # factor on the weights, gated SwiGLU experts or ungated relu ** 2 ones,
-    # and the width of a shared expert (0: none; of the experts' form)
+    # ("softmax", or "sigmoid", with a correction bias in the choice unless
+    # ``router_bias`` is off), a factor on the weights, gated SwiGLU experts
+    # or ungated relu ** 2 ones, and the width of a shared expert (0: none;
+    # of the experts' form)
     router_score: str = "softmax"
+    router_bias: bool = True
     router_scale: float = 1.0
     experts_gated: bool = True
     d_shared: int = 0
@@ -216,13 +247,30 @@ class Config:
                 f"of {self.qk_nope_dim} + {self.qk_rope_dim} (an even "
                 f"number rotated), values of {self.v_head_dim}")
         if self.n_dense_layers and not (
-                self.routed and len(set(kinds)) == 1
-                and kinds[0] in PAIR_KINDS):
+                self.routed and kinds[0] in PAIR_KINDS
+                and self.lead in PAIR_KINDS
+                and (self.lead == "latent") == self.latent):
             raise ValueError(
                 f"n_dense_layers={self.n_dense_layers}: leading dense "
-                "pairs stand in front of a routed model's periods of one "
-                f"pair kind (layer_kinds={kinds!r}, n_experts="
-                f"{self.n_experts})")
+                "pairs stand in front of a routed model's periods of pairs, "
+                "with the attention of the one pair kind or of the stated "
+                f"lead_kind (layer_kinds={kinds!r}, lead_kind="
+                f"{self.lead_kind!r}, n_experts={self.n_experts})")
+        if any(k not in ("full", "window") for k, _ in self.attention_by_kind
+               ) or self.head_gate and self.latent:
+            raise ValueError(
+                "attention by kind and a head gate are full and window "
+                f"attention's: {self.attention_by_kind!r}, head_gate="
+                f"{self.head_gate}, layer_kinds={kinds!r}")
+        attended = set(kinds) | ({self.lead} if self.n_dense_layers else set())
+        for kind in attended & {"full", "window"}:
+            heads, theta, rotated = self.attention(kind)
+            if not (heads > 0 and heads % self.n_kv_heads == 0 and theta > 0
+                    and 0 < rotated <= self.head_dim and rotated % 2 == 0):
+                raise ValueError(
+                    f"{kind} attention: {heads} query heads over "
+                    f"{self.n_kv_heads}, theta {theta}, {rotated} of "
+                    f"{self.head_dim} dims rotated (even)")
         periods, rest = divmod(self.n_layers - self.n_dense_layers,
                                len(kinds))
         if periods < 1 or rest or self.n_dense_layers < 0:
@@ -230,7 +278,9 @@ class Config:
                 f"n_layers={self.n_layers} is no whole number of periods "
                 f"of {len(kinds)} layers behind {self.n_dense_layers} "
                 "leading dense ones")
-        if ("window" in kinds) != (self.window is not None):
+        windowed = "window" in kinds or (
+            self.n_dense_layers > 0 and self.lead == "window")
+        if windowed != (self.window is not None):
             raise ValueError(
                 "window layers need a window, and a window needs them: "
                 f"layer_kinds={kinds!r}, window={self.window!r}")
@@ -255,6 +305,28 @@ class Config:
     @property
     def experts_held(self) -> int:
         return self.n_experts_held or self.n_experts
+
+    @property
+    def lead(self) -> str:
+        """The leading dense pairs' attention kind."""
+        return self.lead_kind or (self.layer_kinds[0]
+                                  if len(set(self.layer_kinds)) == 1 else "")
+
+    @property
+    def stacked_by_kind(self) -> bool:
+        """Whether each kind's layers are a stack of their own: the kinds'
+        parameter trees differ (single mixers, or pairs of unlike query
+        heads)."""
+        return self.single_mixers or len(
+            {self.attention(k).heads for k in self.layer_kinds}) > 1
+
+    def attention(self, kind: str) -> KindAttention:
+        """A layer of ``kind``'s query heads, theta and rotated dimensions,
+        the plain values where ``attention_by_kind`` gives none."""
+        own = dict(self.attention_by_kind).get(kind, KindAttention())
+        return KindAttention(own.heads or self.n_heads,
+                             own.theta or self.rope_theta,
+                             own.rotated or self.head_dim)
 
     @property
     def latent(self) -> bool:
@@ -341,6 +413,30 @@ def tiny_latent(vocab: int = 256) -> Config:
     )
 
 
+def tiny_gated(vocab: int = 256) -> Config:
+    """Test-scale config of the gated-heads shape: one leading dense full
+    pair, then a period of three window layers and one full one; full
+    layers 4 query heads, window layers 6, over 2 KV heads; full layers
+    rotate the first half of each head at their own theta under YaRN,
+    window layers all of it; a sigmoid gate on each head; sigmoid routing
+    with no correction bias over 8 gated experts of which 2 a token and the
+    first 4 held, beside a shared expert."""
+    return Config(
+        vocab=vocab, d_model=48, n_layers=5, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=96, rope_theta=10_000.0, norm_eps=1e-6,
+        layer_kinds=("window", "window", "window", "full"), window=8,
+        n_dense_layers=1, lead_kind="full",
+        attention_by_kind=(("full", KindAttention(theta=500_000.0, rotated=8)),
+                           ("window", KindAttention(heads=6))),
+        head_gate=True,
+        yarn_full=Yarn(factor=4.0, original_len=16, beta_fast=8.0,
+                       attention_factor=1.2),
+        n_experts=8, n_experts_held=4, experts_per_token=2, d_expert=24,
+        router_score="sigmoid", router_bias=False, router_scale=2.5,
+        d_shared=24,
+    )
+
+
 def _normal(key, shape, scale):
     return jax.random.normal(key, shape, jnp.float32) * scale
 
@@ -348,18 +444,26 @@ def _normal(key, shape, scale):
 def _moe_form(c: Config) -> Dict[str, bool]:
     """What of the routed layer's optional parts this configuration has."""
     return {"gated": c.experts_gated,
-            "score_bias": c.router_score == "sigmoid"}
+            "score_bias": c.router_score == "sigmoid" and c.router_bias}
 
 
-def _init_attention(c: Config, keys, lead=()) -> Params:
-    d = c.d_model
-    return {
+def _init_attention(c: Config, keys, lead=(), kind: str = "full") -> Params:
+    """A kind's attention: its query heads' projections, and with
+    ``head_gate`` the gate's (``wg``, d_model x heads, drawn from a key
+    folded out of the query's)."""
+    d, heads = c.d_model, c.attention(kind).heads
+    q = heads * c.head_dim
+    p = {
         "attn_norm": {"scale": jnp.ones((*lead, d), jnp.float32)},
-        "wq": {"w": _normal(keys[0], (*lead, d, c.q_dim), d**-0.5)},
+        "wq": {"w": _normal(keys[0], (*lead, d, q), d**-0.5)},
         "wk": {"w": _normal(keys[1], (*lead, d, c.kv_dim), d**-0.5)},
         "wv": {"w": _normal(keys[2], (*lead, d, c.kv_dim), d**-0.5)},
-        "wo": {"w": _normal(keys[3], (*lead, c.q_dim, d), c.q_dim**-0.5)},
+        "wo": {"w": _normal(keys[3], (*lead, q, d), q**-0.5)},
     }
+    if c.head_gate:
+        p["wg"] = {"w": _normal(jax.random.fold_in(keys[0], 1),
+                                (*lead, d, heads), d**-0.5)}
+    return p
 
 
 def _init_latent(c: Config, keys, lead=()) -> Params:
@@ -381,10 +485,12 @@ def _init_latent(c: Config, keys, lead=()) -> Params:
     }
 
 
-def _init_pairs(c: Config, lk, n: int, routed: bool) -> Params:
+def _init_pairs(c: Config, lk, n: int, routed: bool,
+                kind: str = "full") -> Params:
     """``n`` pairs stacked on axis 0 (``lax.scan`` walks the leading axis):
-    the attention's leaves, the feed-forward's norm, and every layer's
-    router and held experts where ``routed``, else the dense SwiGLU."""
+    the attention's leaves (``kind``'s), the feed-forward's norm, and every
+    layer's router and held experts where ``routed``, else the dense
+    SwiGLU."""
     d = c.d_model
     if routed:
         feed_forward = jax.vmap(lambda k: _init_routed(c, k))(
@@ -395,9 +501,9 @@ def _init_pairs(c: Config, lk, n: int, routed: bool) -> Params:
             "w_up": {"w": _normal(lk[5], (n, d, c.d_ff), d**-0.5)},
             "w_down": {"w": _normal(lk[6], (n, c.d_ff, d), c.d_ff**-0.5)},
         }
-    attention = _init_latent if c.latent else _init_attention
     return {
-        **attention(c, lk, lead=(n,)),
+        **(_init_latent(c, lk, lead=(n,)) if c.latent
+           else _init_attention(c, lk, lead=(n,), kind=kind)),
         "mlp_norm": {"scale": jnp.ones((n, d), jnp.float32)},
         **feed_forward,
     }
@@ -414,14 +520,14 @@ def _init_mixer(c: Config, kind: str, key) -> Params:
     if kind == "mamba":
         return mamba2.init(key, c)
     if kind == "attention":
-        return _init_attention(c, jax.random.split(key, 4))
+        return _init_attention(c, jax.random.split(key, 4), kind=kind)
     return {"mlp_norm": {"scale": jnp.ones((c.d_model,), jnp.float32)},
             **_init_routed(c, key)}
 
 
 def _per_kind(c: Config) -> Dict[str, int]:
-    """How many layers of each kind the whole stack has."""
-    periods = c.n_layers // len(c.layer_kinds)
+    """How many layers of each kind the periods have."""
+    periods = (c.n_layers - c.n_dense_layers) // len(c.layer_kinds)
     return {kind: c.layer_kinds.count(kind) * periods
             for kind in sorted(set(c.layer_kinds))}
 
@@ -438,8 +544,14 @@ def init(config: Config, key) -> Params:
             kind: jax.vmap(lambda k, kind=kind: _init_mixer(c, kind, k))(
                 jax.random.split(lk[i], count))
             for i, (kind, count) in enumerate(_per_kind(c).items())}
+    elif c.stacked_by_kind:  # pairs of unlike attention: the same, by kind
+        layers = {
+            kind: _init_pairs(c, jax.random.split(lk[i], 7), count, c.routed,
+                              kind)
+            for i, (kind, count) in enumerate(_per_kind(c).items())}
     else:
-        layers = _init_pairs(c, lk, c.n_layers - c.n_dense_layers, c.routed)
+        layers = _init_pairs(c, lk, c.n_layers - c.n_dense_layers, c.routed,
+                             c.layer_kinds[0])
     params = {
         "embed": {"w": _normal(ke, (c.vocab, d), 1.0)},
         "layers": layers,
@@ -449,7 +561,7 @@ def init(config: Config, key) -> Params:
     if c.n_dense_layers:  # a stack of their own, in front of the periods
         params["lead"] = _init_pairs(
             c, jax.random.split(jax.random.fold_in(kl, 7), 7),
-            c.n_dense_layers, routed=False)
+            c.n_dense_layers, routed=False, kind=c.lead)
     return params
 
 
@@ -484,7 +596,9 @@ def logical_axes(config: Config) -> Params:
     routed_axes = lambda: {
         "mlp_norm": {"scale": ("stats",)},
         **moe.logical_axes(shared=c.d_shared > 0, **_moe_form(c))}
-    attention_axes = _LATENT_AXES if c.latent else _ATTENTION_AXES
+    attention_axes = _LATENT_AXES if c.latent else dict(
+        _ATTENTION_AXES, **({"wg": {"w": ("embed", "heads")}}
+                            if c.head_gate else {}))
     if c.single_mixers:
         by_kind = {"mamba": mamba2.logical_axes,
                    "attention": lambda: _ATTENTION_AXES,
@@ -493,6 +607,8 @@ def logical_axes(config: Config) -> Params:
     else:
         layers = {**attention_axes,
                   **(routed_axes() if c.routed else _DENSE_AXES)}
+        if c.stacked_by_kind:
+            layers = {kind: layers for kind in _per_kind(c)}
     # the leading stack axis is always replicated (None)
     stacked = lambda tree: jax.tree.map(
         lambda axes: (None, *axes), tree,
@@ -543,13 +659,17 @@ def rope_frequencies(dh: int, theta: float, yarn: Optional[Yarn] = None):
     return freqs * ((1.0 - ramp) + ramp / yarn.factor)
 
 
-def _rope_tables(t, dh, theta, dtype, yarn=None):
+def _rope_tables(t, dh, theta, dtype, yarn=None, factor=1.0):
     """cos/sin rotation tables [T, Dh/2] for global positions 0..T-1
     (arrays are global-view; sequence sharding is XLA's problem, not
-    RoPE's). Shared by both layout variants so the math can never drift."""
+    RoPE's), times ``factor`` in float32 before the rounding to ``dtype``.
+    Shared by both layout variants so the math can never drift."""
     freqs = rope_frequencies(dh, theta, yarn)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * freqs[None, :]
-    return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
+    if factor == 1.0:
+        return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
+    return ((jnp.cos(ang) * factor).astype(dtype),
+            (jnp.sin(ang) * factor).astype(dtype))
 
 
 def _rotate(x, cos, sin):
@@ -557,17 +677,32 @@ def _rotate(x, cos, sin):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
 
-def _rope(x, theta, yarn=None):
+def _rope(x, theta, yarn=None, factor=1.0):
     """RoPE, model layout: x [B,T,H,Dh], positions along axis 1."""
-    cos, sin = _rope_tables(x.shape[1], x.shape[-1], theta, x.dtype, yarn)
+    cos, sin = _rope_tables(x.shape[1], x.shape[-1], theta, x.dtype, yarn,
+                            factor)
     return _rotate(x, cos[None, :, None, :], sin[None, :, None, :])
 
 
-def _rope_bhtd(x, theta, yarn=None):
+def _rope_bhtd(x, theta, yarn=None, factor=1.0):
     """RoPE, kernel heads-major layout: x [B,H,T,Dh], positions along
     axis 2 (same tables, different broadcast)."""
-    cos, sin = _rope_tables(x.shape[2], x.shape[-1], theta, x.dtype, yarn)
+    cos, sin = _rope_tables(x.shape[2], x.shape[-1], theta, x.dtype, yarn,
+                            factor)
     return _rotate(x, cos[None, None], sin[None, None])
+
+
+def _rope_first(rope, x, rotated, theta, yarn=None):
+    """``rope`` (either layout) over the first ``rotated`` dimensions of
+    each head, the frequencies over those dimensions alone, the rest passed
+    as they are. YaRN's factor is on the rotated part's cos and sin: the
+    unrotated part of a score carries none of it (Yarn)."""
+    if rotated == x.shape[-1]:
+        return rope(x, theta, yarn)
+    factor = yarn.attention_factor if yarn else 1.0
+    return jnp.concatenate(
+        [rope(x[..., :rotated], theta, yarn, factor), x[..., rotated:]],
+        axis=-1)
 
 
 def apply(
@@ -689,6 +824,7 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
             return latent_attention(h, lp)
         y = _rmsnorm(h, lp["attn_norm"]["scale"], c.norm_eps)
         b, t, _ = y.shape
+        heads, theta, rotated = c.attention(kind)
         # K/V stay at n_kv_heads: every attention path is GQA-aware, so the
         # ring never carries expanded K/V
         window = c.window if kind == "window" else None
@@ -696,9 +832,29 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
         # the single mixer "attention" has no positional embedding
         rotates = kind != "attention"
         # YaRN's factor on cos and sin of q and k alike is its square on
-        # their product
-        scale = c.head_dim**-0.5 * (yarn.attention_factor**2 if yarn else 1)
+        # their product: on the scale where the whole head rotates, on the
+        # rotated dims' tables where part of it does (Yarn)
+        whole = rotated == c.head_dim
+        scale = c.head_dim**-0.5 * (
+            yarn.attention_factor**2 if yarn and whole else 1)
         use_flash = not seq_sharded and c.attention_impl != "dense"
+
+        def rope(a, layout):
+            if not rotates:
+                return a
+            with jax.named_scope("attention_rope"):
+                return _rope_first(layout, a, rotated, theta, yarn)
+
+        def gate(attn, spec):
+            """Each head's output times sigmoid(y W_g); ``spec`` the
+            product's einsum into ``attn``'s layout less its last axis."""
+            if not c.head_gate:
+                return attn
+            with jax.named_scope("attention_gate"):
+                g = jnp.einsum(spec, y, lp["wg"]["w"].astype(dt))
+                g = jax.nn.sigmoid(g.astype(jnp.float32)).astype(attn.dtype)
+                return attn * g[..., None]
+
         if use_flash:
             from mpi_operator_tpu.kernels import flash_attention
 
@@ -708,27 +864,25 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
             # no standalone [B,T,H,D]↔[B,H,T,D] copies around the kernel.
             # auto/flash: the kernel on TPU, chunked XLA elsewhere; mesh
             # passed through (the pallas call is not SPMD-partitionable).
-            wq3 = lp["wq"]["w"].astype(dt).reshape(-1, c.n_heads, c.head_dim)
+            wq3 = lp["wq"]["w"].astype(dt).reshape(-1, heads, c.head_dim)
             wk3 = lp["wk"]["w"].astype(dt).reshape(-1, c.n_kv_heads, c.head_dim)
             wv3 = lp["wv"]["w"].astype(dt).reshape(-1, c.n_kv_heads, c.head_dim)
-            rope = (lambda a: _rope_bhtd(a, c.rope_theta, yarn)) if rotates \
-                else (lambda a: a)
-            q = rope(jnp.einsum("btd,dhx->bhtx", y, wq3))
-            k = rope(jnp.einsum("btd,dhx->bhtx", y, wk3))
+            q = rope(jnp.einsum("btd,dhx->bhtx", y, wq3), _rope_bhtd)
+            k = rope(jnp.einsum("btd,dhx->bhtx", y, wk3), _rope_bhtd)
             v = jnp.einsum("btd,dhx->bhtx", y, wv3)
             attn = flash_attention(
                 q, k, v, causal=True, scale=scale, mesh=mesh,
                 layout="bhtd", window=window,
             )
-            wo3 = lp["wo"]["w"].astype(dt).reshape(c.n_heads, c.head_dim, -1)
+            attn = gate(attn, "btd,dh->bht")
+            wo3 = lp["wo"]["w"].astype(dt).reshape(heads, c.head_dim, -1)
             h = h + jnp.einsum("bhtx,hxd->btd", attn, wo3)
         else:
-            q = (y @ lp["wq"]["w"].astype(dt)).reshape(b, t, c.n_heads, c.head_dim)
+            q = (y @ lp["wq"]["w"].astype(dt)).reshape(b, t, heads, c.head_dim)
             k = (y @ lp["wk"]["w"].astype(dt)).reshape(b, t, c.n_kv_heads, c.head_dim)
             v = (y @ lp["wv"]["w"].astype(dt)).reshape(b, t, c.n_kv_heads, c.head_dim)
-            if rotates:
-                q = _rope(q, c.rope_theta, yarn)
-                k = _rope(k, c.rope_theta, yarn)
+            q = rope(q, _rope)
+            k = rope(k, _rope)
             if seq_sharded:
                 # ring attention: the only exact option over a sharded sequence
                 attn = ring_attention(q, k, v, mesh, causal=True, scale=scale)
@@ -736,7 +890,7 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
                 attn = dense_attention(
                     q, k, v, causal=True, scale=scale, window=window,
                 )
-            attn = attn.reshape(b, t, c.q_dim)
+            attn = gate(attn, "btd,dh->bth").reshape(b, t, heads * c.head_dim)
             h = h + attn @ lp["wo"]["w"].astype(dt)
         return constrain_fwd(h, ["batch", "seq", "embed"])
 
@@ -771,7 +925,7 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
             y32.astype(h.dtype), router_in=y32,
             experts_per_token=c.experts_per_token,
             first_expert=c.first_expert, router_scale=c.router_scale,
-            compute_dtype=dt,
+            router_score=c.router_score, compute_dtype=dt,
             matmul_precision=c.matmul_precision, mesh=mesh)
         return constrain_fwd(h + out, ["batch", "seq", "embed"]), counters
 
@@ -819,7 +973,7 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
     def lead_layer(carry, lp):
         """A leading pair of a routed model: the dense feed-forward."""
         with jax.named_scope("lead"):
-            h = attend(carry, lp, c.layer_kinds[0])
+            h = attend(carry, lp, c.lead)
             with jax.named_scope("mlp"):
                 return mlp(h, lp), None
 
@@ -846,10 +1000,11 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
     # the scan walks periods; the kinds inside one are unrolled in its
     # body. A model of one kind is the plain scan over its layers.
     kinds = c.layer_kinds
-    if c.single_mixers:
+    if c.stacked_by_kind:
         # each kind's layers are a stack of their own: the body takes the
         # next layer of its kind as it walks the pattern
-        layers = {kind: mixer_of(kind) for kind in set(kinds)}
+        of_kind = mixer_of if c.single_mixers else layer_of
+        layers = {kind: of_kind(kind) for kind in set(kinds)}
 
         def period(carry, pp):
             taken, outs = dict.fromkeys(pp, 0), {}
@@ -865,7 +1020,9 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
             kind: jax.tree.map(
                 lambda a: a.reshape(-1, kinds.count(kind), *a.shape[1:]),
                 params["layers"][kind])
-            for kind in set(kinds)})
+            # in a fixed order: the program's text, and so its compile
+            # cache key, would otherwise follow the process's string hash
+            for kind in sorted(set(kinds))})
     elif len(kinds) == 1:
         x, counters = lax.scan(layer_of(kinds[0]), x, params["layers"])
     else:
@@ -977,28 +1134,35 @@ def param_count(config: Config) -> int:
     """The parameters held: of a routed model's experts, this share's."""
     c = config
     d = c.d_model
-    if c.latent:
-        r, h = c.kv_lora_rank, c.n_heads
-        attention = (d * h * c.qk_head_dim + d * (r + c.qk_rope_dim) + r
-                     + r * h * (c.qk_nope_dim + c.v_head_dim)
-                     + h * c.v_head_dim * d + d)
-    else:
-        attention = d * (c.q_dim + 2 * c.kv_dim) + c.q_dim * d + d
+
+    def attention(kind):
+        if c.latent:
+            r, h = c.kv_lora_rank, c.n_heads
+            return (d * h * c.qk_head_dim + d * (r + c.qk_rope_dim) + r
+                    + r * h * (c.qk_nope_dim + c.v_head_dim)
+                    + h * c.v_head_dim * d + d)
+        heads = c.attention(kind).heads
+        q = heads * c.head_dim
+        return (d * (q + 2 * c.kv_dim) + q * d + d
+                + (d * heads if c.head_gate else 0))
+
     matrices = 3 if c.experts_gated else 2  # of an expert, routed or shared
-    routed = (d * c.n_experts
-              + (c.n_experts if c.router_score == "sigmoid" else 0)
+    routed = (d * c.n_experts + (c.n_experts if _moe_form(c)["score_bias"]
+                                 else 0)
               + matrices * d * (c.experts_held * c.d_expert + c.d_shared)
               + d)
     if c.single_mixers:
         inner, conv, proj = mamba2.widths(c)
         mamba = (d + d * proj + conv * (c.conv_kernel + 1) + 3 * c.ssm_heads
                  + inner + inner * d)
-        per_kind = {"mamba": mamba, "attention": attention, "experts": routed}
+        per_kind = {"mamba": mamba, "attention": attention("attention"),
+                    "experts": routed}
         layers = sum(per_kind[kind] * count
                      for kind, count in _per_kind(c).items())
     else:
         dense = 3 * d * c.d_ff + d
-        layers = (c.n_dense_layers * (attention + dense)
-                  + (c.n_layers - c.n_dense_layers)
-                  * (attention + (routed if c.routed else dense)))
+        layers = (c.n_dense_layers * (attention(c.lead) + dense)
+                  + sum(count * (attention(kind)
+                                 + (routed if c.routed else dense))
+                        for kind, count in _per_kind(c).items()))
     return c.vocab * d + layers + d + d * c.vocab

@@ -5,9 +5,9 @@ the router scores, of which the ``w_up.shape[0]`` from ``first_expert`` on
 live here. It routes every token over all published experts
 (:func:`route`, in float32, by one of two score functions: softmax, the
 ``experts_per_token`` largest, their weights divided by their sum; or
-sigmoid, the largest of score plus a correction bias, weighed by the
-unbiased scores over their sum times a scale), and computes its own
-experts' part of the result:
+sigmoid, the largest of score (plus a correction bias, where the layer has
+one), weighed by the unbiased scores over their sum times a scale), and
+computes its own experts' part of the result:
 
     y[t] = sum over the chosen experts e held here of  w[t, e] * W_down[e] . h
     h = silu(W_gate[e] . x[t]) * (W_up[e] . x[t])      gated: three products
@@ -149,15 +149,15 @@ def logical_axes(gated: bool = True, shared: bool = False,
     return axes
 
 
-def route(x32, router_w, experts_per_token: int, *, bias=None,
-          scale: float = 1.0):
+def route(x32, router_w, experts_per_token: int, *, score: str = "softmax",
+          bias=None, scale: float = 1.0):
     """(weights [N, k] float32, experts [N, k] int32) over every published
-    expert, in float32, by one of two score functions.
+    expert, in float32, by one of two score functions, ``score``.
 
-    Softmax (no ``bias``): the k largest of the softmax, divided by their
-    sum (they sum to 1). Sigmoid (``bias`` [E], a correction that is no
-    parameter: it enters the choice alone, so nothing flows back to it):
-    the k largest of ``sigmoid(logits) + bias``, weighed by their unbiased
+    Softmax: the k largest of the softmax, divided by their sum (they sum
+    to 1). Sigmoid: the k largest of ``sigmoid(logits)``, plus ``bias`` [E]
+    where there is one (a correction that is no parameter: it enters the
+    choice alone, so nothing flows back to it), weighed by their unbiased
     scores over those scores' sum, times ``scale`` (they sum to ``scale``).
 
     The product is made at ``highest`` precision: a default float32 product
@@ -165,14 +165,20 @@ def route(x32, router_w, experts_per_token: int, *, bias=None,
     some token always lie within that rounding."""
     logits = jnp.matmul(x32.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    if bias is None:
+    if score not in ("softmax", "sigmoid") or (
+            bias is not None and score == "softmax"):
+        raise ValueError(f"score={score!r} with a bias: {bias is not None}; "
+                         "softmax, or sigmoid with or without a bias")
+    if score == "softmax":
         top_w, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1),
                                  experts_per_token)
         top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
         return (top_w if scale == 1.0 else top_w * scale), top_e
     scores = jax.nn.sigmoid(logits)
-    _, top_e = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
-                         experts_per_token)
+    _, top_e = lax.top_k(
+        scores if bias is None
+        else scores + lax.stop_gradient(bias.astype(jnp.float32)),
+        experts_per_token)
     top_w = jnp.take_along_axis(scores, top_e, axis=-1)
     return (top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20) * scale,
             top_e)
@@ -387,7 +393,8 @@ def _row_passes(r: int, d: int, f: int, held_here: int,
 
 
 def _share(x, router_in, w, first_expert, *, experts_per_token: int,
-           compute_dtype, matmul_precision: str, router_scale: float):
+           compute_dtype, matmul_precision: str, router_scale: float,
+           router_score: str):
     """One member's part: x [N, D] -> (y [N, D], this share's counts [G],
     assignments it could not give a row, rows the gathers in token order
     read).
@@ -405,7 +412,8 @@ def _share(x, router_in, w, first_expert, *, experts_per_token: int,
 
     with jax.named_scope("moe_router"):
         weights, experts = route(router_in, w["router"], k,
-                                 bias=w.get("bias"), scale=router_scale)
+                                 score=router_score, bias=w.get("bias"),
+                                 scale=router_scale)
         local = experts - first_expert
         held = jnp.logical_and(local >= 0, local < held_here)  # [N, k]
         # absent experts' assignments share one key past the held groups
@@ -490,14 +498,16 @@ def _shared(params: Params, x, dt):
 
 def apply(params: Params, x, *, experts_per_token: int, first_expert: int = 0,
           router_in=None, router_scale: float = 1.0,
+          router_score: str = "softmax",
           compute_dtype=jnp.bfloat16, matmul_precision: str = "bf16",
           mesh: Mesh = None):
     """x [B, T, D] -> (y [B, T, D], counters). ``params`` as :func:`init`
     gives them: the router over all published experts and the matrices of
     the experts held, ``first_expert`` on. What the tree holds says what the
-    layer is: a router ``bias`` the sigmoid score (else softmax), no
-    ``w_gate`` ungated ``relu ** 2`` experts, ``shared_up`` a shared expert
-    (gated where ``shared_gate`` is there too).
+    layer is: a router ``bias`` a correction in the sigmoid score's choice
+    (``router_score``: :func:`route`), no ``w_gate`` ungated ``relu ** 2``
+    experts, ``shared_up`` a shared expert (gated where ``shared_gate`` is
+    there too).
     ``router_in`` [B, T, D] is what the router scores where that differs
     from ``x`` (a float32 copy of a bf16 activation); ``router_scale``
     multiplies the weights; ``matmul_precision`` other than ``bf16`` sends
@@ -512,7 +522,7 @@ def apply(params: Params, x, *, experts_per_token: int, first_expert: int = 0,
     share = functools.partial(
         _share, experts_per_token=experts_per_token,
         compute_dtype=compute_dtype, matmul_precision=matmul_precision,
-        router_scale=router_scale)
+        router_scale=router_scale, router_score=router_score)
     batch_axes, experts_spread = _mesh_axes(mesh)
 
     if not batch_axes and not experts_spread:

@@ -108,3 +108,27 @@ def test_a_window_needs_causal_attention():
         flash_attention(q, k, v, causal=False, window=8)
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, k, v, causal=True, window=0)
+
+
+@pytest.mark.parametrize("heads", [6, 8])
+def test_groups_of_six_and_eight_under_a_window_of_512(heads):
+    """The gated-heads configuration's attention: 48 or 64 query heads over
+    8 KV heads (groups 6 and 8) and a window of 512 keys, here one KV head's
+    group over 1024 positions in tiles of 256 (the window two tiles wide):
+    the forward and the three gradients against the dense banded mask."""
+    q, k, v = _qkv(jax.random.PRNGKey(heads), t=1024, h=heads, hkv=1)
+    cot = jax.random.normal(jax.random.PRNGKey(3), q.shape, jnp.float32)
+
+    def through(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) * cot),
+            argnums=(0, 1, 2))(q, k, v)
+
+    want = through(lambda q, k, v: _banded_by_hand(q, k, v, 512))
+    got = through(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=512, block_q=256, block_k=256,
+        interpret=True))
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5)
+    for name, a, b in zip("qkv", got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5,
+                                   err_msg=f"d{name}")

@@ -10,6 +10,7 @@ from benchmark.tests.test_nemotron_h import *  # noqa: F401,F403
 from benchmark.tests.test_deepseek_v3 import *  # noqa: F401,F403
 from benchmark.tests.test_compile_records import *  # noqa: F401,F403
 from benchmark.tests.test_token_sum_readers import *  # noqa: F401,F403
+from benchmark.tests.test_laguna import *  # noqa: F401,F403
 
 
 def test_its_entry_is_the_last_and_sits_beside_ckpt_open_s():  # noqa: F811
@@ -173,3 +174,41 @@ def test_the_eight_entries_are_appended_and_reported_everywhere():  # noqa: F811
     assert {"trainer", "worker bootstrap", "step loop"} <= layers
     # what follows them lists its cells: no other metric is everywhere
     assert all("workloads" in m for m in per_layer[first + 8:])
+
+
+def test_the_two_entries_are_appended_for_the_routed_cells():  # noqa: F811
+    """A stopgap, and it says when to go. ``test_token_sum_readers``'s test
+    of this name pins the token sums' two readers as ``per_layer``'s last,
+    which the gated-heads cell's three readers, appended after them, leave
+    untrue, and a change may edit no file of the benchmark: this takes its
+    place in tier-1 until a ``benchmark`` issue corrects the original to
+    "contiguous, in order, unchanged" (PERF.md section 7). It holds the two
+    as the original does, and it fails once the original passes again, so
+    that it cannot outlive its reason."""
+    import json
+    import os
+
+    import pytest
+
+    from benchmark.tests import test_token_sum_readers as original
+
+    with pytest.raises(AssertionError):
+        # corrected? then delete this function: the import above runs it
+        original.test_the_two_entries_are_appended_for_the_routed_cells()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    metrics = spec["per_layer"]
+    names = [m["name"] for m in metrics]
+    first = names.index(original.TOKEN_SUM_READERS[0])
+    two = metrics[first:first + 2]
+    assert tuple(m["name"] for m in two) == original.TOKEN_SUM_READERS
+    for m, unit, source in zip(two, ("ms", "%"),
+                               ("device_trace", "program_counter")):
+        assert m == {"name": m["name"], "unit": unit, "better": "lower",
+                     "source": source, "layer": "routed feed-forward",
+                     "moves": "tokens_per_s_per_chip",
+                     "workloads": original.ROUTED_CELLS}
+    # what follows them is the gated-heads cell's, and lists that cell
+    assert all(m["workloads"] == ["lagunaxs2.steady-8k"]
+               for m in metrics[first + 2:])

@@ -265,6 +265,30 @@ def test_products_768_wide_compile_for_a_v5e(one_chip, k, n):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
+@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)])
+def test_the_flash_kernels_compile_for_a_v5e_at_the_gated_cells_heads(
+        one_chip, heads, window):
+    """``lagunaxs2.steady-8k``'s attention: 2 rows x 8192 positions, 48
+    query heads over 8 KV heads (group 6) causal on full layers, 64 over 8
+    (group 8) under a 512-key band on window layers, heads of 128; forward,
+    ``dq`` and ``dk`` / ``dv`` at the 1024 x 1024 tiles, each one Mosaic
+    call under its name."""
+    fa = importlib.import_module("mpi_operator_tpu.kernels.flash_attention")
+    spec = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=one_chip)
+
+    def all_three(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: fa._flash(
+            q, k, v, True, 128 ** -0.5, 1024, 1024, False, window), q, k, v)
+        return o, vjp(do)
+
+    q, kv = spec(2, heads, 8192, 128), spec(2, 8, 8192, 128)
+    text = jax.jit(all_three).lower(q, kv, kv, q).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert re.search(name + r"_*\.\d", text), name
+
+
 @pytest.mark.parametrize("d,dv", [(192, 128), (128, 128)])
 def test_the_flash_kernels_compile_for_a_v5e_at_unlike_head_sizes(
         one_chip, d, dv):
@@ -393,7 +417,9 @@ def test_on_the_kernel_path_the_layer_gathers_no_row_of_every_assignment(
     put = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
     x = jax.ShapeDtypeStruct((2, n // 2, d), jnp.bfloat16, sharding=one_chip)
     loss = lambda p, x: jnp.sum(moe.apply(
-        p, x, experts_per_token=k)[0].astype(jnp.float32) ** 2)
+        p, x, experts_per_token=k,
+        router_score="softmax" if gated else "sigmoid")[0]
+        .astype(jnp.float32) ** 2)
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         jax.tree.map(put, p), x).compile().as_text()
     assert re.search(r"moe_token_sum_*\.\d", text)

@@ -614,3 +614,142 @@ def test_bf16_in_the_latent_programs_place_fails_it(latent_reference):
     loss, got = _latent_step({AXIS_DATA: 1}, "bfloat16")
     assert abs(loss - want_loss) / want_loss > 2e-6
     assert _worst_leaf(got, want) > 1e-2
+
+
+# -- query heads, theta and rotation by kind; the head gate; a leading dense
+# pair before a period of several kinds ------------------------------------
+
+@pytest.fixture(scope="module")
+def gated():
+    cfg = dataclasses.replace(llama.tiny_gated(), compute_dtype=jnp.float32)
+    return cfg, llama.init(cfg, jax.random.PRNGKey(0))
+
+
+def test_pairs_of_unlike_heads_stack_by_kind_behind_the_lead(gated):
+    cfg, params = gated
+    assert cfg.stacked_by_kind and cfg.lead == "full"
+    layers = params["layers"]
+    assert set(layers) == {"full", "window"}
+    shape = lambda tree, name: tree[name]["w"].shape
+    # 4 query heads on full layers, 6 on window layers, over 2 KV heads
+    assert shape(layers["window"], "wq") == (3, 48, 6 * 16)
+    assert shape(layers["window"], "wo") == (3, 6 * 16, 48)
+    assert shape(layers["window"], "wg") == (3, 48, 6)
+    assert shape(layers["window"], "wk") == (3, 48, 2 * 16)
+    assert shape(layers["full"], "wq") == (1, 48, 4 * 16)
+    assert shape(layers["full"], "wg") == (1, 48, 4)
+    assert shape(params["lead"], "wq") == (1, 48, 4 * 16)
+    assert shape(params["lead"], "wg") == (1, 48, 4)
+    assert shape(params["lead"], "w_gate") == (1, 48, 96)
+    assert shape(layers["full"], "w_gate") == (1, 4, 48, 24)
+    assert "bias" not in layers["window"]["router"]  # sigmoid, no bias
+    assert llama.param_count(cfg) == sum(
+        a.size for a in jax.tree.leaves(params))
+    axes = llama.logical_axes(cfg)
+    assert axes["layers"]["window"]["wg"]["w"] == (None, "embed", "heads")
+    jax.tree.map(lambda a, ax: None if a.ndim == len(ax) else 1 / 0,
+                 params, axes, is_leaf=lambda x: isinstance(x, tuple))
+
+
+def test_the_gated_flash_and_dense_paths_agree(gated):
+    cfg, params = gated
+    dense = dataclasses.replace(cfg, attention_impl="dense")
+    np.testing.assert_allclose(
+        llama.apply(cfg, params, TOKENS), llama.apply(dense, params, TOKENS),
+        atol=1e-5, rtol=1e-5)
+
+
+def test_the_gate_and_each_kinds_rope_reach_the_attention(gated):
+    """Each of the by-kind settings moves the logits: the gate (a model
+    without it reads other logits), the full layers' theta and rotated
+    share, the window layers' heads being their own stack."""
+    cfg, params = gated
+    base = llama.apply(cfg, params, TOKENS)
+    moved = lambda c, p=params: float(jnp.max(jnp.abs(
+        llama.apply(c, p, TOKENS) - base)))
+    ungated = jax.tree.map(lambda a: a, params)
+    for tree in (ungated["lead"], *ungated["layers"].values()):
+        del tree["wg"]
+    assert moved(dataclasses.replace(cfg, head_gate=False), ungated) > 1e-3
+    full = lambda **change: dataclasses.replace(cfg, attention_by_kind=tuple(
+        (k, a._replace(**change) if k == "full" else a)
+        for k, a in cfg.attention_by_kind))
+    assert moved(full(theta=0.0)) > 1e-3  # the plain theta
+    assert moved(full(rotated=0)) > 1e-3  # the whole head
+    yarn = dataclasses.replace(cfg.yarn_full, attention_factor=1.0)
+    assert moved(dataclasses.replace(cfg, yarn_full=yarn)) > 1e-3
+
+
+def test_a_partial_rotation_turns_the_first_dims_and_scales_them_alone():
+    """The first ``rotated`` dims turn at frequencies over those dims alone
+    and carry YaRN's factor on cos and sin; the rest pass untouched and
+    unscaled. A whole head keeps the factor off its tables (it is on the
+    scores' scale there)."""
+    yarn = llama.Yarn(factor=4.0, original_len=16, beta_fast=8.0,
+                      attention_factor=1.2)
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 5, 3, 16))
+    got = llama._rope_first(llama._rope, x, 8, 5e5, yarn)
+    np.testing.assert_array_equal(got[..., 8:], x[..., 8:])
+    cos, sin = llama._rope_tables(5, 8, 5e5, jnp.float32, yarn)
+    x1, x2 = x[..., :4], x[..., 4:8]
+    c, s = cos[None, :, None], sin[None, :, None]
+    np.testing.assert_allclose(
+        got[..., :8], 1.2 * jnp.concatenate([x1 * c - x2 * s,
+                                             x1 * s + x2 * c], -1),
+        rtol=1e-6, atol=1e-6)
+    whole = llama._rope_first(llama._rope, x, 16, 5e5, yarn)
+    np.testing.assert_allclose(whole, llama._rope(x, 5e5, yarn), rtol=1e-6)
+    # the heads-major layout turns the same numbers
+    np.testing.assert_allclose(
+        llama._rope_first(llama._rope_bhtd, x.transpose(0, 2, 1, 3), 8, 5e5,
+                          yarn).transpose(0, 2, 1, 3), got, rtol=1e-6)
+
+
+@pytest.mark.parametrize("change,match", [
+    # a lead before a period of several kinds names its kind
+    (dict(lead_kind=""), "n_dense_layers"),
+    (dict(lead_kind="latent"), "n_dense_layers"),
+    (dict(attention_by_kind=(("window", llama.KindAttention(heads=5)),)),
+     "window attention"),
+    (dict(attention_by_kind=(("full", llama.KindAttention(rotated=5)),)),
+     "full attention"),
+    (dict(attention_by_kind=(("full", llama.KindAttention(theta=-1.0)),)),
+     "full attention"),
+    (dict(attention_by_kind=(("latent", llama.KindAttention(heads=4)),)),
+     "by kind"),
+    (dict(n_experts=0, n_experts_held=0), "n_dense_layers"),
+])
+def test_config_refuses_the_gated_shapes_it_does_not_compute(change, match):
+    with pytest.raises(ValueError, match=match):
+        dataclasses.replace(llama.tiny_gated(), **change)
+    with pytest.raises(ValueError, match="by kind"):
+        dataclasses.replace(llama.tiny_latent(), head_gate=True)
+
+
+@pytest.mark.parametrize("name", ["tiny_hybrid", "tiny_gated"])
+def test_a_stack_by_kind_lowers_to_one_text_whatever_the_string_hash(name):
+    """The kinds' stacks enter the scan in a fixed order: without it the
+    step's text, and so its compile cache key, followed the process's
+    string hash, and a fresh process missed the entry another wrote."""
+    import subprocess
+    import sys as _sys
+
+    code = (
+        "import dataclasses, hashlib, jax, jax.numpy as jnp\n"
+        "from mpi_operator_tpu.models import llama\n"
+        f"cfg = llama.{name}()\n"
+        "p = jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0)))\n"
+        "t = jax.ShapeDtypeStruct((2, 32), jnp.int32)\n"
+        "text = jax.jit(lambda p, t: llama.loss_fn(cfg, p, {'tokens': t})[0]"
+        ").lower(p, t).as_text()\n"
+        "print(hashlib.sha256(text.encode()).hexdigest())\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    digests = set()
+    for seed in ("1", "2", "3"):
+        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=root)
+        out = subprocess.run([_sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        digests.add(out.stdout.strip())
+    assert len(digests) == 1

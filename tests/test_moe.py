@@ -489,6 +489,7 @@ def test_the_router_is_float32_whatever_the_activations(whole):
 # -- the other form: sigmoid scores, ungated experts, a shared expert --------
 
 E2, K2, SCALE = 128, 6, 2.5
+SIGMOID = {"router_score": "sigmoid", "router_scale": SCALE}
 
 
 @pytest.fixture(scope="module")
@@ -536,8 +537,8 @@ def _loop2(p, x, first=0, shared=True):
 def test_the_sigmoid_route_is_the_published_rule(whole_sigmoid):
     p, x = whole_sigmoid
     xf = x.reshape(-1, D)
-    w, e = moe.route(xf, p["router"]["w"], K2, bias=p["router"]["bias"],
-                     scale=SCALE)
+    w, e = moe.route(xf, p["router"]["w"], K2, score="sigmoid",
+                     bias=p["router"]["bias"], scale=SCALE)
     want_w, want_e = _plain_route(xf, p)
     np.testing.assert_array_equal(e, want_e)
     np.testing.assert_allclose(w, want_w, rtol=1e-6)
@@ -545,7 +546,7 @@ def test_the_sigmoid_route_is_the_published_rule(whole_sigmoid):
     # the bias changes the choice and not the weight: without it other
     # experts are chosen for many tokens, and an expert chosen either way
     # has the weight its unbiased score gives among its companions
-    _, unbiased = moe.route(xf, p["router"]["w"], K2,
+    _, unbiased = moe.route(xf, p["router"]["w"], K2, score="sigmoid",
                             bias=jnp.zeros((E2,)), scale=SCALE)
     changed = jnp.any(jnp.sort(e, -1) != jnp.sort(unbiased, -1), axis=-1)
     assert 0.2 < float(jnp.mean(changed)) < 1.0
@@ -555,16 +556,50 @@ def test_the_sigmoid_route_is_the_published_rule(whole_sigmoid):
         w, picked / jnp.sum(picked, -1, keepdims=True) * SCALE, rtol=1e-6)
     # a buffer: nothing flows back to it
     grad = jax.grad(lambda b: jnp.sum(moe.route(
-        xf, p["router"]["w"], K2, bias=b, scale=SCALE)[0] ** 2))(
+        xf, p["router"]["w"], K2, score="sigmoid", bias=b,
+        scale=SCALE)[0] ** 2))(
             p["router"]["bias"])
     assert not np.any(np.asarray(grad))
+
+
+def test_a_sigmoid_route_without_a_bias_chooses_by_score_alone(
+        whole_sigmoid):
+    """``score="sigmoid"`` with no correction bias: the k largest sigmoid
+    scores, weighed by themselves over their sum times the scale; and the
+    layer takes that rule from ``router_score`` where its tree has no bias.
+    The score is always said: a bias with the softmax is refused."""
+    p, x = whole_sigmoid
+    xf = x.reshape(-1, D)
+    w, e = moe.route(xf, p["router"]["w"], K2, scale=SCALE, score="sigmoid")
+    s = jax.nn.sigmoid(jnp.matmul(xf, p["router"]["w"], precision="highest"))
+    np.testing.assert_array_equal(
+        jnp.sort(e, -1), jnp.sort(jnp.argsort(-s, axis=-1)[:, :K2], -1))
+    picked = jnp.take_along_axis(s, e, axis=-1)
+    np.testing.assert_allclose(
+        w, picked / jnp.sum(picked, -1, keepdims=True) * SCALE, rtol=1e-6)
+    # the same as a bias of nought, and not the softmax's rule
+    zero = moe.route(xf, p["router"]["w"], K2, score="sigmoid",
+                     bias=jnp.zeros((E2,)), scale=SCALE)
+    np.testing.assert_array_equal(e, zero[1])
+    np.testing.assert_allclose(w, zero[0], rtol=1e-6)
+    soft_w, _ = moe.route(xf, p["router"]["w"], K2, scale=SCALE)
+    assert float(jnp.max(jnp.abs(soft_w - w))) > 1e-2
+    unbiased = dict(p, router={"w": p["router"]["w"]})
+    got, _ = moe.apply(unbiased, x, experts_per_token=K2, router_scale=SCALE,
+                       router_score="sigmoid", **F32)
+    want, _ = moe.apply(dict(p, router={"w": p["router"]["w"],
+                                        "bias": jnp.zeros((E2,))}),
+                        x, experts_per_token=K2, **SIGMOID, **F32)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    with pytest.raises(ValueError, match="softmax, or sigmoid"):
+        moe.route(xf, p["router"]["w"], K2, bias=jnp.zeros((E2,)))
 
 
 def test_the_ungated_layer_with_its_shared_expert_is_the_plain_loop(
         whole_sigmoid):
     p, x = whole_sigmoid
     apply = lambda p, x: moe.apply(p, x, experts_per_token=K2,
-                                   router_scale=SCALE, **F32)[0]
+                                   **SIGMOID, **F32)[0]
     np.testing.assert_allclose(apply(p, x), _loop2(p, x), atol=2e-5,
                                rtol=2e-5)
     cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
@@ -586,7 +621,7 @@ def test_sixteen_shares_and_the_shared_expert_once_add_up(whole_sigmoid):
     for first in range(0, E2, each):
         y, counters = moe.apply(
             _share2(p, first, each, shared=False), x, first_expert=first,
-            experts_per_token=K2, router_scale=SCALE, **F32)
+            experts_per_token=K2, **SIGMOID, **F32)
         np.testing.assert_allclose(
             y, _loop2(_share2(p, first, each), x, first, shared=False),
             atol=1e-5, rtol=1e-5)
@@ -595,13 +630,13 @@ def test_sixteen_shares_and_the_shared_expert_once_add_up(whole_sigmoid):
     relu2 = lambda a: jnp.square(jax.nn.relu(a))
     total = total + (relu2(xf @ p["shared_up"]["w"])
                      @ p["shared_down"]["w"]).reshape(x.shape)
-    whole, _ = moe.apply(p, x, experts_per_token=K2, router_scale=SCALE, **F32)
+    whole, _ = moe.apply(p, x, experts_per_token=K2, **SIGMOID, **F32)
     np.testing.assert_allclose(total, whole, atol=3e-5, rtol=3e-5)
     np.testing.assert_allclose(total, _loop2(p, x), atol=3e-5, rtol=3e-5)
     assert held == 2 * 24 * K2
     # one share with the shared expert is that share plus the expert
     one, _ = moe.apply(_share2(p, 8, each), x, first_expert=8,
-                       experts_per_token=K2, router_scale=SCALE, **F32)
+                       experts_per_token=K2, **SIGMOID, **F32)
     np.testing.assert_allclose(one, _loop2(_share2(p, 8, each), x, 8),
                                atol=1e-5, rtol=1e-5)
 
@@ -613,7 +648,7 @@ def test_over_a_mesh_the_shared_expert_is_added_once(whole_sigmoid, axes):
     n = int(np.prod(list(axes.values())))
     mesh = build_mesh(MeshPlan(axes=axes), jax.devices()[:n])
     y, _ = jax.jit(lambda p, x: moe.apply(
-        p, x, experts_per_token=K2, router_scale=SCALE, mesh=mesh, **F32))(p, x)
+        p, x, experts_per_token=K2, **SIGMOID, mesh=mesh, **F32))(p, x)
     np.testing.assert_allclose(y, _loop2(p, x), atol=3e-5, rtol=3e-5)
 
 
@@ -637,7 +672,7 @@ def bounded_ungated(request):
     def layer_and_gradients():
         apply = lambda p, x: moe.apply(
             p, x.astype(dtype), first_expert=2, experts_per_token=K,
-            router_scale=SCALE, compute_dtype=dtype,
+            **SIGMOID, compute_dtype=dtype,
             router_in=x)[0].astype(jnp.float32)
         y, vjp = jax.vjp(apply, p, x)
         d_p, d_x = vjp(cot)
@@ -668,6 +703,7 @@ def test_bounded_and_poisoned_the_ungated_layer_is_the_same(
 # -- gated experts beside a gated shared expert: the latent-attention model's --
 
 E3, K3, SCALE3, F3 = 128, 6, 2.448, 24
+SIGMOID3 = {"router_score": "sigmoid", "router_scale": SCALE3}
 
 
 @pytest.fixture(scope="module")
@@ -743,7 +779,7 @@ def test_the_gated_layer_with_its_gated_shared_expert_is_the_plain_reference(
         whole_gated_sigmoid):
     p, x = whole_gated_sigmoid
     apply = lambda p, x: moe.apply(p, x, experts_per_token=K3,
-                                   router_scale=SCALE3, **F32)[0]
+                                   **SIGMOID3, **F32)[0]
     np.testing.assert_allclose(apply(p, x), _reference_layer(p, x),
                                atol=2e-5, rtol=2e-5)
     cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
@@ -769,7 +805,7 @@ def test_eight_shares_and_the_gated_shared_expert_once_add_up(
     for first in range(0, E3, each):
         y, counters = moe.apply(
             _share3(p, first, each, shared=False), x, first_expert=first,
-            experts_per_token=K3, router_scale=SCALE3, **F32)
+            experts_per_token=K3, **SIGMOID3, **F32)
         np.testing.assert_allclose(
             y, _reference_layer(p, x, first, each, shared=False),
             atol=1e-5, rtol=1e-5)
@@ -779,13 +815,13 @@ def test_eight_shares_and_the_gated_shared_expert_once_add_up(
     total = total + shared
     np.testing.assert_allclose(total, _reference_layer(p, x), atol=3e-5,
                                rtol=3e-5)
-    whole, _ = moe.apply(p, x, experts_per_token=K3, router_scale=SCALE3,
+    whole, _ = moe.apply(p, x, experts_per_token=K3, **SIGMOID3,
                          **F32)
     np.testing.assert_allclose(total, whole, atol=3e-5, rtol=3e-5)
     assert held == 2 * 24 * K3
     # one share with the shared expert is that share plus the expert, once
     one, _ = moe.apply(_share3(p, 16, each), x, first_expert=16,
-                       experts_per_token=K3, router_scale=SCALE3, **F32)
+                       experts_per_token=K3, **SIGMOID3, **F32)
     np.testing.assert_allclose(
         one, _reference_layer(p, x, 16, each), atol=1e-5, rtol=1e-5)
 
@@ -798,7 +834,7 @@ def test_over_a_mesh_the_gated_shared_expert_is_added_once(
     n = int(np.prod(list(axes.values())))
     mesh = build_mesh(MeshPlan(axes=axes), jax.devices()[:n])
     y, _ = jax.jit(lambda p, x: moe.apply(
-        p, x, experts_per_token=K3, router_scale=SCALE3, mesh=mesh,
+        p, x, experts_per_token=K3, **SIGMOID3, mesh=mesh,
         **F32))(p, x)
     np.testing.assert_allclose(y, _reference_layer(p, x), atol=3e-5,
                                rtol=3e-5)

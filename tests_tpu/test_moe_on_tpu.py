@@ -254,7 +254,8 @@ def test_the_gated_shared_expert_at_the_kanana_shape():
     size = float(jnp.sqrt(jnp.mean(want ** 2)))
     assert gap < 1e-2 * size, (gap, size)
     both = lambda p: jax.jit(lambda p, x: moe.apply(
-        p, x, experts_per_token=6, router_scale=2.448)[0])(p, x)
+        p, x, experts_per_token=6, router_scale=2.448,
+        router_score="sigmoid")[0])(p, x)
     routed = {n: v for n, v in p.items() if not n.startswith("shared_")}
     added = both(p).astype(jnp.float32) - both(routed).astype(jnp.float32)
     gap = float(jnp.sqrt(jnp.mean((added - want) ** 2)))

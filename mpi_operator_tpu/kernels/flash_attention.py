@@ -17,6 +17,14 @@ whole-width block. bf16 operands on the MXU,
 f32 accumulation in VMEM scratch that persists across the innermost grid
 dimension; outputs are written on that dimension's final step.
 
+A sliding window (query i sees the ``window`` keys up to its own) changes
+the grids, not the arithmetic: their innermost axis walks only the tiles
+the band opens, each step the band's first tile plus the step, where the
+causal grids walk every tile and skip the dead ones. The window's tile is
+square, half the window, at least 512 and at most 1024 (``_band_tile``):
+a 512-key band then computes 1,024 keys a query, against 2,048 in the
+1024² tiles every other call keeps.
+
 Backward is FlashAttention-2-style: the forward additionally emits the
 log-sum-exp rows, and the backward recomputes probabilities blockwise
 on-chip to produce dq (grid over q tiles × streamed K/V) and dk/dv (grid
@@ -41,12 +49,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
-
-
-def _window_kw(window):
-    """The kernels' ``window`` keyword, only where there is one: without it
-    the partials, and so the Mosaic bodies, are what they were."""
-    return {} if window is None else {"window": int(window)}
 
 
 def _pad_t(x, t_pad: int):
@@ -82,78 +84,143 @@ def _causal_first_q_tile(ki, bq: int, bk: int):
 # A window adds the band's other side: query i sees key j iff
 # i - window < j <= i. A tile is then computed iff it is causally open AND
 # (ki + 1) * bk + window - 1 > qi * bq ("band open"): the tile's last key
-# lies inside the window of the tile's first query. The clamps below are
-# the same inequality solved for ki and for qi.
-
-
-def _band_open(qi, ki, bq: int, bk: int, window: int):
-    """True iff k tile ki reaches into the window of q tile qi."""
-    return (ki + 1) * bk + window - 1 > qi * bq
+# lies inside the window of the tile's first query. A windowed call's grid
+# walks only the band: its innermost axis counts steps along it, not tiles,
+# and each step's tile is the band's first plus the step. The two helpers
+# below are the inequality solved for ki and for qi.
 
 
 def _band_first_k_tile(qi, bq: int, bk: int, window: int):
-    """Smallest ki with _band_open(qi, ki): the tile that holds the oldest
-    key the tile's first query sees."""
+    """Smallest ki with the band open: the tile that holds the oldest key
+    the tile's first query sees."""
     return jnp.maximum(qi * bq - window + 1, 0) // bk
 
 
 def _band_last_q_tile(ki, bq: int, bk: int, window: int):
-    """Largest qi with _band_open(qi, ki) (the caller clips it to the
-    grid): the tile of the last query that still sees the tile's last key."""
+    """Largest qi with the band open (the caller clips it to the grid): the
+    tile of the last query that still sees the tile's last key."""
     return ((ki + 1) * bk + window - 2) // bq
 
 
-def _tile_open(qi, ki, bq: int, bk: int, causal: bool, window):
-    """The kernels' compute-skip predicate; ``True`` (a Python bool) where
-    nothing is skipped."""
-    is_open = _causal_open(qi, ki, bq, bk) if causal else True
-    if window is not None:
-        is_open = jnp.logical_and(is_open, _band_open(qi, ki, bq, bk, window))
-    return is_open
+def _band_k_walk(qi, step, bq: int, bk: int, window: int, n_kb: int):
+    """(ki, live) at ``step`` of q tile qi's walk along the band (flash_fwd,
+    flash_dq): from the band's first k tile to the causal diagonal or the
+    sequence's last tile. A step past that is dead; its index repeats the
+    last live tile, so pallas elides its DMA."""
+    ki = _band_first_k_tile(qi, bq, bk, window) + step
+    last = jnp.minimum(_causal_last_k_tile(qi, bq, bk), n_kb - 1)
+    return jnp.minimum(ki, last), ki <= last
 
 
-def _k_tile_clamp(qi, ki, bq: int, bk: int, causal: bool, window):
+def _band_q_walk(ki, step, bq: int, bk: int, window: int, n_qb: int):
+    """(qi, live) at ``step`` of k tile ki's walk along the band
+    (flash_dkv): from the causal diagonal to the band's last q tile or the
+    sequence's last tile; dead steps as in ``_band_k_walk``."""
+    qi = _causal_first_q_tile(ki, bq, bk) + step
+    last = jnp.minimum(_band_last_q_tile(ki, bq, bk, window), n_qb - 1)
+    return jnp.minimum(qi, last), qi <= last
+
+
+def _band_steps(walk, n_outer: int, n_inner: int, *tiling):
+    """A band walk's grid length: the most live steps any outer tile has
+    (the walk is live from its first step on). Static: computed from the
+    tiling alone, also while the caller is being traced."""
+    with jax.ensure_compile_time_eval():
+        _, live = walk(jnp.arange(n_outer)[None, :],
+                       jnp.arange(n_inner)[:, None], *tiling)
+        return int(jnp.max(jnp.sum(live, axis=0)))
+
+
+def _band_tile(window: int, t: int) -> int:
+    """A windowed call's square tile: half the window, at least 512 and at
+    most 1024 keys, and no longer than the sequence. A 512-key window then
+    computes 1,024 keys a query where 1024² tiles computed 2,048, and a
+    1024-key window 1,536 for 3,072."""
+    return min(max(window // 2, 512), 1024, t)
+
+
+def _k_sweep(bq: int, bk: int, n_qb: int, n_kb: int, causal: bool, window,
+             g: int):
+    """The innermost axis of flash_fwd's and flash_dq's grids: (its length,
+    the K/V tiles' index map, the kernels' window keywords). Without a
+    window every k tile, and the partials, and so the Mosaic bodies, are
+    what they were; with one, the band walk's steps."""
+    if window is None:
+        def kv_index(bi, hi, qi, ki):
+            return (bi, hi // g, _k_tile_clamp(qi, ki, bq, bk, causal), 0)
+        return n_kb, kv_index, {}
+    walk = (bq, bk, int(window), n_kb)
+    steps = _band_steps(_band_k_walk, n_qb, n_kb, *walk)
+
+    def kv_index(bi, hi, qi, step):
+        return (bi, hi // g, _band_k_walk(qi, step, *walk)[0], 0)
+    return steps, kv_index, {"window": int(window), "band_steps": steps}
+
+
+def _q_sweep(bq: int, bk: int, n_qb: int, n_kb: int, causal: bool, window):
+    """The innermost axis of flash_dkv's grid, as ``_k_sweep``: every q
+    tile, or the band walk's steps."""
+    if window is None:
+        def q_index(bi, hi, ki, qi):
+            return (bi, hi, _q_tile_clamp(ki, qi, bq, bk, causal), 0)
+        return n_qb, q_index, {}
+    walk = (bq, bk, int(window), n_qb)
+    steps = _band_steps(_band_q_walk, n_kb, n_qb, *walk)
+
+    def q_index(bi, hi, ki, step):
+        return (bi, hi, _band_q_walk(ki, step, *walk)[0], 0)
+    return steps, q_index, {"window": int(window), "band_steps": steps}
+
+
+def _tile_open(qi, ki, bq: int, bk: int, causal: bool):
+    """The full grid's compute-skip predicate; ``True`` (a Python bool)
+    where nothing is skipped."""
+    return _causal_open(qi, ki, bq, bk) if causal else True
+
+
+def _k_tile_clamp(qi, ki, bq: int, bk: int, causal: bool):
     """ki clamped to the tiles q tile qi uses: a clamped index repeats on
     skipped grid steps, so pallas elides their DMAs."""
     if causal:
         ki = jnp.minimum(ki, _causal_last_k_tile(qi, bq, bk))
-    if window is not None:
-        ki = jnp.maximum(ki, _band_first_k_tile(qi, bq, bk, window))
     return ki
 
 
-def _q_tile_clamp(ki, qi, bq: int, bk: int, causal: bool, window):
+def _q_tile_clamp(ki, qi, bq: int, bk: int, causal: bool):
     """qi clamped to the tiles k tile ki uses (dk/dv streams q tiles)."""
     if causal:
         qi = jnp.maximum(qi, _causal_first_q_tile(ki, bq, bk))
-    if window is not None:
-        qi = jnp.minimum(qi, _band_last_q_tile(ki, bq, bk, window))
     return qi
 
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     block_q: int, block_k: int, n_kb: int, causal: bool, scale: float,
-    t_real: int, window=None,
+    t_real: int, window=None, band_steps=None,
 ):
     """One grid step folds one (q-tile, k-tile) pair. Grid (b, h, qi, ki),
-    ki innermost: the f32 scratch (acc, m, l) carries the online softmax
-    across a q-tile's k sweep; o/lse are written on the sweep's last step.
+    ki innermost (with a window: (b, h, qi, step), the band's k tiles): the
+    f32 scratch (acc, m, l) carries the online softmax across a q-tile's k
+    sweep; o/lse are written on the sweep's last step.
     Refs: q [1,1,BQ,D], k [1,1,BK,D], v [1,1,BK,Dv], o [1,1,BQ,Dv],
     lse [1,1,BQ,1]."""
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # causal: tiles strictly above the diagonal contribute nothing; skip
-    # their compute (the matching index-map clamp elides their DMAs too).
-    # A window skips the tiles wholly older than the band the same way.
-    diag_open = _tile_open(qi, ki, block_q, block_k, causal, window)
+    if window is None:
+        # causal: tiles strictly above the diagonal contribute nothing; skip
+        # their compute (the matching index-map clamp elides their DMAs too)
+        ki, n_steps = step, n_kb
+        diag_open = _tile_open(qi, ki, block_q, block_k, causal)
+    else:
+        ki, diag_open = _band_k_walk(qi, step, block_q, block_k, window, n_kb)
+        n_steps = band_steps
 
     @pl.when(diag_open)
     def _fold():
@@ -187,7 +254,7 @@ def _fwd_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(ki == n_kb - 1)
+    @pl.when(step == n_steps - 1)
     def _emit():
         l = l_ref[:, 0]
         # fully-masked rows (q padding) have l == 0; emit 0, not NaN
@@ -210,7 +277,13 @@ def _flash_fwd(
     bk = min(block_k, t)
     n_qb = pl.cdiv(t, bq)
     n_kb = pl.cdiv(t, bk)
-    grid = (b, h, n_qb, n_kb)
+    # causal: a k tile strictly above the diagonal is skipped by the kernel
+    # (pl.when) — clamping its block index to the last USED tile makes the
+    # index map repeat, so pallas elides the DMA too. ~2x less K/V traffic
+    # at long T (the causally-dead half of the rectangle grid). A window
+    # walks the band's tiles alone.
+    n_steps, kv_index, band_kw = _k_sweep(bq, bk, n_qb, n_kb, causal, window, g)
+    grid = (b, h, n_qb, n_steps)
 
     # zero-pad to block multiples: an edge tile's OOB region is otherwise
     # undefined memory, and 0·NaN = NaN leaks through masked weights in the
@@ -223,16 +296,8 @@ def _flash_fwd(
 
     kernel = functools.partial(
         _fwd_kernel, block_q=bq, block_k=bk, n_kb=n_kb, causal=causal,
-        scale=scale, t_real=t, **_window_kw(window),
+        scale=scale, t_real=t, **band_kw,
     )
-
-    # causal: a k tile strictly above the diagonal is skipped by the kernel
-    # (pl.when) — clamping its block index to the last USED tile makes the
-    # index map repeat, so pallas elides the DMA too. ~2x less K/V traffic
-    # at long T (the causally-dead half of the rectangle grid). A window
-    # clamps from below too: only the band's tiles are ever fetched.
-    def kv_index(bi, hi, qi, ki):
-        return (bi, hi // g, _k_tile_clamp(qi, ki, bq, bk, causal, window), 0)
 
     o, lse = pl.pallas_call(
         kernel,
@@ -264,19 +329,25 @@ def _flash_fwd(
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref, *,
     block_q: int, block_k: int, n_kb: int, causal: bool, scale: float,
-    t_real: int, window=None,
+    t_real: int, window=None, band_steps=None,
 ):
-    """dq: grid (b, h, qi, ki) streams K/V tiles past each q tile,
-    recomputing P on-chip from the saved LSE. Refs: q/dq [1,1,BQ,D],
-    k [1,1,BK,D], v [1,1,BK,Dv], do [1,1,BQ,Dv], lse/delta [1,1,BQ,1]."""
+    """dq: grid (b, h, qi, ki) streams K/V tiles past each q tile (with a
+    window, the band's: as the forward), recomputing P on-chip from the
+    saved LSE. Refs: q/dq [1,1,BQ,D], k [1,1,BK,D], v [1,1,BK,Dv],
+    do [1,1,BQ,Dv], lse/delta [1,1,BQ,1]."""
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    diag_open = _tile_open(qi, ki, block_q, block_k, causal, window)
+    if window is None:
+        ki, n_steps = step, n_kb
+        diag_open = _tile_open(qi, ki, block_q, block_k, causal)
+    else:
+        ki, diag_open = _band_k_walk(qi, step, block_q, block_k, window, n_kb)
+        n_steps = band_steps
 
     @pl.when(diag_open)
     def _fold():
@@ -310,7 +381,7 @@ def _bwd_dq_kernel(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(ki == n_kb - 1)
+    @pl.when(step == n_steps - 1)
     def _emit():
         dq_ref[0, 0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
@@ -319,21 +390,26 @@ def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc_ref, dv_acc_ref, *,
     block_q: int, block_k: int, n_qb: int, causal: bool, scale: float,
-    t_real: int, window=None,
+    t_real: int, window=None, band_steps=None,
 ):
-    """dk/dv: grid (b, h, ki, qi) streams Q/dO tiles past each k tile. GQA:
-    outputs are per *q* head; the wrapper group-sums to kv heads. Refs:
-    k/dk [1,1,BK,D], v/dv [1,1,BK,Dv], q [1,1,BQ,D], do [1,1,BQ,Dv],
-    lse/delta [1,1,BQ,1]."""
+    """dk/dv: grid (b, h, ki, qi) streams Q/dO tiles past each k tile (with
+    a window, (b, h, ki, step): the band's q tiles). GQA: outputs are per
+    *q* head; the wrapper group-sums to kv heads. Refs: k/dk [1,1,BK,D],
+    v/dv [1,1,BK,Dv], q [1,1,BQ,D], do [1,1,BQ,Dv], lse/delta [1,1,BQ,1]."""
     ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    step = pl.program_id(3)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    diag_open = _tile_open(qi, ki, block_q, block_k, causal, window)
+    if window is None:
+        qi, n_steps = step, n_qb
+        diag_open = _tile_open(qi, ki, block_q, block_k, causal)
+    else:
+        qi, diag_open = _band_q_walk(ki, step, block_q, block_k, window, n_qb)
+        n_steps = band_steps
 
     @pl.when(diag_open)
     def _fold():
@@ -370,7 +446,7 @@ def _bwd_dkv_kernel(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(qi == n_qb - 1)
+    @pl.when(step == n_steps - 1)
     def _emit():
         dk_ref[0, 0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
@@ -406,21 +482,18 @@ def _flash_bwd(
     v_p = _pad_t(v, n_kb * bk)
 
     # causally-skipped tiles: clamp the index map so the DMA is elided too
-    # (see the same trick in _flash_fwd)
-    def kv_index(bi, hi, qi, ki):
-        return (bi, hi // g, _k_tile_clamp(qi, ki, bq, bk, causal, window), 0)
-
-    def q_index_dkv(bi, hi, ki, qi):
-        qi = _q_tile_clamp(ki, qi, bq, bk, causal, window)
-        # the band's last q tile may lie past the grid at the sequence's end
-        return (bi, hi, jnp.minimum(qi, n_qb - 1) if window else qi, 0)
+    # (see the same trick in _flash_fwd); a window walks the band alone
+    k_steps, kv_index, k_band_kw = _k_sweep(
+        bq, bk, n_qb, n_kb, causal, window, g)
+    q_steps, q_index_dkv, q_band_kw = _q_sweep(
+        bq, bk, n_qb, n_kb, causal, window)
 
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, block_q=bq, block_k=bk, n_kb=n_kb,
-            causal=causal, scale=scale, t_real=t, **_window_kw(window),
+            causal=causal, scale=scale, t_real=t, **k_band_kw,
         ),
-        grid=(b, h, n_qb, n_kb),
+        grid=(b, h, n_qb, k_steps),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bk, d), kv_index),
@@ -442,9 +515,9 @@ def _flash_bwd(
     dk_h, dv_h = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, block_q=bq, block_k=bk, n_qb=n_qb,
-            causal=causal, scale=scale, t_real=t, **_window_kw(window),
+            causal=causal, scale=scale, t_real=t, **q_band_kw,
         ),
-        grid=(b, h, n_kb, n_qb),
+        grid=(b, h, n_kb, q_steps),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), q_index_dkv),
             pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, qi: (bi, hi // g, ki, 0)),
@@ -607,8 +680,8 @@ def flash_attention(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 1024,
-    block_k: int = 1024,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     mesh=None,
     batch_axes=("data", "fsdp"),
@@ -631,9 +704,19 @@ def flash_attention(
     explicitly to exercise the kernel body off-TPU (kernel tests do).
     Differentiable (Pallas flash backward).
 
+    ``block_q`` / ``block_k``: the tiles; ``None`` picks them, 1024 without
+    a window and ``_band_tile`` with one.
+
     ``window`` (with ``causal``): query i sees key j iff i - window < j <= i,
-    ``window`` keys with its own. All three kernels skip the k tiles (dk/dv:
-    the q tiles) on both sides of the band and mask inside its edge tiles."""
+    ``window`` keys with its own. The three kernels' grids then walk only
+    the band: the innermost axis of flash_fwd's and flash_dq's grid counts
+    the k tiles from the band's first (its oldest key) to the diagonal, and
+    flash_dkv's the q tiles from the diagonal to the band's last, so no grid
+    step is spent on a tile outside it; the kernels mask inside its edge
+    tiles. The tile is then square and sized from the window: half of it,
+    at least 512 and at most 1024 (``_band_tile``), so a band of 512 keys
+    computes two 512² tiles a q tile where the full grid's 1024² computed
+    two of four times the size."""
     if layout not in ("bthd", "bhtd"):
         raise ValueError(f"layout={layout!r}; expected bthd|bhtd")
     if window is not None and (not causal or window < 1):
@@ -649,6 +732,10 @@ def flash_attention(
     use_kernel = interpret is not None or jax.default_backend() == "tpu"
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    tile = 1024 if window is None else _band_tile(
+        window, q.shape[2 if heads_major else 1])
+    bq = tile if block_q is None else block_q
+    bk = tile if block_k is None else block_k
 
     def local(q_, k_, v_):
         if heads_major:
@@ -658,12 +745,12 @@ def flash_attention(
             kt = k_.transpose(0, 2, 1, 3)
             vt = v_.transpose(0, 2, 1, 3)
         if use_kernel:
-            o = _flash(qt, kt, vt, causal, scale, block_q, block_k, interpret,
-                       window)
+            o = _flash(qt, kt, vt, causal, scale, bq, bk, interpret, window)
         else:
+            # the reference's chunk bounds its memory; it is not a tile
             o = _chunked_reference(
-                qt, kt, vt, causal=causal, scale=scale, block_q=block_q,
-                window=window,
+                qt, kt, vt, causal=causal, scale=scale,
+                block_q=1024 if block_q is None else block_q, window=window,
             )
         return o if heads_major else o.transpose(0, 2, 1, 3)
 

@@ -271,15 +271,17 @@ def test_the_flash_kernels_compile_for_a_v5e_at_the_gated_cells_heads(
     """``lagunaxs2.steady-8k``'s attention: 2 rows x 8192 positions, 48
     query heads over 8 KV heads (group 6) causal on full layers, 64 over 8
     (group 8) under a 512-key band on window layers, heads of 128; forward,
-    ``dq`` and ``dk`` / ``dv`` at the 1024 x 1024 tiles, each one Mosaic
-    call under its name."""
+    ``dq`` and ``dk`` / ``dv`` at the tiles each call picks (1024 x 1024 on
+    full layers, 512 x 512 walking the band on window layers), each one
+    Mosaic call under its name."""
     fa = importlib.import_module("mpi_operator_tpu.kernels.flash_attention")
     spec = lambda *shape: jax.ShapeDtypeStruct(
         shape, jnp.bfloat16, sharding=one_chip)
+    tile = 1024 if window is None else fa._band_tile(window, 8192)
 
     def all_three(q, k, v, do):
         o, vjp = jax.vjp(lambda q, k, v: fa._flash(
-            q, k, v, True, 128 ** -0.5, 1024, 1024, False, window), q, k, v)
+            q, k, v, True, 128 ** -0.5, tile, tile, False, window), q, k, v)
         return o, vjp(do)
 
     q, kv = spec(2, heads, 8192, 128), spec(2, 8, 8192, 128)

@@ -114,13 +114,10 @@ def test_long_context_16k_trains():
         assert bool(jnp.all(jnp.isfinite(g.astype(jnp.float32))))
 
 
-def test_banded_kernels_at_the_mellum_shape_match_reference():
-    """The shape ``mellum2.steady-8k`` runs on a chip: b 2, 32 / 4 heads of
-    128, T 8192, window 1024 — the forward and the three gradients of the
-    banded kernels at their 1024 x 1024 tiles against the chunked
-    reference with the band as a mask on positions."""
-    q, k, v = _qkv(jax.random.PRNGKey(7), b=2, t=8192, h=32, hkv=4, d=128)
-    window = 1024
+def _banded_matches_reference(q, k, v, window):
+    """The forward and the three gradients of the banded kernels, walking
+    the band in the tiles the window picks, against the chunked reference
+    with the band as a mask on positions."""
     got = jax.jit(lambda *a: flash_attention(*a, causal=True, window=window))(
         q, k, v)
     want = jax.jit(lambda *a: chunked_reference(*a, window=window))(q, k, v)
@@ -147,6 +144,23 @@ def test_banded_kernels_at_the_mellum_shape_match_reference():
             np.asarray(b, np.float32) / scale,
             atol=5e-2, rtol=5e-2, err_msg=name,
         )
+
+
+def test_banded_kernels_at_the_mellum_shape_match_reference():
+    """The shape ``mellum2.steady-8k`` runs on a chip: b 2, 32 / 4 heads of
+    128, T 8192, window 1024, walked in 512 x 512 tiles."""
+    _banded_matches_reference(
+        *_qkv(jax.random.PRNGKey(7), b=2, t=8192, h=32, hkv=4, d=128), 1024)
+
+
+@pytest.mark.parametrize("heads", [48, 64])
+def test_banded_kernels_at_the_laguna_shape_match_reference(heads):
+    """The shape ``lagunaxs2.steady-8k`` runs on a chip: b 2, T 8192, 48
+    (full layers) or 64 (window layers) query heads of 128 over 8 KV heads,
+    window 512, walked in 512 x 512 tiles: two a q tile."""
+    _banded_matches_reference(
+        *_qkv(jax.random.PRNGKey(heads), b=2, t=8192, h=heads, hkv=8, d=128),
+        512)
 
 
 def test_latent_kernels_at_the_kanana_shape_match_reference():

@@ -48,6 +48,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mpi_operator_tpu.kernels.sparse_select import (
+    no_cotangent,
+    select_tile,
+    unpack,
+    words_to_mask,
+)
+
 _NEG_INF = -1e30
 
 
@@ -562,6 +569,318 @@ def _flash_bwd(
     return dq, dk, d_v
 
 
+# A selection (kernels/sparse_select.py) gives each query its own keys: the
+# three kernels below are the causal ones with the selection's bits read a
+# tile at a time beside K/V (or Q), in the selection's square tile, and a
+# tile that holds no selected key passed over (its flag, prefetched to
+# SMEM). A row's first tiles may hold none of its keys, so masked
+# probabilities are set to nought and not left to the running maximum.
+
+
+def _selected(words_ref, qi, ki, block: int, t_real: int):
+    """The tile's mask: selected, causal and inside the sequence."""
+    q_idx = qi * block + jax.lax.broadcasted_iota(
+        jnp.int32, (block, block), 0)
+    k_idx = ki * block + jax.lax.broadcasted_iota(
+        jnp.int32, (block, block), 1)
+    causal = jnp.logical_and(q_idx >= k_idx, k_idx < t_real)
+    return jnp.logical_and(unpack(words_ref[0], block, 0, block), causal)
+
+
+def _flag(flags_ref, bi, qi, ki, n: int):
+    return flags_ref[(bi * n + qi) * n + ki] > 0
+
+
+def _fwd_sel_kernel(
+    flags_ref, q_ref, k_ref, v_ref, words_ref, o_ref, lse_ref, acc_ref,
+    m_ref, l_ref, *, block: int, n: int, scale: float, t_real: int,
+):
+    """The forward over a selection: grid (b, h, qi, ki), as _fwd_kernel's
+    causal grid. Refs as there, and words [1, block/32, block]."""
+    bi, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(jnp.logical_and(_causal_open(qi, ki, block, block),
+                             _flag(flags_ref, bi, qi, ki, n)))
+    def _fold():
+        v = v_ref[0, 0]
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        sel = _selected(words_ref, qi, ki, block, t_real)
+        s = jnp.where(sel, s, _NEG_INF)
+        m_prev = m_ref[:, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        p = jnp.where(sel, jnp.exp(s - m_new[:, None]), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
+        m_ref[:, 0] = m_new
+        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(ki == n - 1)
+    def _emit():
+        l = l_ref[:, 0]
+        safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(safe[:, None])
+
+
+def _dq_sel_kernel(
+    flags_ref, q_ref, k_ref, v_ref, words_ref, do_ref, lse_ref, delta_ref,
+    dq_ref, acc_ref, *, block: int, n: int, scale: float, t_real: int,
+):
+    """dq over a selection: grid (b, h, qi, ki), as _bwd_dq_kernel's."""
+    bi, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(jnp.logical_and(_causal_open(qi, ki, block, block),
+                             _flag(flags_ref, bi, qi, ki, n)))
+    def _fold():
+        k = k_ref[0, 0]
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        sel = _selected(words_ref, qi, ki, block, t_real)
+        p = jnp.where(sel, jnp.exp(s - lse_ref[0, 0]), 0.0)
+        dp = jax.lax.dot_general(
+            do_ref[0, 0], v_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, 0])).astype(k.dtype)
+        acc_ref[...] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    @pl.when(ki == n - 1)
+    def _emit():
+        dq_ref[0, 0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+
+
+def _dkv_sel_kernel(
+    flags_ref, q_ref, k_ref, v_ref, words_ref, do_ref, lse_ref, delta_ref,
+    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, block: int, n: int,
+    scale: float, t_real: int,
+):
+    """dk/dv over a selection: grid (b, h, ki, qi), as _bwd_dkv_kernel's;
+    the words are the (qi, ki) tile's."""
+    bi, ki, qi = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
+        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
+
+    @pl.when(jnp.logical_and(_causal_open(qi, ki, block, block),
+                             _flag(flags_ref, bi, qi, ki, n)))
+    def _fold():
+        q = q_ref[0, 0]
+        do = do_ref[0, 0]
+        s = jax.lax.dot_general(
+            q, k_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        q_idx = qi * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 0)
+        sel = jnp.logical_and(_selected(words_ref, qi, ki, block, t_real),
+                              q_idx < t_real)
+        p = jnp.where(sel, jnp.exp(s - lse_ref[0, 0]), 0.0)
+        dv_acc_ref[...] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, 0])).astype(q.dtype)
+        dk_acc_ref[...] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    @pl.when(qi == n - 1)
+    def _emit():
+        dk_ref[0, 0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
+
+
+def _sel_specs(block: int, g: int, d: int, dv: int, q_major: bool):
+    """BlockSpecs of (q, k, v, words, do, lse, delta) over a selection's
+    grid: (b, h, qi, ki) for flash_fwd and flash_dq, (b, h, ki, qi) for
+    flash_dkv (``q_major`` False); the causally dead tiles' indices clamped
+    to a live one, so that their DMAs are elided."""
+    def ids(a, b_):
+        qi, ki = (a, b_) if q_major else (b_, a)
+        if q_major:
+            ki = _k_tile_clamp(qi, ki, block, block, True)
+        else:
+            qi = _q_tile_clamp(ki, qi, block, block, True)
+        return qi, ki
+
+    def q_row(bi, hi, a, b_, f):
+        return (bi, hi, ids(a, b_)[0], 0)
+
+    def kv_row(bi, hi, a, b_, f):
+        return (bi, hi // g, ids(a, b_)[1], 0)
+
+    def words(bi, hi, a, b_, f):
+        qi, ki = ids(a, b_)
+        return (bi, qi, ki)
+
+    return [pl.BlockSpec((1, 1, block, d), q_row),
+            pl.BlockSpec((1, 1, block, d), kv_row),
+            pl.BlockSpec((1, 1, block, dv), kv_row),
+            pl.BlockSpec((1, block // 32, block), words),
+            pl.BlockSpec((1, 1, block, dv), q_row),
+            pl.BlockSpec((1, 1, block, 1), q_row),
+            pl.BlockSpec((1, 1, block, 1), q_row)]
+
+
+def _flash_sel_fwd(q, k, v, words, flags, *, scale: float, interpret: bool):
+    """q [B,H,T,D], k [B,Hkv,T,D], v [B,Hkv,T,Dv], a selection's words and
+    flags in its tile -> (o [B,H,T,Dv], lse [B,H,T_pad,1])."""
+    b, h, t, d = q.shape
+    dv = v.shape[-1]
+    g = h // k.shape[1]
+    block = select_tile(t)
+    n = pl.cdiv(t, block)
+    q, k, v = (_pad_t(a, n * block) for a in (q, k, v))
+    specs = _sel_specs(block, g, d, dv, q_major=True)
+    o, lse = pl.pallas_call(
+        functools.partial(_fwd_sel_kernel, block=block, n=n, scale=scale,
+                          t_real=t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, h, n, n),
+            in_specs=specs[:4],
+            out_specs=[specs[4], specs[5]],
+            scratch_shapes=[pltpu.VMEM((block, dv), jnp.float32),
+                            pltpu.VMEM((block, 1), jnp.float32),
+                            pltpu.VMEM((block, 1), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, h, n * block, dv), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, n * block, 1), jnp.float32)],
+        interpret=interpret,
+        name="sparse_flash_fwd",
+    )(flags.reshape(-1), q, k, v, words)
+    return o[:, :, :t], lse
+
+
+def _flash_sel_bwd(q, k, v, words, flags, o, lse, do, *, scale: float,
+                   interpret: bool):
+    """dq, dk, dv over a selection (as _flash_bwd, causal)."""
+    b, h, t, d = q.shape
+    dv = v.shape[-1]
+    h_kv = k.shape[1]
+    g = h // h_kv
+    block = select_tile(t)
+    n = pl.cdiv(t, block)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    q_p, k_p, v_p, do_p, delta = (
+        _pad_t(a, n * block) for a in (q, k, v, do, delta))
+    operands = (flags.reshape(-1), q_p, k_p, v_p, words, do_p, lse, delta)
+    q_specs = _sel_specs(block, g, d, dv, q_major=True)
+    dq = pl.pallas_call(
+        functools.partial(_dq_sel_kernel, block=block, n=n, scale=scale,
+                          t_real=t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, h, n, n), in_specs=q_specs,
+            out_specs=pl.BlockSpec((1, 1, block, d), q_specs[0].index_map),
+            scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, n * block, d), q.dtype),
+        interpret=interpret,
+        name="sparse_flash_dq",
+    )(*operands)[:, :, :t]
+    k_specs = _sel_specs(block, g, d, dv, q_major=False)
+    out = lambda bi, hi, ki, qi, f: (bi, hi, ki, 0)
+    dk_h, dv_h = pl.pallas_call(
+        functools.partial(_dkv_sel_kernel, block=block, n=n, scale=scale,
+                          t_real=t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, h, n, n), in_specs=k_specs,
+            out_specs=[pl.BlockSpec((1, 1, block, d), out),
+                       pl.BlockSpec((1, 1, block, dv), out)],
+            scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
+                            pltpu.VMEM((block, dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, h, n * block, d), k.dtype),
+                   jax.ShapeDtypeStruct((b, h, n * block, dv), v.dtype)],
+        interpret=interpret,
+        name="sparse_flash_dkv",
+    )(*operands)
+    group_sum = lambda a, width, dtype: (
+        a[:, :, :t].reshape(b, h_kv, g, t, width).astype(jnp.float32)
+        .sum(axis=2).astype(dtype))
+    return dq, group_sum(dk_h, d, k.dtype), group_sum(dv_h, dv, v.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_sel(q, k, v, words, flags, scale, interpret):
+    o, lse = _flash_sel_fwd(q, k, v, words, flags, scale=scale,
+                            interpret=interpret)
+    return o, lse[:, :, :q.shape[2], 0]
+
+
+def _flash_sel_vjp_fwd(q, k, v, words, flags, scale, interpret):
+    o, lse = _flash_sel_fwd(q, k, v, words, flags, scale=scale,
+                            interpret=interpret)
+    from jax.ad_checkpoint import checkpoint_name
+
+    o = checkpoint_name(o, "flash_o")
+    lse = checkpoint_name(lse, "flash_lse")
+    return (o, lse[:, :, :q.shape[2], 0]), (q, k, v, words, flags, o, lse)
+
+
+def _flash_sel_vjp_bwd(scale, interpret, res, cotangents):
+    # the log-sum-exp is handed out for a caller's stopped use: its
+    # cotangent is dropped
+    do, _ = cotangents
+    q, k, v, words, flags, o, lse = res
+    dq, dk, dv = _flash_sel_bwd(q, k, v, words, flags, o, lse, do,
+                                scale=scale, interpret=interpret)
+    return dq, dk, dv, no_cotangent(words), no_cotangent(flags)
+
+
+_flash_sel.defvjp(_flash_sel_vjp_fwd, _flash_sel_vjp_bwd)
+
+
+def _selected_reference(q, k, v, words, *, scale: float):
+    """The chunked XLA reference over a selection, a selection tile of
+    queries at a time: (o, each query's log-sum-exp over its keys, no
+    gradient through it)."""
+    b, h, t, d = q.shape
+    h_kv = k.shape[1]
+    g = h // h_kv
+    block = select_tile(t)
+    n = -(-t // block)
+    t_pad = n * block
+    mask = words_to_mask(words, t_pad, block)[:, :, :t]
+    qr = _pad_t(q, t_pad).reshape(b, h, n, block, d).transpose(2, 0, 1, 3, 4)
+    mr = mask.reshape(b, n, block, t).transpose(1, 0, 2, 3)
+    k_idx = jnp.arange(t)
+
+    @jax.checkpoint
+    def blk(args):
+        i, q_blk, m_blk = args
+        q_idx = i * block + jnp.arange(block)
+        m_blk = m_blk & (q_idx[:, None] >= k_idx[None, :])
+        s = jnp.einsum("bhgqd,bhkd->bhgqk",
+                       q_blk.reshape(b, h_kv, g, block, d).astype(jnp.float32),
+                       k.astype(jnp.float32)).reshape(b, h, block, t) * scale
+        s = jnp.where(m_blk[:, None], s, _NEG_INF)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        p = jnp.where(m_blk[:, None], jnp.exp(s - lse[..., None]), 0.0)
+        o = jnp.einsum("bhgqk,bhkd->bhgqd",
+                       p.reshape(b, h_kv, g, block, t), v.astype(p.dtype))
+        return (o.reshape(b, h, block, -1).astype(q.dtype),
+                jax.lax.stop_gradient(lse))
+
+    o, lse = jax.lax.map(blk, (jnp.arange(n), qr, mr))
+    o = o.transpose(1, 2, 0, 3, 4).reshape(b, h, t_pad, -1)[:, :, :t]
+    return o, lse.transpose(1, 2, 0, 3).reshape(b, h, t_pad)[:, :, :t]
+
+
 def _block_reference(q_blk, k, v, q_offset, *, causal: bool, scale: float,
                      window=None):
     """Attention for one q block against full K/V (heads-major, GQA-aware).
@@ -688,6 +1007,7 @@ def flash_attention(
     head_axis: str = "tensor",
     layout: str = "bthd",
     window: Optional[int] = None,
+    selection=None,
 ):
     """Flash attention in model layout q [B,T,H,D], k [B,T,Hkv,D],
     v [B,T,Hkv,Dv] → [B,T,H,Dv] (Dv = D, or another head size for the
@@ -716,7 +1036,23 @@ def flash_attention(
     tiles. The tile is then square and sized from the window: half of it,
     at least 512 and at most 1024 (``_band_tile``), so a band of 512 keys
     computes two 512² tiles a q tile where the full grid's 1024² computed
-    two of four times the size."""
+    two of four times the size.
+
+    ``selection`` (a ``sparse_select.Selection``, with ``causal`` and no
+    window): query i sees only its selected keys, one selection for every
+    head. The kernels are then ``sparse_flash_fwd``, ``sparse_flash_dq``
+    and ``sparse_flash_dkv``, in the selection's square tile, each reading
+    the tile's bits beside its K/V or Q tile and passing over a tile that
+    holds no selected key; the call returns ``(o, lse)``, lse [B,H,T]
+    float32 each query's log-sum-exp over its keys, for a caller's stopped
+    use (no gradient flows back through it). A call without a selection
+    builds the kernels, grids and tiles it always has."""
+    if selection is not None:
+        return _selected_attention(
+            q, k, v, selection, causal=causal, scale=scale,
+            interpret=interpret, mesh=mesh, batch_axes=batch_axes,
+            head_axis=head_axis, layout=layout, window=window,
+            blocks=(block_q, block_k))
     if layout not in ("bthd", "bhtd"):
         raise ValueError(f"layout={layout!r}; expected bthd|bhtd")
     if window is not None and (not causal or window < 1):
@@ -778,3 +1114,46 @@ def flash_attention(
         local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
+
+
+def _selected_attention(q, k, v, selection, *, causal, scale, interpret,
+                        mesh, batch_axes, head_axis, layout, window, blocks):
+    """flash_attention over a selection (see there)."""
+    if not causal or window is not None or blocks != (None, None):
+        raise ValueError("a selection is of causal keys, in its own tile: "
+                         f"causal={causal}, window={window}, blocks={blocks}")
+    if layout not in ("bthd", "bhtd"):
+        raise ValueError(f"layout={layout!r}; expected bthd|bhtd")
+    heads_major = layout == "bhtd"
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    use_kernel = interpret is not None or jax.default_backend() == "tpu"
+
+    def local(q_, k_, v_, words, flags):
+        qt, kt, vt = ((a if heads_major else a.transpose(0, 2, 1, 3))
+                      for a in (q_, k_, v_))
+        if use_kernel:
+            o, lse = _flash_sel(qt, kt, vt, words, flags, scale,
+                                bool(interpret))
+        else:
+            o, lse = _selected_reference(qt, kt, vt, words, scale=scale)
+        return (o if heads_major else o.transpose(0, 2, 1, 3)), lse
+
+    words, flags = selection.words, selection.flags
+    if mesh is None:
+        return local(q, k, v, words, flags)
+
+    from jax.sharding import PartitionSpec as P
+
+    b_part = tuple(a for a in batch_axes if a in mesh.axis_names) or None
+    h_dim = 1 if heads_major else 2
+    tp = mesh.shape.get(head_axis, 1) if head_axis in mesh.axis_names else 1
+    h_part = head_axis if (tp > 1 and q.shape[h_dim] % tp == 0
+                           and k.shape[h_dim] % tp == 0) else None
+    spec = (P(b_part, h_part, None, None) if heads_major
+            else P(b_part, None, h_part, None))
+    bits = P(b_part, None, None)
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(spec, spec, spec, bits, bits),
+        out_specs=(spec, P(b_part, h_part, None)), check_vma=False,
+    )(q, k, v, words, flags)

@@ -436,10 +436,14 @@ SETUP_OVERLAPPED = ("ckpt_import",)
 # rows its two gathers in token order read (mean a layer); and the
 # state-space layers' (models/mamba2.py) — the share of the scan's (row,
 # chunk, head) whose decay over the whole chunk exceeds 0.1, so that state
-# is handed from chunk to chunk (mean a layer).
+# is handed from chunk to chunk (mean a layer); and a learned selection's
+# (models/llama.py) — selected pairs over causal pairs and the share of the
+# kernels' causally open tiles that hold a selected key (both mean a
+# layer), and the indexer's KL (the sum over layers of its mean a token).
 TRAIN_COUNTERS = ("moe.assignments_held", "moe.load_max_over_mean",
                   "moe.assignments_dropped", "moe.token_rows_read",
-                  "ssm.carry_share")
+                  "ssm.carry_share", "sparse.selected_share",
+                  "sparse.tiles_open_share", "sparse.index_kl")
 # the blob's `compiles` (runtime/compile_cache.compile_records, ISSUE 39):
 # the programs with the most compile seconds, the rest summed under one
 # key; the newest compile spans; the incarnation's first step times

@@ -28,7 +28,11 @@ The reference has no LLM workload — its examples top out at CNN scale
   (``Config.n_experts`` > 0: parallel/moe.py's share of the experts).
   Full and window attention may differ by kind in their query heads, their
   RoPE's theta and the share of a head they rotate, and may gate each
-  head's output by a sigmoid of the layer's input.
+  head's output by a sigmoid of the layer's input. Full attention may norm
+  each query and key head (``qk_norm``) and may attend to a learned
+  selection of keys: an indexer in the layer scores every causal key, each
+  query keeps its ``index_topk`` best, and the indexer learns from a KL
+  loss of its own that joins the returned loss (kernels/sparse_select.py).
   ``Config.n_dense_layers`` pairs in front of a routed model's periods keep
   the dense feed-forward, ``d_ff`` wide: a stack of their own
   (``params["lead"]``), scanned before the periods, so the program is two
@@ -204,6 +208,20 @@ class Config:
     ssm_state: int = 0
     conv_kernel: int = 4
     ssm_chunk: int = 128
+    # a per-head RMSNorm on each query and key head before the rotation
+    # (``q_norm``, ``k_norm``: ``head_dim`` scales each)
+    qk_norm: bool = False
+    # a learned selection of keys in every layer (full attention only):
+    # ``index_heads`` heads of ``index_head_dim`` score every causal key
+    # from the layer's normed input, gradient stopped (``I = sum_j w_j
+    # relu(q_j . k)``, queries and a LayerNormed key rotated as the
+    # layer's); a query attends to the keys at or above its
+    # ``index_topk``-th largest score, one selection for all of its heads.
+    # The indexer's KL to the attention's head-mean probabilities, a mean a
+    # token, joins the loss (:func:`loss_fn`)
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self):
         if self.attention_impl not in ("auto", "dense", "flash"):
@@ -278,6 +296,16 @@ class Config:
                 f"n_layers={self.n_layers} is no whole number of periods "
                 f"of {len(kinds)} layers behind {self.n_dense_layers} "
                 "leading dense ones")
+        if self.indexed and not (
+                set(kinds) == {"full"} and not self.n_dense_layers
+                and self.index_head_dim > 0 and self.index_head_dim % 2 == 0
+                and self.index_topk > 0):
+            raise ValueError(
+                f"an indexer ({self.index_heads} heads of "
+                f"{self.index_head_dim}, an even number, top "
+                f"{self.index_topk}) is every full layer's, with no other "
+                f"kind and no leading dense pairs: layer_kinds={kinds!r}, "
+                f"n_dense_layers={self.n_dense_layers}")
         windowed = "window" in kinds or (
             self.n_dense_layers > 0 and self.lead == "window")
         if windowed != (self.window is not None):
@@ -297,6 +325,10 @@ class Config:
     @property
     def routed(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def indexed(self) -> bool:
+        return self.index_heads > 0
 
     @property
     def single_mixers(self) -> bool:
@@ -463,6 +495,17 @@ def _init_attention(c: Config, keys, lead=(), kind: str = "full") -> Params:
     if c.head_gate:
         p["wg"] = {"w": _normal(jax.random.fold_in(keys[0], 1),
                                 (*lead, d, heads), d**-0.5)}
+    if c.qk_norm:
+        p["q_norm"] = {"scale": jnp.ones((*lead, c.head_dim), jnp.float32)}
+        p["k_norm"] = {"scale": jnp.ones((*lead, c.head_dim), jnp.float32)}
+    if c.indexed:
+        hi, di = c.index_heads, c.index_head_dim
+        ik = [jax.random.fold_in(keys[1], 1 + i) for i in range(3)]
+        p["wq_index"] = {"w": _normal(ik[0], (*lead, d, hi * di), d**-0.5)}
+        p["wk_index"] = {"w": _normal(ik[1], (*lead, d, di), d**-0.5)}
+        p["w_index"] = {"w": _normal(ik[2], (*lead, d, hi), d**-0.5)}
+        p["k_index_norm"] = {"scale": jnp.ones((*lead, di), jnp.float32),
+                             "bias": jnp.zeros((*lead, di), jnp.float32)}
     return p
 
 
@@ -583,6 +626,18 @@ _LATENT_AXES = {
     "wkv_b": {"w": ("latent", "heads")},
     "wo": {"w": ("heads", "embed")},
 }
+_QK_NORM_AXES = {"q_norm": {"scale": (None,)}, "k_norm": {"scale": (None,)}}
+
+
+# the indexer's leaves: its heads are few and narrow, held whole
+_INDEX_AXES = {
+    "wq_index": {"w": ("embed", None)},
+    "wk_index": {"w": ("embed", None)},
+    "w_index": {"w": ("embed", None)},
+    "k_index_norm": {"scale": (None,), "bias": (None,)},
+}
+
+
 _DENSE_AXES = {
     "mlp_norm": {"scale": ("stats",)},
     "w_gate": {"w": ("embed", "mlp")},
@@ -598,7 +653,9 @@ def logical_axes(config: Config) -> Params:
         **moe.logical_axes(shared=c.d_shared > 0, **_moe_form(c))}
     attention_axes = _LATENT_AXES if c.latent else dict(
         _ATTENTION_AXES, **({"wg": {"w": ("embed", "heads")}}
-                            if c.head_gate else {}))
+                            if c.head_gate else {}),
+        **(_QK_NORM_AXES if c.qk_norm else {}),
+        **(_INDEX_AXES if c.indexed else {}))
     if c.single_mixers:
         by_kind = {"mamba": mamba2.logical_axes,
                    "attention": lambda: _ATTENTION_AXES,
@@ -765,6 +822,10 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
         raise ValueError(
             "a window needs the whole sequence on one chip: the ring "
             "(mesh axis 'sequence') has no band")
+    if seq_sharded and c.indexed:
+        raise ValueError(
+            "a selection of keys needs the whole sequence on one chip: the "
+            "ring (mesh axis 'sequence') has no selection")
     if seq_sharded and c.latent:
         raise ValueError(
             "latent attention needs the whole sequence on one chip: the "
@@ -819,9 +880,58 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
                     c.n_heads, c.v_head_dim, -1))
         return constrain_fwd(h, ["batch", "seq", "embed"])
 
+    def indexer(lp, y):
+        """The indexer's queries [B,Hi,T,Di], key [B,T,Di] and weights
+        [B,T,Hi] (float32, both scales folded in), from the layer's normed
+        input with its gradient stopped: the indexer learns from its own
+        loss alone, and the layer from nothing of the indexer's."""
+        hi, di = c.index_heads, c.index_head_dim
+        ys = lax.stop_gradient(y)
+        qi = jnp.einsum("btd,dhx->bhtx", ys, lp["wq_index"]["w"].astype(dt)
+                        .reshape(-1, hi, di))
+        ki = (ys @ lp["wk_index"]["w"].astype(dt)).astype(jnp.float32)
+        mean = jnp.mean(ki, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
+        ki = ((ki - mean) * lax.rsqrt(var + c.norm_eps)
+              * lp["k_index_norm"]["scale"] + lp["k_index_norm"]["bias"])
+        qi = _rope_bhtd(qi, c.rope_theta)
+        ki = _rope(ki.astype(dt)[:, :, None], c.rope_theta)[:, :, 0]
+        w = (ys @ lp["w_index"]["w"].astype(dt)).astype(jnp.float32) * (
+            hi**-0.5 * di**-0.5)
+        return qi, ki, w
+
+    def selected_attention(lp, y, q, k, v, scale):
+        """Attention over each query's selected keys, heads-major: the
+        indexer, the selection (once a layer, for every head), the flash
+        kernels over it, and the indexer's KL loss. Returns (the
+        attention, the layer's sparse counters and its KL, a mean a
+        token)."""
+        from mpi_operator_tpu.kernels import flash_attention, sparse_select
+
+        b, t, _ = y.shape
+        with jax.named_scope("sparse_indexer"):
+            qi, ki, w = indexer(lp, y)
+        with jax.named_scope("sparse_select"):
+            sel = sparse_select.select(qi, ki, w, topk=c.index_topk,
+                                       mesh=mesh)
+            tile = sparse_select.select_tile(t)
+            n = -(-t // tile)
+            causal_tiles = b * n * (n + 1) // 2
+            counters = {
+                SELECTED_SHARE: jnp.sum(sel.count) / (b * t * (t + 1) / 2),
+                TILES_OPEN_SHARE: jnp.sum(sel.flags) / causal_tiles}
+        attn, lse = flash_attention(q, k, v, causal=True, scale=scale,
+                                    mesh=mesh, layout="bhtd", selection=sel)
+        with jax.named_scope("sparse_indexer"):
+            counters[INDEX_KL] = sparse_select.index_kl(
+                qi, ki, w, q, k, lse, sel, scale=scale, mesh=mesh) / (b * t)
+        return attn, counters
+
     def attention(h, lp, kind):
+        """``h`` plus the attention of its norm, and the layer's counters
+        (a selection's; none for other attention)."""
         if kind == "latent":
-            return latent_attention(h, lp)
+            return latent_attention(h, lp), {}
         y = _rmsnorm(h, lp["attn_norm"]["scale"], c.norm_eps)
         b, t, _ = y.shape
         heads, theta, rotated = c.attention(kind)
@@ -837,13 +947,22 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
         whole = rotated == c.head_dim
         scale = c.head_dim**-0.5 * (
             yarn.attention_factor**2 if yarn and whole else 1)
-        use_flash = not seq_sharded and c.attention_impl != "dense"
+        # a selection has the flash kernels' selected forms and no other
+        use_flash = c.indexed or (not seq_sharded
+                                  and c.attention_impl != "dense")
 
         def rope(a, layout):
             if not rotates:
                 return a
             with jax.named_scope("attention_rope"):
                 return _rope_first(layout, a, rotated, theta, yarn)
+
+        def qk_norm(a, which):
+            """Each head of q or k RMS-normed over its width, where the
+            configuration says so."""
+            if not c.qk_norm:
+                return a
+            return _rmsnorm(a, lp[f"{which}_norm"]["scale"], c.norm_eps)
 
         def gate(attn, spec):
             """Each head's output times sigmoid(y W_g); ``spec`` the
@@ -855,6 +974,7 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
                 g = jax.nn.sigmoid(g.astype(jnp.float32)).astype(attn.dtype)
                 return attn * g[..., None]
 
+        counters = {}
         if use_flash:
             from mpi_operator_tpu.kernels import flash_attention
 
@@ -867,13 +987,18 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
             wq3 = lp["wq"]["w"].astype(dt).reshape(-1, heads, c.head_dim)
             wk3 = lp["wk"]["w"].astype(dt).reshape(-1, c.n_kv_heads, c.head_dim)
             wv3 = lp["wv"]["w"].astype(dt).reshape(-1, c.n_kv_heads, c.head_dim)
-            q = rope(jnp.einsum("btd,dhx->bhtx", y, wq3), _rope_bhtd)
-            k = rope(jnp.einsum("btd,dhx->bhtx", y, wk3), _rope_bhtd)
+            q = rope(qk_norm(jnp.einsum("btd,dhx->bhtx", y, wq3), "q"),
+                     _rope_bhtd)
+            k = rope(qk_norm(jnp.einsum("btd,dhx->bhtx", y, wk3), "k"),
+                     _rope_bhtd)
             v = jnp.einsum("btd,dhx->bhtx", y, wv3)
-            attn = flash_attention(
-                q, k, v, causal=True, scale=scale, mesh=mesh,
-                layout="bhtd", window=window,
-            )
+            if c.indexed:
+                attn, counters = selected_attention(lp, y, q, k, v, scale)
+            else:
+                attn = flash_attention(
+                    q, k, v, causal=True, scale=scale, mesh=mesh,
+                    layout="bhtd", window=window,
+                )
             attn = gate(attn, "btd,dh->bht")
             wo3 = lp["wo"]["w"].astype(dt).reshape(heads, c.head_dim, -1)
             h = h + jnp.einsum("bhtx,hxd->btd", attn, wo3)
@@ -881,8 +1006,8 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
             q = (y @ lp["wq"]["w"].astype(dt)).reshape(b, t, heads, c.head_dim)
             k = (y @ lp["wk"]["w"].astype(dt)).reshape(b, t, c.n_kv_heads, c.head_dim)
             v = (y @ lp["wv"]["w"].astype(dt)).reshape(b, t, c.n_kv_heads, c.head_dim)
-            q = rope(q, _rope)
-            k = rope(k, _rope)
+            q = rope(qk_norm(q, "q"), _rope)
+            k = rope(qk_norm(k, "k"), _rope)
             if seq_sharded:
                 # ring attention: the only exact option over a sharded sequence
                 attn = ring_attention(q, k, v, mesh, causal=True, scale=scale)
@@ -892,7 +1017,7 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
                 )
             attn = gate(attn, "btd,dh->bth").reshape(b, t, heads * c.head_dim)
             h = h + attn @ lp["wo"]["w"].astype(dt)
-        return constrain_fwd(h, ["batch", "seq", "embed"])
+        return constrain_fwd(h, ["batch", "seq", "embed"]), counters
 
     def mlp(h, lp):
         y = _rmsnorm(h, lp["mlp_norm"]["scale"], c.norm_eps)
@@ -943,11 +1068,17 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
         # nemotron3nano.steady-8k's four layers (PERF.md section 6, PR 34).
         # It grows with depth and tokens a chip as the carry does: the
         # source's 23 Mamba layers would keep 9.3 GB at those tokens on a
-        # 16 GB chip. A job that deep takes these two names out
+        # 16 GB chip. A job that deep takes these two names out. And a
+        # selected layer's bits and its indexer loss's gradients
+        # (kernels/sparse_select.py): T / 8 bytes and about 4 x
+        # index_heads x index_head_dim bytes a token, 70 MB a layer at
+        # 16,384 tokens and 16 heads of 64, so that the replay runs neither
+        # the selection's kernel nor the loss's
         return jax.checkpoint(
             layer,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "flash_o", "flash_lse", "ssd_y", "ssd_states"
+                "flash_o", "flash_lse", "ssd_y", "ssd_states",
+                "sparse_select_bits", "sparse_index_grads"
             ),
         )
 
@@ -961,19 +1092,20 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
     def layer_of(kind):
         """A pair: attention of ``kind``, then the feed-forward."""
         def layer(carry, lp):
-            h = attend(carry, lp, kind)
+            h, counters = attend(carry, lp, kind)
             with jax.named_scope("mlp"):
                 if c.routed:
                     with jax.named_scope("moe"):
-                        return routed(h, lp)
-                return mlp(h, lp), {}
+                        h, routed_counters = routed(h, lp)
+                        return h, {**routed_counters, **counters}
+                return mlp(h, lp), counters
 
         return remat(layer)
 
     def lead_layer(carry, lp):
         """A leading pair of a routed model: the dense feed-forward."""
         with jax.named_scope("lead"):
-            h = attend(carry, lp, c.lead)
+            h, _ = attend(carry, lp, c.lead)
             with jax.named_scope("mlp"):
                 return mlp(h, lp), None
 
@@ -981,7 +1113,7 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
         """A single mixer: ``x + mixer(rmsnorm(x))`` and nothing else."""
         def layer(carry, lp):
             if kind == "attention":
-                return attend(carry, lp, kind), {}
+                return attend(carry, lp, kind)
             if kind == "experts":
                 with jax.named_scope("mlp"), jax.named_scope("moe"):
                     return routed(carry, lp)
@@ -1047,15 +1179,25 @@ def _forward(config, params, tokens, *, mesh, rules, return_features):
         return logits.astype(jnp.float32), counters
 
 
+# a selection's counters: selected pairs over causal pairs, and the share of
+# the kernels' causally open tiles that hold a selected key (each a mean a
+# layer); and the indexer's KL, a mean a token, which the loss adds up
+SELECTED_SHARE = "sparse.selected_share"
+TILES_OPEN_SHARE = "sparse.tiles_open_share"
+INDEX_KL = "sparse.index_kl"
+
+
 def _step_counters(counters):
     """One scalar a counter for the step, from each layer's: how many
     assignments fell to held experts (mean a layer), the fullest held
     expert over the mean (worst layer), assignments without a row (sum),
     rows read in token order, the share of the scan's chunks that hand state
-    on (both mean a layer). Keep its lines: those below are in cache keys."""
+    on, a selection's two shares (all mean a layer). Keep its lines: those
+    below are in cache keys."""
     fold = {moe.ASSIGNMENTS_HELD: jnp.mean, moe.LOAD_MAX_OVER_MEAN: jnp.max,
             moe.ASSIGNMENTS_DROPPED: jnp.sum, moe.TOKEN_ROWS_READ: jnp.mean,
-            mamba2.CARRY_SHARE: jnp.mean}
+            mamba2.CARRY_SHARE: jnp.mean, SELECTED_SHARE: jnp.mean,
+            TILES_OPEN_SHARE: jnp.mean, INDEX_KL: jnp.sum}
     return {name: fold[name](values) for name, values in counters.items()}
 
 
@@ -1069,10 +1211,15 @@ def loss_fn(
     ce_chunk: int = 2048,
 ):
     """Next-token cross-entropy. batch = {"tokens": [B,T]}; position t
-    predicts token t+1; the final position is dropped. A model with routed
-    or state-space layers returns ``(loss, counters)``: their named scalars
-    of the step (parallel/moe.py, models/mamba2.py), which ``Trainer`` puts
-    into the step's metrics.
+    predicts token t+1; the final position is dropped. Where the
+    configuration has an indexer (``Config.index_heads``), the returned loss
+    adds each layer's indexer KL, a mean a token (the counter
+    ``sparse.index_kl`` is their sum): the indexer's gradient comes from it
+    alone, and every other leaf's from the cross-entropy alone. A model
+    with routed, state-space or selected layers returns ``(loss,
+    counters)``: their named scalars of the step (parallel/moe.py,
+    models/mamba2.py, the selection's), which ``Trainer`` puts into the
+    step's metrics.
 
     Above ``ce_chunk`` positions the loss is computed blockwise over the
     sequence (checkpointed lax.map): the [B,T,vocab] f32 logits plus their
@@ -1094,7 +1241,12 @@ def loss_fn(
             lp = jax.nn.log_softmax(out[:, :-1])
             ll = jnp.take_along_axis(lp, targets[..., None], axis=-1)[..., 0]
             loss = -jnp.mean(ll)
-    return (loss, _step_counters(counters)) if counters else loss
+    if counters:
+        counters = _step_counters(counters)
+        if INDEX_KL in counters:
+            loss = loss + counters[INDEX_KL]
+        return loss, counters
+    return loss
 
 
 def _chunked_nll(feats, head, tokens, ce_chunk):
@@ -1143,8 +1295,11 @@ def param_count(config: Config) -> int:
                     + h * c.v_head_dim * d + d)
         heads = c.attention(kind).heads
         q = heads * c.head_dim
+        hi, di = c.index_heads, c.index_head_dim
+        indexer = d * (hi * di + di + hi) + 2 * di if c.indexed else 0
         return (d * (q + 2 * c.kv_dim) + q * d + d
-                + (d * heads if c.head_gate else 0))
+                + (d * heads if c.head_gate else 0)
+                + (2 * c.head_dim if c.qk_norm else 0) + indexer)
 
     matrices = 3 if c.experts_gated else 2  # of an expert, routed or shared
     routed = (d * c.n_experts + (c.n_experts if _moe_form(c)["score_bias"]

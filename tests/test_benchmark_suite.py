@@ -209,6 +209,7 @@ def test_the_two_entries_are_appended_for_the_routed_cells():  # noqa: F811
                      "source": source, "layer": "routed feed-forward",
                      "moves": "tokens_per_s_per_chip",
                      "workloads": original.ROUTED_CELLS}
-    # what follows them is the gated-heads cell's, and lists that cell
-    assert all(m["workloads"] == ["lagunaxs2.steady-8k"]
-               for m in metrics[first + 2:])
+    # what follows them is the gated-heads cell's three, each listing that
+    # cell, then the learned-selection cell's five, each listing that one
+    assert [m["workloads"] for m in metrics[first + 2:]] == (
+        [["lagunaxs2.steady-8k"]] * 3 + [["keyevl2.long-16k"]] * 5)

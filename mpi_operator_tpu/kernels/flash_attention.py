@@ -56,6 +56,7 @@ from mpi_operator_tpu.kernels.sparse_select import (
 )
 
 _NEG_INF = -1e30
+_SEL_VMEM = 64 * 1024 * 1024  # the selected kernels' g-head blocks
 
 
 def _pad_t(x, t_pad: int):
@@ -569,108 +570,173 @@ def _flash_bwd(
     return dq, dk, d_v
 
 
-# A selection (kernels/sparse_select.py) gives each query its own keys: the
-# three kernels below are the causal ones with the selection's bits read a
-# tile at a time beside K/V (or Q), in the selection's square tile, and a
-# tile that holds no selected key passed over (its flag, prefetched to
-# SMEM). A row's first tiles may hold none of its keys, so masked
-# probabilities are set to nought and not left to the running maximum.
+# A selection (kernels/sparse_select.py) gives each query its own keys, one
+# selection for every head: the three kernels below are the causal ones with
+# the selection's bits read a tile at a time beside K/V (or Q), in the
+# selection's square tile, and a tile that holds no selected key passed over
+# (its flag, prefetched to SMEM). Their grids run over KV heads: a step holds
+# the KV head's g query heads, reads K/V and the tile's words once for all g,
+# and builds the tile's mask once, as a bias (0 at a selected pair, _NEG_INF
+# elsewhere) in VMEM that each head adds to its scores.
+#
+# The kernels trust the bits: a selected pair is causal (key <= query) and
+# its key lies inside the sequence (key < T), padded query rows included.
+# ``select`` guarantees it, since its kernel scores every non-causal or
+# padded key INT_MIN, below any threshold it can reach, and its jax.numpy
+# twin masks them out before the threshold; so the kernels compare no
+# positions. While a row has met none of its keys its running maximum is
+# _NEG_INF, and so are its masked scores: the forward then subtracts 0, not
+# the maximum, so that their probabilities are nought. A row that meets no
+# key at all (a tile's flag cleared) emits 0 and a log-sum-exp of _NEG_INF,
+# which the backward kernels raise to -_NEG_INF for the same end.
 
 
-def _selected(words_ref, qi, ki, block: int, t_real: int):
-    """The tile's mask: selected, causal and inside the sequence."""
-    q_idx = qi * block + jax.lax.broadcasted_iota(
-        jnp.int32, (block, block), 0)
-    k_idx = ki * block + jax.lax.broadcasted_iota(
-        jnp.int32, (block, block), 1)
-    causal = jnp.logical_and(q_idx >= k_idx, k_idx < t_real)
-    return jnp.logical_and(unpack(words_ref[0], block, 0, block), causal)
+def _sel_bias(bias_ref, words_ref, block: int):
+    """Writes the tile's mask as a bias on the scores, an eighth of its rows
+    (four bit planes) a loop step: as ``_heads``, the code of one chunk and
+    not of the whole tile."""
+    rows = block // 8
+
+    def chunk(c, carry):
+        bias_ref[pl.ds(pl.multiple_of(c * rows, rows), rows), :] = jnp.where(
+            unpack(words_ref[0], rows, c * 4, block), 0.0, _NEG_INF)
+        return carry
+
+    jax.lax.fori_loop(0, 8, chunk, 0)
+
+
+def _sel_lse(lse):
+    """A row's log-sum-exp for exp(s - lse): a row with no key has none,
+    and its probabilities are nought."""
+    return jnp.where(lse > _NEG_INF / 2, lse, -_NEG_INF)
 
 
 def _flag(flags_ref, bi, qi, ki, n: int):
     return flags_ref[(bi * n + qi) * n + ki] > 0
 
 
+def _heads(g: int, body):
+    """``body(h)`` for each of a step's g query heads, as one loop, so that
+    the kernel's code (which the device holds in HBM) is one head's
+    [block, ·] body and not g of them."""
+    def step(h, carry):
+        body(h)
+        return carry
+
+    jax.lax.fori_loop(0, g, step, 0)
+
+
 def _fwd_sel_kernel(
     flags_ref, q_ref, k_ref, v_ref, words_ref, o_ref, lse_ref, acc_ref,
-    m_ref, l_ref, *, block: int, n: int, scale: float, t_real: int,
+    m_ref, l_ref, bias_ref, *, block: int, n: int, g: int, scale: float,
 ):
-    """The forward over a selection: grid (b, h, qi, ki), as _fwd_kernel's
-    causal grid. Refs as there, and words [1, block/32, block]."""
+    """The forward over a selection: grid (b, h_kv, qi, ki), ki innermost,
+    the causally dead tiles skipped. Refs: q [1,g,block,D], k [1,1,block,D],
+    v [1,1,block,Dv], words [1,block/32,block], o [1,g,block,Dv], lse
+    [1,g,block,1]; scratch acc [g,block,Dv], m and l [g,block,1], the
+    tile's bias [block,block]."""
     bi, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        def init(h):
+            acc_ref[h] = jnp.zeros(acc_ref.shape[1:], jnp.float32)
+            m_ref[h] = jnp.full(m_ref.shape[1:], _NEG_INF, jnp.float32)
+            l_ref[h] = jnp.zeros(l_ref.shape[1:], jnp.float32)
+
+        _heads(g, init)
 
     @pl.when(jnp.logical_and(_causal_open(qi, ki, block, block),
                              _flag(flags_ref, bi, qi, ki, n)))
     def _fold():
+        _sel_bias(bias_ref, words_ref, block)
+        k = k_ref[0, 0]
         v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        sel = _selected(words_ref, qi, ki, block, t_real)
-        s = jnp.where(sel, s, _NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.where(sel, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
-        m_ref[:, 0] = m_new
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+
+        def head(h):
+            s = jax.lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + bias_ref[...]
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - jnp.where(m_new > _NEG_INF / 2, m_new, 0.0))
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[h] = m_new
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        _heads(g, head)
 
     @pl.when(ki == n - 1)
     def _emit():
-        l = l_ref[:, 0]
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[...] + jnp.log(safe[:, None])
+        def emit(h):
+            l = l_ref[h]
+            safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, h] = (acc_ref[h] / safe).astype(o_ref.dtype)
+            lse_ref[0, h] = m_ref[h] + jnp.log(safe)
+
+        _heads(g, emit)
 
 
 def _dq_sel_kernel(
     flags_ref, q_ref, k_ref, v_ref, words_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, acc_ref, *, block: int, n: int, scale: float, t_real: int,
+    dq_ref, acc_ref, bias_ref, *, block: int, n: int, g: int, scale: float,
 ):
-    """dq over a selection: grid (b, h, qi, ki), as _bwd_dq_kernel's."""
+    """dq over a selection: grid (b, h_kv, qi, ki), as the forward's.
+    Refs as there, and do [1,g,block,Dv], lse/delta [1,g,block,1], dq
+    [1,g,block,D]; scratch acc [g,block,D] and the tile's bias."""
     bi, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        def init(h):
+            acc_ref[h] = jnp.zeros(acc_ref.shape[1:], jnp.float32)
+
+        _heads(g, init)
 
     @pl.when(jnp.logical_and(_causal_open(qi, ki, block, block),
                              _flag(flags_ref, bi, qi, ki, n)))
     def _fold():
+        _sel_bias(bias_ref, words_ref, block)
         k = k_ref[0, 0]
-        s = jax.lax.dot_general(
-            q_ref[0, 0], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        sel = _selected(words_ref, qi, ki, block, t_real)
-        p = jnp.where(sel, jnp.exp(s - lse_ref[0, 0]), 0.0)
-        dp = jax.lax.dot_general(
-            do_ref[0, 0], v_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, 0])).astype(k.dtype)
-        acc_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        v = v_ref[0, 0]
+
+        def head(h):
+            s = jax.lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + bias_ref[...]
+            p = jnp.exp(s - _sel_lse(lse_ref[0, h]))
+            dp = jax.lax.dot_general(
+                do_ref[0, h], v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, h])).astype(k.dtype)
+            acc_ref[h] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        _heads(g, head)
 
     @pl.when(ki == n - 1)
     def _emit():
-        dq_ref[0, 0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+        def emit(h):
+            dq_ref[0, h] = (acc_ref[h] * scale).astype(dq_ref.dtype)
+
+        _heads(g, emit)
 
 
 def _dkv_sel_kernel(
     flags_ref, q_ref, k_ref, v_ref, words_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, block: int, n: int,
-    scale: float, t_real: int,
+    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, bias_ref, *, block: int, n: int,
+    g: int, scale: float,
 ):
-    """dk/dv over a selection: grid (b, h, ki, qi), as _bwd_dkv_kernel's;
-    the words are the (qi, ki) tile's."""
+    """dk/dv over a selection: grid (b, h_kv, ki, qi), qi innermost; the
+    words are the (qi, ki) tile's. The g query heads' parts add into one
+    accumulator a KV head. A padded query row adds nought: its dO and
+    delta are zero. Refs as dq's, and dk [1,1,block,D], dv
+    [1,1,block,Dv]; scratch dk_acc [block,D], dv_acc [block,Dv] and the
+    tile's bias."""
     bi, ki, qi = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(qi == 0)
@@ -681,25 +747,29 @@ def _dkv_sel_kernel(
     @pl.when(jnp.logical_and(_causal_open(qi, ki, block, block),
                              _flag(flags_ref, bi, qi, ki, n)))
     def _fold():
-        q = q_ref[0, 0]
-        do = do_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        q_idx = qi * block + jax.lax.broadcasted_iota(
-            jnp.int32, (block, block), 0)
-        sel = jnp.logical_and(_selected(words_ref, qi, ki, block, t_real),
-                              q_idx < t_real)
-        p = jnp.where(sel, jnp.exp(s - lse_ref[0, 0]), 0.0)
-        dv_acc_ref[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, 0])).astype(q.dtype)
-        dk_acc_ref[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        _sel_bias(bias_ref, words_ref, block)
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+
+        def head(h):
+            q = q_ref[0, h]
+            do = do_ref[0, h]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + bias_ref[...]
+            p = jnp.exp(s - _sel_lse(lse_ref[0, h]))
+            dv_acc_ref[...] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, h])).astype(q.dtype)
+            dk_acc_ref[...] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        _heads(g, head)
 
     @pl.when(qi == n - 1)
     def _emit():
@@ -709,9 +779,10 @@ def _dkv_sel_kernel(
 
 def _sel_specs(block: int, g: int, d: int, dv: int, q_major: bool):
     """BlockSpecs of (q, k, v, words, do, lse, delta) over a selection's
-    grid: (b, h, qi, ki) for flash_fwd and flash_dq, (b, h, ki, qi) for
-    flash_dkv (``q_major`` False); the causally dead tiles' indices clamped
-    to a live one, so that their DMAs are elided."""
+    grid: (b, h_kv, qi, ki) for flash_fwd and flash_dq, (b, h_kv, ki, qi)
+    for flash_dkv (``q_major`` False); a q-side block holds the KV head's
+    g query heads, and the causally dead tiles' indices are clamped to a
+    live one, so that their DMAs are elided."""
     def ids(a, b_):
         qi, ki = (a, b_) if q_major else (b_, a)
         if q_major:
@@ -720,23 +791,23 @@ def _sel_specs(block: int, g: int, d: int, dv: int, q_major: bool):
             qi = _q_tile_clamp(ki, qi, block, block, True)
         return qi, ki
 
-    def q_row(bi, hi, a, b_, f):
-        return (bi, hi, ids(a, b_)[0], 0)
+    def q_row(bi, hk, a, b_, f):
+        return (bi, hk, ids(a, b_)[0], 0)
 
-    def kv_row(bi, hi, a, b_, f):
-        return (bi, hi // g, ids(a, b_)[1], 0)
+    def kv_row(bi, hk, a, b_, f):
+        return (bi, hk, ids(a, b_)[1], 0)
 
-    def words(bi, hi, a, b_, f):
+    def words(bi, hk, a, b_, f):
         qi, ki = ids(a, b_)
         return (bi, qi, ki)
 
-    return [pl.BlockSpec((1, 1, block, d), q_row),
+    return [pl.BlockSpec((1, g, block, d), q_row),
             pl.BlockSpec((1, 1, block, d), kv_row),
             pl.BlockSpec((1, 1, block, dv), kv_row),
             pl.BlockSpec((1, block // 32, block), words),
-            pl.BlockSpec((1, 1, block, dv), q_row),
-            pl.BlockSpec((1, 1, block, 1), q_row),
-            pl.BlockSpec((1, 1, block, 1), q_row)]
+            pl.BlockSpec((1, g, block, dv), q_row),
+            pl.BlockSpec((1, g, block, 1), q_row),
+            pl.BlockSpec((1, g, block, 1), q_row)]
 
 
 def _flash_sel_fwd(q, k, v, words, flags, *, scale: float, interpret: bool):
@@ -744,23 +815,26 @@ def _flash_sel_fwd(q, k, v, words, flags, *, scale: float, interpret: bool):
     flags in its tile -> (o [B,H,T,Dv], lse [B,H,T_pad,1])."""
     b, h, t, d = q.shape
     dv = v.shape[-1]
-    g = h // k.shape[1]
+    h_kv = k.shape[1]
+    g = h // h_kv
     block = select_tile(t)
     n = pl.cdiv(t, block)
     q, k, v = (_pad_t(a, n * block) for a in (q, k, v))
     specs = _sel_specs(block, g, d, dv, q_major=True)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_sel_kernel, block=block, n=n, scale=scale,
-                          t_real=t),
+        functools.partial(_fwd_sel_kernel, block=block, n=n, g=g,
+                          scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, h, n, n),
+            num_scalar_prefetch=1, grid=(b, h_kv, n, n),
             in_specs=specs[:4],
             out_specs=[specs[4], specs[5]],
-            scratch_shapes=[pltpu.VMEM((block, dv), jnp.float32),
-                            pltpu.VMEM((block, 1), jnp.float32),
-                            pltpu.VMEM((block, 1), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((g, block, dv), jnp.float32),
+                            pltpu.VMEM((g, block, 1), jnp.float32),
+                            pltpu.VMEM((g, block, 1), jnp.float32),
+                            pltpu.VMEM((block, block), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((b, h, n * block, dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, n * block, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_SEL_VMEM),
         interpret=interpret,
         name="sparse_flash_fwd",
     )(flags.reshape(-1), q, k, v, words)
@@ -781,38 +855,38 @@ def _flash_sel_bwd(q, k, v, words, flags, o, lse, do, *, scale: float,
     q_p, k_p, v_p, do_p, delta = (
         _pad_t(a, n * block) for a in (q, k, v, do, delta))
     operands = (flags.reshape(-1), q_p, k_p, v_p, words, do_p, lse, delta)
+    params = pltpu.CompilerParams(vmem_limit_bytes=_SEL_VMEM)
     q_specs = _sel_specs(block, g, d, dv, q_major=True)
     dq = pl.pallas_call(
-        functools.partial(_dq_sel_kernel, block=block, n=n, scale=scale,
-                          t_real=t),
+        functools.partial(_dq_sel_kernel, block=block, n=n, g=g,
+                          scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, h, n, n), in_specs=q_specs,
-            out_specs=pl.BlockSpec((1, 1, block, d), q_specs[0].index_map),
-            scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)]),
+            num_scalar_prefetch=1, grid=(b, h_kv, n, n), in_specs=q_specs,
+            out_specs=pl.BlockSpec((1, g, block, d), q_specs[0].index_map),
+            scratch_shapes=[pltpu.VMEM((g, block, d), jnp.float32),
+                            pltpu.VMEM((block, block), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, h, n * block, d), q.dtype),
+        compiler_params=params,
         interpret=interpret,
         name="sparse_flash_dq",
     )(*operands)[:, :, :t]
     k_specs = _sel_specs(block, g, d, dv, q_major=False)
-    out = lambda bi, hi, ki, qi, f: (bi, hi, ki, 0)
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(_dkv_sel_kernel, block=block, n=n, scale=scale,
-                          t_real=t),
+    dk, d_v = pl.pallas_call(
+        functools.partial(_dkv_sel_kernel, block=block, n=n, g=g,
+                          scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, h, n, n), in_specs=k_specs,
-            out_specs=[pl.BlockSpec((1, 1, block, d), out),
-                       pl.BlockSpec((1, 1, block, dv), out)],
+            num_scalar_prefetch=1, grid=(b, h_kv, n, n), in_specs=k_specs,
+            out_specs=[k_specs[1], k_specs[2]],
             scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
-                            pltpu.VMEM((block, dv), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((b, h, n * block, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, h, n * block, dv), v.dtype)],
+                            pltpu.VMEM((block, dv), jnp.float32),
+                            pltpu.VMEM((block, block), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, h_kv, n * block, d), k.dtype),
+                   jax.ShapeDtypeStruct((b, h_kv, n * block, dv), v.dtype)],
+        compiler_params=params,
         interpret=interpret,
         name="sparse_flash_dkv",
     )(*operands)
-    group_sum = lambda a, width, dtype: (
-        a[:, :, :t].reshape(b, h_kv, g, t, width).astype(jnp.float32)
-        .sum(axis=2).astype(dtype))
-    return dq, group_sum(dk_h, d, k.dtype), group_sum(dv_h, dv, v.dtype)
+    return dq, dk[:, :, :t], d_v[:, :, :t]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -1041,9 +1115,12 @@ def flash_attention(
     ``selection`` (a ``sparse_select.Selection``, with ``causal`` and no
     window): query i sees only its selected keys, one selection for every
     head. The kernels are then ``sparse_flash_fwd``, ``sparse_flash_dq``
-    and ``sparse_flash_dkv``, in the selection's square tile, each reading
-    the tile's bits beside its K/V or Q tile and passing over a tile that
-    holds no selected key; the call returns ``(o, lse)``, lse [B,H,T]
+    and ``sparse_flash_dkv``, in the selection's square tile, on grids over
+    KV heads: each step takes a KV head's query heads, reads the tile's
+    bits once beside its K/V tile and builds one mask for all of them, and
+    a tile that holds no selected key is passed over. The kernels trust
+    the bits to be causal and inside the sequence, as ``select`` makes
+    them. The call returns ``(o, lse)``, lse [B,H,T]
     float32 each query's log-sum-exp over its keys, for a caller's stopped
     use (no gradient flows back through it). A call without a selection
     builds the kernels, grids and tiles it always has."""

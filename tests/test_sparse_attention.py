@@ -1,10 +1,12 @@
 """The learned selection of keys (kernels/sparse_select.py) and the flash
 kernels over it, on the CPU: the selection against ``lax.top_k``'s set,
-exactly, with short rows and planted ties; its bits; the three flash
-kernels over a selection against a dense masked reference, forward and
-three gradients; the indexer loss's value and gradients against autodiff
-of the plain KL; and flash calls without a selection lowering to the
-jaxprs they lowered to before the selection existed. The kernels' bodies
+exactly, with short rows and planted ties; its bits, and that they are
+causal and inside the sequence; the three flash kernels over a selection
+against a dense masked reference, forward and three gradients, at one,
+two and eight query heads a KV head; the indexer loss's value and
+gradients against autodiff of the plain KL; and flash calls without a
+selection lowering to the jaxprs they lowered to before the selection
+existed. The kernels' bodies
 run in interpret mode (``interpret=True``), their ``jax.numpy`` twins as
 the model runs them off the chip.
 
@@ -132,17 +134,10 @@ def _masked_attention(q, k, v, mask, scale):
             jax.nn.logsumexp(s, axis=-1))
 
 
-@pytest.mark.parametrize("interpret", [None, True], ids=["xla", "kernel"])
-@pytest.mark.parametrize("t,topk", [(80, 16), (300, 40)])
-def test_flash_over_a_selection_matches_a_dense_mask(t, topk, interpret):
-    """Forward, log-sum-exp and the three gradients of the kernels over a
-    selection (and of the chunked XLA reference the model runs off the
-    chip) against dense attention masked to the same selection."""
-    qi, ki, w = _indexer(t, t)
-    sel = ss.select(qi, ki, w, topk=topk)
-    mask = ss.words_to_mask(sel.words, t, ss.select_tile(t))
-    q, k, v = _qkv(jax.random.PRNGKey(t), t)
-    scale = 16 ** -0.5
+def _against_a_dense_mask(q, k, v, sel, mask, interpret):
+    """Forward, log-sum-exp and the three gradients over ``sel`` against
+    dense attention masked to ``mask``."""
+    scale = q.shape[-1] ** -0.5
     o, lse = flash_attention(q, k, v, layout="bhtd", selection=sel,
                              interpret=interpret)
     o_ref, lse_ref = _masked_attention(q, k, v, mask, scale)
@@ -158,20 +153,97 @@ def test_flash_over_a_selection_matches_a_dense_mask(t, topk, interpret):
         np.testing.assert_allclose(a, b, atol=5e-5)
 
 
+# ragged lengths: 1100 is two tiles of 1024, the second mostly padding
+_LENGTHS = [(80, 16), (300, 40), (1100, 150)]
+
+
+@pytest.mark.parametrize("interpret", [None, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("t,topk", _LENGTHS)
+def test_flash_over_a_selection_matches_a_dense_mask(t, topk, interpret):
+    """Forward, log-sum-exp and the three gradients of the kernels over a
+    selection (and of the chunked XLA reference the model runs off the
+    chip) against dense attention masked to the same selection, two query
+    heads a KV head."""
+    qi, ki, w = _indexer(t, t)
+    sel = ss.select(qi, ki, w, topk=topk)
+    mask = ss.words_to_mask(sel.words, t, ss.select_tile(t))
+    _against_a_dense_mask(*_qkv(jax.random.PRNGKey(t), t), sel, mask,
+                          interpret)
+
+
+@pytest.mark.parametrize("interpret", [None, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("t,topk", _LENGTHS)
+@pytest.mark.parametrize("h,hkv", [(2, 2), (8, 1)], ids=["g1", "g8"])
+def test_a_kv_heads_query_heads_share_one_grid_step(h, hkv, t, topk,
+                                                    interpret):
+    """The kernels' grids run over KV heads, a step holding the KV head's
+    g query heads and one mask for all of them: at one and at eight query
+    heads a KV head (two: the test above), forward, log-sum-exp and the
+    three gradients are dense attention masked to the selection."""
+    qi, ki, w = _indexer(t + h, t)
+    sel = ss.select(qi, ki, w, topk=topk)
+    mask = ss.words_to_mask(sel.words, t, ss.select_tile(t))
+    _against_a_dense_mask(*_qkv(jax.random.PRNGKey(t + hkv), t, h=h,
+                                hkv=hkv), sel, mask, interpret)
+
+
 def test_a_tile_with_no_selected_key_is_passed_over():
     """Flags at nought for a tile that holds selected keys: the kernels
-    pass over it, and the result is the masked attention without it."""
+    pass over it, and the result and its gradients are the masked
+    attention without it, with two and with eight query heads a KV head.
+    Ten rows whose keys all lie in that tile are left with none: their
+    output is nought and they add nothing to any gradient."""
     t = 1100  # two tiles of 1024: (1, 0) is off the diagonal
-    q, k, v = _qkv(jax.random.PRNGKey(3), t, h=2, hkv=1, d=8)
-    mask = jnp.tril(jnp.ones((1, t, t), bool))
+    empty = jnp.arange(1030, 1040)
+    mask = jnp.tril(jnp.ones((1, t, t), bool)).at[0, empty, 1024:].set(False)
     words = ss.pack(mask, 1024)
     flags = ss.tile_flags(words, 1024).at[0, 1, 0].set(0)
     sel = ss.Selection(words, flags, jnp.zeros((1, t)), jnp.zeros((1, t)))
-    o, _ = flash_attention(q, k, v, layout="bhtd", selection=sel,
-                           interpret=True)
-    skipped = mask.at[0, 1024:, :1024].set(False)
-    o_ref, _ = _masked_attention(q, k, v, skipped, 8 ** -0.5)
-    np.testing.assert_allclose(o, o_ref, atol=2e-5)
+    # the reference's empty rows see key 0 and take no cotangent
+    skipped = mask.at[0, 1024:, :1024].set(False).at[0, empty, 0].set(True)
+    for h, hkv in ((2, 1), (8, 2)):
+        q, k, v = _qkv(jax.random.PRNGKey(3), t, h=h, hkv=hkv, d=8)
+        scale = 8 ** -0.5
+        o, lse = flash_attention(q, k, v, layout="bhtd", selection=sel,
+                                 interpret=True)
+        o_ref, lse_ref = _masked_attention(q, k, v, skipped, scale)
+        assert not bool(jnp.any(o[:, :, empty]))
+        full = jnp.ones(t, bool).at[empty].set(False)
+        np.testing.assert_allclose(o[:, :, full], o_ref[:, :, full],
+                                   atol=2e-5)
+        np.testing.assert_allclose(lse[:, :, full], lse_ref[:, :, full],
+                                   atol=2e-5)
+        ct = jax.random.normal(jax.random.PRNGKey(1), o.shape)
+        got = jax.grad(lambda *a: jnp.sum(ct * flash_attention(
+            *a, layout="bhtd", selection=sel, interpret=True)[0]),
+            argnums=(0, 1, 2))(q, k, v)
+        ct = ct.at[:, :, empty].set(0.0)
+        want = jax.grad(lambda *a: jnp.sum(ct * _masked_attention(
+            *a, skipped, scale)[0]), argnums=(0, 1, 2))(q, k, v)
+        assert not bool(jnp.any(got[0][:, :, empty]))
+        np.testing.assert_allclose(got[0][:, :, full], want[0][:, :, full],
+                                   atol=5e-5)
+        for a, b in zip(got[1:], want[1:]):
+            np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+@pytest.mark.parametrize("interpret", [None, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("t,topk", [(80, 100), (300, 40), (1100, 150),
+                                    (1100, 2000)])
+def test_the_bits_are_causal_and_inside_the_sequence(t, topk, interpret):
+    """The flash kernels over a selection compare no positions: they trust
+    that every selected pair is causal and that its key lies inside the
+    sequence, in every row of the tiles, the padded ones included, and
+    also where a row has fewer causal keys than the top (t + 1 <= topk)."""
+    qi, ki, w = _indexer(t + topk, t, b=2)
+    sel = ss.select(qi, ki, w, topk=topk, interpret=interpret)
+    tile = ss.select_tile(t)
+    t_pad = -(-t // tile) * tile
+    bits = ss.words_to_mask(sel.words, t_pad, tile)
+    q_idx, k_idx = jnp.arange(t_pad)[:, None], jnp.arange(t_pad)[None, :]
+    allowed = (k_idx <= q_idx) & (k_idx < t)
+    assert not bool(jnp.any(bits & ~allowed))
+    assert bool(jnp.all(jnp.any(bits[:, :t], axis=-1)))  # and not empty
 
 
 def _plain_kl(qi, ki, w, q, k, lse, mask, scale):
@@ -285,8 +357,9 @@ def test_models_without_a_selection_lower_as_before(name):
 
 def test_a_selection_builds_its_own_kernels_in_its_tile():
     """Over a selection the three kernels are the sparse ones, on the
-    causal grid in the selection's square tile, beside the selection's and
-    the loss's kernels."""
+    causal grid over KV heads (a step holds a KV head's query heads) in the
+    selection's square tile, beside the selection's and the loss's
+    kernels."""
     from tests.test_banded_attention import _pallas_calls
 
     t = 2048
@@ -310,7 +383,7 @@ def test_a_selection_builds_its_own_kernels_in_its_tile():
                              "sparse_flash_fwd", "sparse_index_kl",
                              "sparse_select"]
     for name in ("sparse_flash_fwd", "sparse_flash_dq", "sparse_flash_dkv"):
-        assert calls[name][0] == (1, 4, 2, 2)
+        assert calls[name][0] == (1, 2, 2, 2)  # (b, h_kv, tiles, tiles)
     assert calls["sparse_select"][0] == (1, 8)  # 256 queries a step
     assert calls["sparse_index_kl"][0] == (1, 8, 4)  # 256 x 512 tiles
 

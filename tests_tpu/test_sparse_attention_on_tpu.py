@@ -7,6 +7,9 @@ cell's shape (``keyevl2.long-16k``: 16,384 tokens, 32 query heads over 4 of
 - ``sparse_flash_fwd`` and the three gradients of ``sparse_flash_dq`` /
   ``sparse_flash_dkv`` (bf16) against masked attention in float32 at
   ``highest``, a block of queries at a time;
+- the three kernels' times, each alone, beside the unmasked
+  ``flash_fwd`` / ``flash_dq`` / ``flash_dkv`` at the same shape (printed,
+  for PERF.md);
 - the ``sparse_index_kl`` kernel's loss and gradients against its
   ``jax.numpy`` twin, float32, at a quarter of the length;
 - how many selected keys the program's bf16 indexer moves against the
@@ -15,6 +18,9 @@ cell's shape (``keyevl2.long-16k``: 16,384 tokens, 32 query heads over 4 of
 
     python -m pytest tests_tpu/test_sparse_attention_on_tpu.py -q -s  # a TPU
 """
+
+import importlib
+import time
 
 import jax
 import jax.numpy as jnp
@@ -141,6 +147,67 @@ def test_flash_over_a_selection_and_three_gradients_at_the_cells_shape():
     gaps = [_rel(o, o_ref)] + [_rel(a, b) for a, b in zip(grads, want)]
     print("rel rms o, dq, dk, dv", gaps)
     assert max(gaps) < 2e-2, gaps
+
+
+def test_the_selected_kernels_timed_beside_the_unmasked_ones():
+    """Each of the three kernels over a selection alone (a jitted call that
+    keeps one of the backward's outputs compiles one kernel), on a random
+    indexer's selection, which opens every causal tile as the cell's does,
+    beside the unmasked causal kernels at the same shape: ms a call, the
+    median of five timings of ten calls."""
+    fa = importlib.import_module("mpi_operator_tpu.kernels.flash_attention")
+    keys = jax.random.split(jax.random.PRNGKey(11), 7)
+    qi = jax.random.normal(keys[0], (1, HI, T, DI), jnp.bfloat16)
+    ki = jax.random.normal(keys[1], (1, T, DI), jnp.bfloat16)
+    w = jax.random.normal(keys[2], (1, T, HI)) / 32
+    sel = jax.jit(lambda a, b, c: ss.select(a, b, c, topk=TOPK))(qi, ki, w)
+    q, k, v, do = (jax.random.normal(key, shape, jnp.bfloat16) for key, shape
+                   in zip(keys[3:], ((1, H, T, D), (1, HKV, T, D),
+                                     (1, HKV, T, D), (1, H, T, D))))
+    scale = D ** -0.5
+    how = dict(scale=scale, interpret=False)
+    plain = dict(causal=True, scale=scale, block_q=1024, block_k=1024,
+                 interpret=False)
+    o_s, lse_s = jax.jit(lambda q, k, v, wd, f: fa._flash_sel_fwd(
+        q, k, v, wd, f, **how))(q, k, v, sel.words, sel.flags)
+    o_u, lse_u = jax.jit(lambda q, k, v: fa._flash_fwd(q, k, v, **plain))(
+        q, k, v)
+    calls = {
+        "sparse_flash_fwd": (lambda q, k, v, wd, f: fa._flash_sel_fwd(
+            q, k, v, wd, f, **how)[0], (q, k, v, sel.words, sel.flags)),
+        "flash_fwd": (lambda q, k, v: fa._flash_fwd(q, k, v, **plain)[0],
+                      (q, k, v)),
+    }
+    for i, kind in ((0, "dq"), (1, "dkv")):
+        calls[f"sparse_flash_{kind}"] = (
+            lambda q, k, v, wd, f, o, lse, do, i=i: fa._flash_sel_bwd(
+                q, k, v, wd, f, o, lse, do, **how)[i],
+            (q, k, v, sel.words, sel.flags, o_s, lse_s, do))
+        calls[f"flash_{kind}"] = (
+            lambda q, k, v, o, lse, do, i=i: fa._flash_bwd(
+                q, k, v, o, lse, do, **plain)[i],
+            (q, k, v, o_u, lse_u, do))
+
+    def timed(fn, args):
+        f = jax.jit(fn)
+        jax.block_until_ready(f(*args))
+        runs = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(10):
+                out = f(*args)
+            jax.block_until_ready(out)
+            runs.append((time.perf_counter() - t0) * 100)
+        return float(np.median(runs))
+
+    ms = {name: timed(*call) for name, call in calls.items()}
+    for kind in ("fwd", "dq", "dkv"):
+        print(f"{kind}: sparse_flash_{kind} {ms[f'sparse_flash_{kind}']:.3f}"
+              f" ms, flash_{kind} {ms[f'flash_{kind}']:.3f} ms")
+    print("three kernels: selected {:.3f} ms, unmasked {:.3f} ms".format(
+        sum(v for n, v in ms.items() if n.startswith("sparse")),
+        sum(v for n, v in ms.items() if not n.startswith("sparse"))))
+    assert all(v > 0 for v in ms.values())
 
 
 def test_the_index_loss_kernel_against_its_xla_twin():
